@@ -50,7 +50,7 @@ DETERMINISM_ALLOWLIST = frozenset({
     "scatter_add", "select_n", "slice", "squeeze", "transpose",
     # control flow / staging
     "closed_call", "cond", "custom_jvp_call", "custom_vjp_call",
-    "custom_vjp_call_jaxpr", "pjit", "remat", "remat2", "scan", "while",
+    "custom_vjp_call_jaxpr", "jit", "remat", "remat2", "scan", "while",
     # elementwise math
     "abs", "add", "and", "cbrt", "ceil", "clamp", "cos", "cosh", "div",
     "eq", "erf", "exp", "expm1", "floor", "ge", "gt", "integer_pow",
@@ -83,7 +83,7 @@ _MATMUL_PRIMS = ("dot_general", "conv_general_dilated")
 def _iter_eqns(jaxpr):
     """Yield every eqn in a (closed) jaxpr, recursing into sub-jaxprs
     carried in eqn params (pjit/scan/while/cond/custom_vjp...)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in inner.eqns:

@@ -5,8 +5,8 @@ compilation artifacts and schedule choices are managed, measured state
 Three layers:
 
 - :mod:`cache` — the persistent XLA compilation cache as a first-class
-  knob: ``configure(dir)`` / the ``DL4J_TPU_COMPILE_CACHE`` env var wire
-  ``jax_compilation_cache_dir`` through ``ModelServer``/``serve()``/
+  knob: ``configure(dir)`` / jax's own ``JAX_COMPILATION_CACHE_DIR``
+  (which overrides any dir passed in code) wire the cache through ``ModelServer``/``serve()``/
   ``fit``/``resilient_fit``; hit/miss traffic lands in
   ``dl4j_xla_cache_hits_total`` / ``_misses_total`` and on RunReport.
   The dir may be a SHARED mount (NFS/GCS-style): ``configure`` stamps

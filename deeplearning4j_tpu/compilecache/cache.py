@@ -10,9 +10,17 @@ module owns the knob:
 
 - :func:`configure` points jax at a cache dir AND zeroes both floors,
   so every executable — tiny CI ladder buckets included — persists.
-- The dir resolves from an explicit argument or the
-  ``DL4J_TPU_COMPILE_CACHE`` env var; reconfiguration mid-process works
-  (jax latches its cache handle on first use; we reset it).
+- **``JAX_COMPILATION_CACHE_DIR`` wins.** jax reads its own variable at
+  import; where it is set, the cache IS that directory: no code path
+  here sets another, and an explicit ``compile_cache_dir=`` argument is
+  ignored with one log line (an operator who placed the cache from
+  outside must find every entry there). Where it is unset, the dir
+  comes from the explicit argument, and with neither the cache stays
+  off — the library has no default dir, so a test run compiles cold.
+  The entry programs (``chip_smoke.py``, ``bench.py``) export the
+  variable themselves, to a fixed ``<checkout>/.jax_cache``, before
+  jax is imported. Reconfiguration mid-process works (jax latches its
+  cache handle on first use; we reset it).
 - Hit/miss traffic is observable: jax emits
   ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` monitoring
   events only while a cache is active, and observability.metrics folds
@@ -49,15 +57,19 @@ federation"). What makes the dir safe to share:
 from __future__ import annotations
 
 import json
+import logging
 import os
 import threading
 import uuid
 from typing import Optional
 
-#: env var consulted by :func:`ensure_configured` (fit / resilient_fit /
-#: serving all call it) — set it and every run in the process shares one
-#: persistent cache without touching call sites
-ENV_VAR = "DL4J_TPU_COMPILE_CACHE"
+logger = logging.getLogger(__name__)
+
+#: jax's own variable, consulted by :func:`configure` (fit /
+#: resilient_fit / serving all reach it) — export it and every run in
+#: the process, and every child it spawns, shares that one persistent
+#: cache; it overrides any dir passed in code
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 #: the shared-dir marker :func:`configure` publishes atomically — its
 #: presence (and valid JSON-ness) is the "this dir is a dl4j compile
@@ -67,6 +79,7 @@ META_SCHEMA_VERSION = 1
 
 _lock = threading.Lock()
 _configured: Optional[str] = None
+_ignored: set = set()  # explicit dirs already reported as overridden
 
 
 def atomic_publish(directory: str, name: str, payload: dict) -> str:
@@ -143,42 +156,64 @@ def cache_dir() -> Optional[str]:
     return _configured
 
 
+def _pinned_dir(path: Optional[str]) -> Optional[str]:
+    """The dir ``$JAX_COMPILATION_CACHE_DIR`` pins the cache to (None
+    when unset). jax latched the variable into its config at import, so
+    a value exported later would name a dir jax never writes — raise
+    rather than report a cache that is not there."""
+    pinned = os.environ.get(ENV_VAR)
+    if not pinned:
+        return None
+    import jax
+    if jax.config.jax_compilation_cache_dir != pinned:
+        raise RuntimeError(
+            f"{ENV_VAR}={pinned!r} was exported after jax was imported "
+            f"(jax holds {jax.config.jax_compilation_cache_dir!r}); set "
+            "it in the environment before the process imports jax")
+    pinned = os.path.abspath(pinned)
+    if path and os.path.abspath(path) != pinned and path not in _ignored:
+        _ignored.add(path)
+        logger.warning("compile_cache_dir=%r ignored: %s pins the cache "
+                       "to %r", path, ENV_VAR, pinned)
+    return pinned
+
+
 def configure(path: Optional[str] = None) -> Optional[str]:
-    """Activate the persistent compilation cache at *path* (or at
-    ``$DL4J_TPU_COMPILE_CACHE`` when *path* is None). Idempotent per
-    dir; switching dirs mid-process resets jax's latched cache handle
-    so the new dir takes effect. Returns the active dir (None when
-    neither source names one — the knob stays off, nothing changes).
+    """Activate the persistent compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` when that is set (*path* is then
+    ignored, with one log line, and jax's dir is left exactly as jax
+    read it), else at *path*. Idempotent per dir; switching dirs
+    mid-process resets jax's latched cache handle so the new dir takes
+    effect. Returns the active dir (None when neither source names one
+    — the knob stays off, nothing changes).
 
     Also installs the compile/cache-event listener so hit/miss counters
     are live even before the first ``install_runtime_metrics`` call.
     """
     global _configured
-    resolved = path or os.environ.get(ENV_VAR) or None
+    pinned = _pinned_dir(path)
+    resolved = pinned or (os.path.abspath(path) if path else None)
     if not resolved:
         return _configured
-    resolved = os.path.abspath(resolved)
     with _lock:
         if _configured == resolved:
             return _configured
         os.makedirs(resolved, exist_ok=True)
         _stamp_shared_dir(resolved)
         import jax
-        jax.config.update("jax_compilation_cache_dir", resolved)
         # stock floors (1s compile time, min serialized bytes) exist to
         # keep huge fleets from caching trivia; here they would skip
         # every CI-sized program — zero both so the cache is honest at
-        # any model size
+        # any model size (jax reads them per write: no reset needed)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        try:
+        if not pinned:
+            jax.config.update("jax_compilation_cache_dir", resolved)
             # jax latches its cache handle on first compile; without a
             # reset, configuring after any jit ran would silently keep
             # the old (or no) cache
             from jax._src import compilation_cache as _cc
             _cc.reset_cache()
-        except Exception:
-            pass
         from deeplearning4j_tpu.observability.metrics import \
             _ensure_compile_listener
         _ensure_compile_listener()
@@ -187,29 +222,28 @@ def configure(path: Optional[str] = None) -> Optional[str]:
 
 
 def deactivate() -> None:
-    """Turn the persistent cache back off: unset the dir, restore jax's
-    stock floors, and drop the latched cache handle so later compiles
-    run cold again. Process-global, like :func:`configure` — meant for
-    tear-down (tests, embedding hosts), not the serving hot path."""
+    """Turn the persistent cache back off: restore jax's stock floors
+    and, unless ``$JAX_COMPILATION_CACHE_DIR`` pins it, unset the dir
+    and drop the latched cache handle so later compiles run cold again.
+    Process-global, like :func:`configure` — meant for tear-down
+    (tests, embedding hosts), not the serving hot path."""
     global _configured
     with _lock:
         if _configured is None:
             return
         import jax
-        jax.config.update("jax_compilation_cache_dir", None)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        try:
+        if not os.environ.get(ENV_VAR):
+            jax.config.update("jax_compilation_cache_dir", None)
             from jax._src import compilation_cache as _cc
             _cc.reset_cache()
-        except Exception:
-            pass
         _configured = None
 
 
 def ensure_configured() -> Optional[str]:
-    """Env-driven activation: a no-op unless ``DL4J_TPU_COMPILE_CACHE``
-    is set (or :func:`configure` already ran). The fit loops, the
-    supervisor and the server call this at run start, so exporting one
-    env var turns on warm boots across the whole stack."""
+    """Env-driven activation: a no-op unless
+    ``JAX_COMPILATION_CACHE_DIR`` is set (or :func:`configure` already
+    ran). The fit loops call this at run start, so exporting jax's own
+    variable turns on warm boots across the whole stack."""
     return configure(None)

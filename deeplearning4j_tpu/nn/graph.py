@@ -798,7 +798,7 @@ class ComputationGraph:
                     self._train_step, self.params, self.state,
                     self.opt_state, it, inputs, labels, fmasks, lmasks, rng)
                 self.flops_per_step = cost["flops"] or None
-            except Exception:
+            except NotImplementedError:
                 # meshed/wrapped steps have no .lower
                 self.flops_per_step = None
         _goodput.observe_flops(self.flops_per_step)
@@ -819,7 +819,7 @@ class ComputationGraph:
         if isinstance(data, (DataSet, MultiDataSet)):
             _obs_metrics.install_runtime_metrics()
             from deeplearning4j_tpu.compilecache import ensure_configured
-            ensure_configured()  # DL4J_TPU_COMPILE_CACHE env var, if set
+            ensure_configured()  # JAX_COMPILATION_CACHE_DIR, if set
             ledger = _goodput.start_run("fit", net=self)
             from deeplearning4j_tpu.observability import (
                 distributed as _obs_dist)
@@ -843,7 +843,7 @@ class ComputationGraph:
         device_prefetch = self._resolve_device_prefetch(device_prefetch)
         _obs_metrics.install_runtime_metrics()
         from deeplearning4j_tpu.compilecache import ensure_configured
-        ensure_configured()  # DL4J_TPU_COMPILE_CACHE env var, if set
+        ensure_configured()  # JAX_COMPILATION_CACHE_DIR, if set
         tracer = _get_tracer()
         ledger = _goodput.start_run("fit", net=self)
         from deeplearning4j_tpu.observability import distributed as _obs_dist

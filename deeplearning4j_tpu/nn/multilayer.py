@@ -596,7 +596,7 @@ class MultiLayerNetwork:
                     self._train_step, self.params, self.state,
                     self.opt_state, it, x, y, fmask, lmask, rng)
                 self.flops_per_step = cost["flops"] or None
-            except Exception:
+            except NotImplementedError:
                 # meshed/wrapped steps have no .lower
                 self.flops_per_step = None
         _goodput.observe_flops(self.flops_per_step)
@@ -666,7 +666,7 @@ class MultiLayerNetwork:
         device_prefetch = self._resolve_device_prefetch(device_prefetch)
         _obs_metrics.install_runtime_metrics()
         from deeplearning4j_tpu.compilecache import ensure_configured
-        ensure_configured()  # DL4J_TPU_COMPILE_CACHE env var, if set
+        ensure_configured()  # JAX_COMPILATION_CACHE_DIR, if set
         tracer = _get_tracer()
         ledger = _goodput.start_run("fit", net=self)
         from deeplearning4j_tpu.observability import distributed as _obs_dist

@@ -538,9 +538,7 @@ def _runtime_collector() -> List[MetricFamily]:
             mem.add(rss, {"device": "process", "kind": "host_rss_bytes"})
     if mem.samples:
         fams.append(mem)
-    update_memory_watermark()
-    with _runtime_lock:
-        peaks = dict(_MEM_PEAK)
+    peaks = memory_watermarks()
     if peaks:
         peak_fam = MetricFamily(
             "dl4j_device_memory_peak_bytes", "gauge",
@@ -605,23 +603,30 @@ def update_memory_watermark() -> None:
     Called at scrape time, epoch boundaries and goodput run start/end —
     deliberately NOT per-step (a /proc read per step would eat the
     trace-overhead budget)."""
-    reported = False
+    import jax
+
     try:
-        import jax
-        for d in jax.local_devices():
-            stats = d.memory_stats()
-            if not stats:
-                continue
-            peak = stats.get("peak_bytes_in_use", stats.get("bytes_in_use"))
-            if peak is None:
-                continue
-            dev = f"{d.platform}:{d.id}"
-            with _runtime_lock:
-                if peak > _MEM_PEAK.get(dev, 0.0):
-                    _MEM_PEAK[dev] = float(peak)
-            reported = True
-    except Exception:
-        pass
+        devices = jax.local_devices()
+    except RuntimeError:
+        # no backend for THIS process (e.g. a router beside the process
+        # that owns the chip): the host number below is the honest one
+        devices = []
+    reported = False
+    # past that, no blanket except: a backend that cannot report (CPU)
+    # returns None, and a device this process owns that RAISES must not
+    # be silently replaced by the host-RSS fallback
+    for d in devices:
+        stats = d.memory_stats()
+        if not stats:
+            continue
+        peak = stats.get("peak_bytes_in_use", stats.get("bytes_in_use"))
+        if peak is None:
+            continue
+        dev = f"{d.platform}:{d.id}"
+        with _runtime_lock:
+            if peak > _MEM_PEAK.get(dev, 0.0):
+                _MEM_PEAK[dev] = float(peak)
+        reported = True
     if reported:
         return
     hwm = _host_hwm_bytes() or _host_rss_bytes()
@@ -631,12 +636,20 @@ def update_memory_watermark() -> None:
                 _MEM_PEAK["process"] = float(hwm)
 
 
+def memory_watermarks() -> Dict[str, float]:
+    """High-water mark per source, sampled now: ``"<platform>:<id>"``
+    keys come from ``Device.memory_stats()``, the single ``"process"``
+    key is the host-RSS fallback of a backend that reports nothing."""
+    update_memory_watermark()
+    with _runtime_lock:
+        return dict(_MEM_PEAK)
+
+
 def memory_watermark_bytes() -> Optional[float]:
     """The single-number memory watermark (max across devices) the
     RunReport records. Samples current state first."""
-    update_memory_watermark()
-    with _runtime_lock:
-        return max(_MEM_PEAK.values()) if _MEM_PEAK else None
+    peaks = memory_watermarks()
+    return max(peaks.values()) if peaks else None
 
 
 def install_runtime_metrics(
@@ -693,7 +706,7 @@ def compile_stats() -> dict:
 def cache_stats() -> dict:
     """Persistent-compilation-cache traffic since process start:
     ``{"hits", "misses"}``. Both 0 unless a cache dir is configured
-    (compilecache.configure / DL4J_TPU_COMPILE_CACHE) — jax only emits
+    (compilecache.configure / JAX_COMPILATION_CACHE_DIR) — jax only emits
     the hit/miss events while a cache is active."""
     with _runtime_lock:
         return dict(_CACHE)

@@ -21,14 +21,17 @@ Three backends behind the ``causal_mha`` registry seam:
 - ``pallas``: a flash-style forward — online softmax over kv tiles with
   the running (m, l, acc) carried in f32 VMEM scratch, causal tile-skip
   above the diagonal, the [t, t] score matrix never materialized to HBM.
-  Guarded by ``attention_supported`` per PERF.md §1: hand-DMA'd streaming
-  kernels measured 13-73 GB/s against XLA's ~700-800 GB/s on this stack,
-  so the kernel only runs where its VMEM-residency win (no score-matrix
-  traffic) is structural, and it silently delegates to the xla backend
-  everywhere else — the same graceful fallback as ops/fused_block.py. The
-  backward recomputes through the xla_dot formulation (a custom_vjp):
-  PERF.md §1's verdict makes a hand-written flash backward a net loss
-  here, and grad parity against the xla backend is what
+  Guarded by ``attention_supported``: hand-DMA'd streaming kernels
+  measured 13-73 GB/s against XLA's ~700-800 GB/s on the previous
+  software stack (PERF.md Findings, rounds 3-5; not re-measured on the
+  installed one), so the kernel only runs where its VMEM-residency win
+  (no score-matrix traffic) is structural, and it silently delegates to
+  the xla backend everywhere else — the same graceful fallback as
+  ops/fused_block.py. It compiles under Mosaic on the v5e and matches
+  ``xla_dot`` there (``chip_smoke.py``, kernels phase). The backward
+  recomputes through the xla_dot formulation (a custom_vjp): that same
+  old verdict priced a hand-written flash backward as a net loss, and
+  grad parity against the xla backend is what
   tests/test_backend_equivalence.py pins either way.
 
 Incremental decode (``decode_mha`` + ``extend_cache``): a step's new-token
@@ -168,8 +171,9 @@ _VMEM_BUDGET = 12 * 1024 * 1024
 def attention_supported(q, k, v, q_start=0) -> bool:
     """Does the flash kernel cover this configuration? Decode steps
     (traced/nonzero q_start, tiny tq) stay on xla — a per-step GEMV has no
-    score-matrix traffic to save and PERF.md §1's per-grid-step overhead
-    (~15-25us) would dominate it."""
+    score-matrix traffic to save and the per-grid-step overhead measured
+    on the previous stack (~15-25us, PERF.md Findings) would dominate
+    it."""
     if not (isinstance(q_start, int) and q_start == 0):
         return False
     if q.dtype not in (jnp.bfloat16, jnp.float32):
@@ -285,8 +289,9 @@ def _flash_vjp_fwd(q, k, v):
 
 def _flash_vjp_bwd(res, g):
     # backward recomputes through the batched-dot formulation (module
-    # docstring): PERF.md §1 prices a hand flash-backward as a net loss
-    # on this stack, and the dot lowering keeps the recompute on the MXU
+    # docstring): the previous stack's Pallas DMA rates priced a hand
+    # flash-backward as a net loss, and the dot lowering keeps the
+    # recompute on the MXU
     q, k, v = res
     _, vjp = jax.vjp(
         lambda a, b_, c: _causal_mha_dot(a, b_, c, 0), q, k, v)

@@ -30,6 +30,7 @@ dWx/db/dx are recovered by the caller with dense matmuls.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -215,6 +216,39 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
         dp_ref[:] = dp_scr[:].astype(cd)
 
 
+# Mosaic's default scoped-VMEM limit on a v5e is 16 MiB, and the backward
+# at b=256, n=512 in bf16 asks for 16.06 MiB (measured on the chip:
+# RESOURCE_EXHAUSTED by 64 KiB). So each call states what it needs, and
+# never less than the default. The largest request the chip has been
+# seen to grant is that shape's 35.8 MiB; the cap is not verified.
+_VMEM_DEFAULT = 16 * 1024 * 1024
+_VMEM_CAP = 96 * 1024 * 1024
+
+
+def _compiler_params(arrays, scratch):
+    """``vmem_limit_bytes`` from the call's own operands. Every 3-d
+    ``[T, ...]`` array streams one ``[1, ...]`` block per grid step and
+    every other array is one block at a constant index; Pallas
+    double-buffers both kinds. ``scratch`` is resident once, and the
+    gate math keeps about four f32 temporaries as wide as the widest
+    streamed block ([b, 4n]) live. 25% headroom over that sum for
+    Mosaic's own spills."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def nbytes(shape, dtype):       # the last dim pads to a 128 lane
+        return (math.prod(shape[:-1]) * -(-shape[-1] // 128) * 128
+                * jnp.dtype(dtype).itemsize)
+
+    blocks = [nbytes(a.shape[1:] if len(a.shape) == 3 else a.shape, a.dtype)
+              for a in arrays]
+    widest = max(math.prod(a.shape[1:]) for a in arrays
+                 if len(a.shape) == 3)
+    need = (2 * sum(blocks) + sum(nbytes(r.shape, r.dtype) for r in scratch)
+            + 4 * widest * 4)
+    limit = min(_VMEM_CAP, max(_VMEM_DEFAULT, need + need // 4))
+    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
+
+
 def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -236,6 +270,9 @@ def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
     full = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     fixed2 = lambda r, cdim: pl.BlockSpec(
         (r, cdim), lambda t: (0, 0), memory_space=pltpu.VMEM)
+    args = (xz_t, mask_t[:, :, None], h0, c0, Wh, p)
+    scratch = [pltpu.VMEM((b, n), jnp.float32),
+               pltpu.VMEM((b, n), jnp.float32)]
     return pl.pallas_call(
         _fwd_kernel,
         grid=(T,),
@@ -254,12 +291,10 @@ def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
             t_block(n), t_block(n),                          # h_prev, c_prev
         ),
         out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((b, n), jnp.float32),
-            pltpu.VMEM((b, n), jnp.float32),
-        ],
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(args + out_shapes, scratch),
         interpret=_interpret(),
-    )(xz_t, mask_t[:, :, None], h0, c0, Wh, p)
+    )(*args)
 
 
 def _bwd_call(res, cts):
@@ -286,6 +321,11 @@ def _bwd_call(res, cts):
         (1, b, width), lambda i: (T - 1 - i, 0, 0), memory_space=pltpu.VMEM)
     fixed2 = lambda r, cdim: pl.BlockSpec(
         (r, cdim), lambda i: (0, 0), memory_space=pltpu.VMEM)
+    args = (G, hprev, cprev, mask_t[:, :, None], Wh, p, dy, dhT, dcT)
+    scratch = [pltpu.VMEM((b, n), jnp.float32),
+               pltpu.VMEM((b, n), jnp.float32),
+               pltpu.VMEM((n, n4), jnp.float32),
+               pltpu.VMEM((3, n), jnp.float32)]
     return pl.pallas_call(
         _bwd_kernel,
         grid=(T,),
@@ -306,14 +346,10 @@ def _bwd_call(res, cts):
             fixed2(3, n),                                    # dp
         ),
         out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((b, n), jnp.float32),
-            pltpu.VMEM((b, n), jnp.float32),
-            pltpu.VMEM((n, n4), jnp.float32),
-            pltpu.VMEM((3, n), jnp.float32),
-        ],
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(args + out_shapes, scratch),
         interpret=_interpret(),
-    )(G, hprev, cprev, mask_t[:, :, None], Wh, p, dy, dhT, dcT)
+    )(*args)
 
 
 @jax.custom_vjp

@@ -253,18 +253,6 @@ def initialize(coordinator_address: str | None = None,
                 "runtime is already up; returning the existing cluster's "
                 "process_info()", RuntimeWarning, stacklevel=2)
         return process_info()
-    # The CPU backend refuses cross-process computations unless an
-    # explicit collectives implementation is configured; wire up gloo
-    # over the coordination service so multi-process CPU fleets (tests,
-    # chaos drills, laptops) can actually train. User settings (env or
-    # config) win; TPU/GPU backends ignore the flag entirely.
-    try:
-        from jax._src import xla_bridge as _xb
-        if _xb.CPU_COLLECTIVES_IMPLEMENTATION.value == "none":
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-    except Exception:  # pragma: no cover - older jaxlib without gloo
-        pass
     kwargs = {}
     if coordinator_address or os.environ.get("JAX_COORDINATOR_ADDRESS"):
         kwargs["coordinator_address"] = (

@@ -30,13 +30,6 @@ def make_mesh(axes: dict[str, int] | None = None, devices=None) -> Mesh:
 
 
 def compat_shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` with per-shard replication checking off, on any
-    supported jax: the top-level entry point (and its ``check_vma``
-    kwarg) only exists on newer releases — older ones ship it as
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with per-shard replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -38,10 +38,11 @@ Per-worker environment (set on top of the parent's):
   count constant across shrinks (``K = total_devices // size``) so a
   resumed smaller fleet sees the same mesh axis size and restores the
   old layout via the elastic resharding path bit-identically;
-- ``DL4J_TPU_COMPILE_CACHE`` when ``compile_cache_dir`` is set — the
-  fleet shares one persistent XLA compile cache (the shared-dir
-  backend, compilecache/cache.py), so only the first worker ever pays
-  a fresh compile and relaunched workers boot warm.
+- ``JAX_COMPILATION_CACHE_DIR`` when ``compile_cache_dir`` is set and
+  the launcher's own environment does not already carry it (the
+  inherited value wins, compilecache/cache.py) — the fleet shares one
+  persistent XLA compile cache, so only the first worker ever pays a
+  fresh compile and relaunched workers boot warm.
 
 The launcher itself never imports jax: worker argv construction is
 delegated to a ``build_argv(size, rank, coordinator)`` callable, so the
@@ -189,8 +190,10 @@ class FleetLauncher:
             # the whole fleet shares ONE persistent compile cache
             # (compilecache/cache.py shared-dir backend): worker 0's
             # compiles are every later worker's — and every RELAUNCHED
-            # worker's — cache hits
-            env["DL4J_TPU_COMPILE_CACHE"] = self.compile_cache_dir
+            # worker's — cache hits. An inherited value wins: it pins
+            # the cache for every process (compilecache/cache.py)
+            env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                           self.compile_cache_dir)
         if self.total_devices:
             if self.total_devices % size:
                 raise ValueError(
@@ -327,7 +330,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--compile-cache-dir", default=None,
                     help="shared persistent XLA compile cache dir "
                          "exported to every worker as "
-                         "DL4J_TPU_COMPILE_CACHE")
+                         "JAX_COMPILATION_CACHE_DIR")
     ap.add_argument("--grace", type=float, default=30.0)
     ap.add_argument("cmd", nargs=argparse.REMAINDER,
                     help="worker command (after --)")

@@ -222,8 +222,9 @@ class SupervisorConfig:
     #: to flight_<instance>.json in checkpoint_dir on SIGTERM, NaN
     #: rollback, preemption and crash
     flight_recorder: bool = True
-    #: persistent XLA compilation cache dir for this run (None = the
-    #: DL4J_TPU_COMPILE_CACHE env var, if set) — a restarted replacement
+    #: persistent XLA compilation cache dir for this run; the
+    #: JAX_COMPILATION_CACHE_DIR env var, if set, overrides it (and is
+    #: what None falls back to) — a restarted replacement
     #: process pointed at the same dir recompiles ~nothing
     compile_cache_dir: Optional[str] = None
     #: route recovery decisions through the cross-process consensus
@@ -786,7 +787,7 @@ class TrainingSupervisor:
 
         _obs_metrics.install_runtime_metrics()
         from deeplearning4j_tpu.compilecache import configure as _cc_configure
-        _cc_configure(cfg.compile_cache_dir)  # falls back to env var
+        _cc_configure(cfg.compile_cache_dir)  # the env var overrides
         self._setup_coordination()
         # attach (and stay attached after run(): a post-run scrape still
         # reports this job's recovery counters alongside serving/compile
@@ -945,7 +946,7 @@ class TrainingSupervisor:
             find_latest_checkpoint)
         _obs_metrics.install_runtime_metrics()
         from deeplearning4j_tpu.compilecache import configure as _cc_configure
-        _cc_configure(cfg.compile_cache_dir)  # falls back to env var
+        _cc_configure(cfg.compile_cache_dir)  # the env var overrides
         self._setup_coordination()
         self.stats.attach_to_registry(
             labels={"job": os.path.basename(

@@ -98,7 +98,8 @@ class ModelServer:
                  slos=None, scheduler=None):
         from deeplearning4j_tpu.compilecache import cache as _ccache
         # Cold-start engine (SERVING.md "Cold start & AOT"):
-        # - compile_cache_dir (or $DL4J_TPU_COMPILE_CACHE) activates the
+        # - compile_cache_dir (or $JAX_COMPILATION_CACHE_DIR, which
+        #   overrides it) activates the
         #   persistent compilation cache, so a second boot of the same
         #   config deserializes executables instead of compiling;
         # - aot_manifest names (or True auto-locates, in the cache dir)

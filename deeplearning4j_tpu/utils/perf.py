@@ -34,33 +34,41 @@ def peak_flops(device) -> float | None:
     return None
 
 
+def _lower(jitted_step, args):
+    if not hasattr(jitted_step, "lower"):
+        raise NotImplementedError(
+            "cost analysis needs a plain jitted step (meshed nets wrap it)")
+    return jitted_step.lower(*args)
+
+
+def _cost_numbers(cost) -> dict:
+    cost = cost or {}
+    return {"flops": float(cost.get("flops", 0.0)),
+            "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
+
+
 def xla_step_cost(jitted_step, *args) -> dict:
     """Cost-model numbers for one compiled call of ``jitted_step(*args)``:
     {"flops", "bytes_accessed"}. Raises NotImplementedError for wrapped
     (non-jit) steps such as the meshed trainers."""
-    if not hasattr(jitted_step, "lower"):
-        raise NotImplementedError(
-            "cost analysis needs a plain jitted step (meshed nets wrap it)")
-    cost = jitted_step.lower(*args).compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    cost = cost or {}
-    return {"flops": float(cost.get("flops", 0.0)),
-            "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
+    return _cost_numbers(
+        _lower(jitted_step, args).compile().cost_analysis())
 
 
 def xla_step_cost_lowered(jitted_step, *args) -> dict:
     """Like :func:`xla_step_cost` but from the *lowered* (pre-backend-
-    compile) module — pure tracing, no second XLA compilation, so the
-    fit loops can auto-derive per-step FLOPs at step-build time without
-    doubling compile cost. Same return shape; flops matches the compiled
-    path on jax 0.4.x. Raises NotImplementedError for wrapped steps."""
-    if not hasattr(jitted_step, "lower"):
-        raise NotImplementedError(
-            "cost analysis needs a plain jitted step (meshed nets wrap it)")
-    cost = jitted_step.lower(*args).cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    cost = cost or {}
-    return {"flops": float(cost.get("flops", 0.0)),
-            "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
+    compile) module where the backend can cost one — pure tracing, no
+    second XLA compilation, so the fit loops can auto-derive per-step
+    FLOPs at step-build time without doubling compile cost. The TPU's
+    PJRT plug-in cannot (``Lowered.cost_analysis()`` is None there), so
+    on it the numbers come from the compiled executable: jax hands back
+    the one the step's own dispatch compiled, which makes this free once
+    the step has run and one real compile where it has not (the chunked
+    fit path derives from the single step; a later ``fit_batch`` then
+    reuses that executable). Same return shape. Raises
+    NotImplementedError for wrapped steps."""
+    lowered = _lower(jitted_step, args)
+    cost = lowered.cost_analysis()
+    if cost is None:
+        cost = lowered.compile().cost_analysis()
+    return _cost_numbers(cost)

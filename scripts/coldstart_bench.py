@@ -1,6 +1,13 @@
 """Cold-start benchmark: time-to-first-reply and compile cost across
 three boot arms, plus the autotuned-vs-default serving schedule.
 
+**CPU control-flow drill (ROADMAP D3).** This script defaults
+``JAX_PLATFORMS`` to ``cpu`` and starts several device-owning child
+processes, which one TPU chip cannot host (a chip belongs to one
+process). Its counts hold on any backend; its timings are CPU
+wall-clock and say nothing about a TPU. The chip check is
+``chip_smoke.py``.
+
 Each arm boots a FRESH python process (``--child`` mode) that builds
 the bench MLP, starts a warmed ``ModelServer``, fires one /predict,
 then replays the bucket ladder to count steady-state compiles:

@@ -2,6 +2,13 @@
 one FrontDoorRouter, warm-boot compile counts off the shared cache, and
 bit-identical decode failover across REAL host processes.
 
+**CPU control-flow drill (ROADMAP D3).** This script defaults
+``JAX_PLATFORMS`` to ``cpu`` and starts several device-owning child
+processes, which one TPU chip cannot host (a chip belongs to one
+process). Its counts hold on any backend; its timings are CPU
+wall-clock and say nothing about a TPU. The chip check is
+``chip_smoke.py``.
+
 The receipt behind BUDGETS.json ``cross_host_serving``
 (CROSSHOST_SERVE_r01.json). Four arms, one topology — a parent-process
 ``FrontDoorRouter`` federating 2 child ``ModelServer`` processes
@@ -9,7 +16,7 @@ The receipt behind BUDGETS.json ``cross_host_serving``
 /predict + /decode, pushing heartbeats to the router:
 
 - **warm boot**: both hosts share one persistent-compile-cache dir
-  (``DL4J_TPU_COMPILE_CACHE`` semantics); host 0 pays the fresh XLA
+  (``compile_cache_dir=`` semantics); host 0 pays the fresh XLA
   compiles, host 1 must boot with ``fresh_compiles == 0`` — the PR 10
   cold/warm arms measured ACROSS hosts instead of across boots.
 - **scaling**: closed-loop /predict load through the router at 1 host,

@@ -1,5 +1,12 @@
 """Fleet observability demo: 3 worker processes, one merged view.
 
+**CPU control-flow drill (ROADMAP D3).** This script defaults
+``JAX_PLATFORMS`` to ``cpu`` and starts several device-owning child
+processes, which one TPU chip cannot host (a chip belongs to one
+process). Its counts hold on any backend; its timings are CPU
+wall-clock and say nothing about a TPU. The chip check is
+``chip_smoke.py``.
+
 Proves the cross-process observability plane end to end:
 
 1. The parent starts a UIServer (the aggregator) on an ephemeral port.

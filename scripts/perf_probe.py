@@ -19,7 +19,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-from bench import (_peak_flops, bench_goodput_overhead, bench_host_loop,
+from bench import (bench_goodput_overhead, bench_host_loop,
                    bench_input_pipeline, bench_mixed_precision,
                    bench_trace_overhead, calibrated_step_time)
 
@@ -267,7 +267,8 @@ def main():
         bytes_ = float(cost.get("bytes accessed", 0.0))
         out["step_gflops"] = round(flops / 1e9, 2)
         out["step_gbytes"] = round(bytes_ / 1e9, 3)
-        peak = _peak_flops(jax.devices()[0])
+        from deeplearning4j_tpu.utils.perf import peak_flops
+        peak = peak_flops(jax.devices()[0])
         if peak and sec_per_step > 0:
             out["mfu"] = round(flops / sec_per_step / peak, 4)
             out["achieved_tflops"] = round(flops / sec_per_step / 1e12, 1)

@@ -10,7 +10,7 @@ Runs ``lower().compile()`` / the server's own warm-up seam over:
 - the net's jitted train step at ``--train-batch`` (``--train``).
 
 The artifacts land in ``--cache-dir`` (the dir you point
-``DL4J_TPU_COMPILE_CACHE`` / ``ModelServer(compile_cache_dir=...)`` at)
+``JAX_COMPILATION_CACHE_DIR`` / ``ModelServer(compile_cache_dir=...)`` at)
 next to ``aot_manifest.json`` describing exactly what was compiled —
 shapes, dtypes, ladder, mesh axes, model fingerprint. A later boot
 whose config drifted from the manifest warns and falls back to lazy

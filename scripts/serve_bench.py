@@ -1,6 +1,13 @@
 """Closed-loop serving load generator: before/after for the
 continuous-batching inference runtime.
 
+**CPU control-flow drill (ROADMAP D3).** This script defaults
+``JAX_PLATFORMS`` to ``cpu`` and starts several device-owning child
+processes, which one TPU chip cannot host (a chip belongs to one
+process). Its counts hold on any backend; its timings are CPU
+wall-clock and say nothing about a TPU. The chip check is
+``chip_smoke.py``.
+
 Measures end-to-end HTTP rows/sec and latency percentiles for the MNIST
 MLP at client concurrency 1 / 8 / 64, against BOTH server designs:
 

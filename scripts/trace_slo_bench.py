@@ -1,6 +1,13 @@
 """Trace-stitching + SLO receipt: a stitched cross-process request
 waterfall and SLO attainment over the same federated load.
 
+**CPU control-flow drill (ROADMAP D3).** This script defaults
+``JAX_PLATFORMS`` to ``cpu`` and starts several device-owning child
+processes, which one TPU chip cannot host (a chip belongs to one
+process). Its counts hold on any backend; its timings are CPU
+wall-clock and say nothing about a TPU. The chip check is
+``chip_smoke.py``.
+
 The receipt behind BUDGETS.json ``slo`` (TRACE_SLO_r01.json). One
 topology — a parent-process ``FrontDoorRouter`` federating 2 child
 ``ModelServer`` processes (``--child-host`` mode), each pushing

@@ -1,6 +1,13 @@
 """SLO-aware traffic engine benchmark: open-loop flash-crowd load
 through the scheduling core, with the autoscaler closing the loop.
 
+**CPU control-flow drill (ROADMAP D3).** This script defaults
+``JAX_PLATFORMS`` to ``cpu`` and starts several device-owning child
+processes, which one TPU chip cannot host (a chip belongs to one
+process). Its counts hold on any backend; its timings are CPU
+wall-clock and say nothing about a TPU. The chip check is
+``chip_smoke.py``.
+
 The receipt behind BUDGETS.json ``traffic`` (TRAFFIC_r01.json). One
 topology, one storyline — a parent-process ``FrontDoorRouter``
 (front-door SchedulingCore: tenant quotas + deadline sheds) over REAL

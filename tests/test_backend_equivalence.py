@@ -137,6 +137,14 @@ class TestAttentionBackendEquivalence:
     def teardown_method(self):
         os.environ.pop("DL4J_TPU_PALLAS_INTERPRET", None)
 
+    @pytest.fixture(autouse=True)
+    def _f32_matmuls(self):
+        # these are f32-tolerance checks of dot lowerings against the
+        # multiply+reduce reference; a TPU's default f32 dot is one bf16
+        # pass (measured 3.7e-3 off on the v5e), so ask for real f32
+        with jax.default_matmul_precision("highest"):
+            yield
+
     def _pallas(self, q, k, v):
         return attn_ops._flash(q, k, v)
 

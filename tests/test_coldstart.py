@@ -10,6 +10,7 @@ import subprocess
 import sys
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -120,16 +121,54 @@ def test_run_report_carries_cache_and_coldstart_fields():
 
 
 # -------------------------------------------------------- cache configure
-def test_configure_env_var_and_idempotence(tmp_path, monkeypatch):
+def test_configure_explicit_dir_and_idempotence(tmp_path, monkeypatch):
+    monkeypatch.delenv(ccache.ENV_VAR, raising=False)
+    assert ccache.configure(None) is None     # no library default dir
     target = str(tmp_path / "xla-cache")
-    monkeypatch.setenv(ccache.ENV_VAR, target)
-    got = ccache.configure(None)
+    got = ccache.configure(target)
     assert got == os.path.abspath(target) and os.path.isdir(got)
     assert ccache.cache_dir() == got
-    # explicit arg beats the env var; reconfiguring is allowed
+    assert jax.config.jax_compilation_cache_dir == got
+    # reconfiguring is allowed, and idempotent per dir
     other = str(tmp_path / "other")
     assert ccache.configure(other) == os.path.abspath(other)
-    assert ccache.configure(other) == os.path.abspath(other)  # idempotent
+    assert ccache.configure(other) == os.path.abspath(other)
+
+
+def test_env_var_exported_after_jax_import_is_loud(tmp_path, monkeypatch):
+    # jax latched its (unset) variable at import: a late export names a
+    # dir jax will never write, so configure must not report it active
+    monkeypatch.delenv(ccache.ENV_VAR, raising=False)
+    monkeypatch.setenv(ccache.ENV_VAR, str(tmp_path / "late"))
+    with pytest.raises(RuntimeError, match="before the process imports"):
+        ccache.configure(None)
+    assert not (tmp_path / "late").exists()
+
+
+def test_jax_compilation_cache_dir_pins_the_cache(tmp_path):
+    """The placement contract, in a real process: with jax's own
+    variable exported the cache IS that dir — an explicit
+    compile_cache_dir= is ignored with one log line, jax's config is
+    left as jax read it, and entries land nowhere else."""
+    pinned, other = str(tmp_path / "pinned"), str(tmp_path / "other")
+    code = (
+        "import logging, os, jax, jax.numpy as jnp\n"
+        "logging.basicConfig()\n"
+        "from deeplearning4j_tpu.compilecache import cache as c\n"
+        f"assert c.configure({other!r}) == {pinned!r}\n"
+        f"assert c.configure({other!r}) == {pinned!r}\n"
+        f"assert jax.config.jax_compilation_cache_dir == {pinned!r}\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(4)).block_until_ready()\n"
+        "c.deactivate()\n"
+        f"assert jax.config.jax_compilation_cache_dir == {pinned!r}\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=_REPO, capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", ccache.ENV_VAR: pinned})
+    assert proc.returncode == 0, proc.stderr[-800:]
+    assert proc.stderr.count("ignored") == 1, proc.stderr[-800:]
+    assert any(f.endswith("-cache") for f in os.listdir(pinned))
+    assert not os.path.exists(other)
 
 
 # ----------------------------------------------------- manifest validation

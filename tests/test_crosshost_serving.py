@@ -510,17 +510,21 @@ def test_decode_step_unknown_session_404_and_bad_op_400():
 
 
 # ------------------------------------------------------- launcher wiring
-def test_fleet_launcher_exports_shared_cache_env():
+def test_fleet_launcher_exports_shared_cache_env(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     from deeplearning4j_tpu.resilience.launcher import FleetLauncher
     lead = FleetLauncher(lambda size, rank, coord: ["true"],
                          compile_cache_dir="/mnt/shared/xla")
     env = lead._worker_env(2, 0, 0)
-    assert env["DL4J_TPU_COMPILE_CACHE"] == "/mnt/shared/xla"
-    # unset -> absent, so workers fall back to their own local default
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/mnt/shared/xla"
+    # an inherited value pins the cache: the launcher never overrides it
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/pinned/outside")
+    assert lead._worker_env(2, 0, 0)[
+        "JAX_COMPILATION_CACHE_DIR"] == "/pinned/outside"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    # unset -> absent, so workers compile cold
     off = FleetLauncher(lambda size, rank, coord: ["true"])
-    env2 = {k: v for k, v in off._worker_env(2, 0, 0).items()
-            if k == "DL4J_TPU_COMPILE_CACHE" and k not in os.environ}
-    assert not env2
+    assert "JAX_COMPILATION_CACHE_DIR" not in off._worker_env(2, 0, 0)
 
 
 # ----------------------------------------------------------- budget gate
