@@ -23,6 +23,7 @@ import numpy as np
 from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.observability import goodput as _goodput
 from deeplearning4j_tpu.observability import metrics as _obs_metrics
+from deeplearning4j_tpu.observability import opindex as _opindex
 from deeplearning4j_tpu.observability.trace import get_tracer as _get_tracer
 from deeplearning4j_tpu.datasets.iterator import DataSetIterator
 from deeplearning4j_tpu.nn.conf.graph_conf import ComputationGraphConfiguration
@@ -352,14 +353,16 @@ class ComputationGraph:
                 xs = [local[i] for i in self.conf.vertex_inputs[v]]
                 if self.vertex_kind[v] == "layer":
                     layer = self._layer_by_name[v]
-                    y, s_new = layer.apply(
-                        p_sub.get(v, {}), s_sub.get(v, {}), xs[0],
-                        train=True, rng=rngs.get(v), mask=None)
+                    with _opindex.scope(v):
+                        y, s_new = layer.apply(
+                            p_sub.get(v, {}), s_sub.get(v, {}), xs[0],
+                            train=True, rng=rngs.get(v), mask=None)
                     if s_new:
                         ns[v] = s_new
                     local[v] = y
                 else:
-                    local[v] = conf.forward(*xs, masks=[None] * len(xs))
+                    with _opindex.scope(v):
+                        local[v] = conf.forward(*xs, masks=[None] * len(xs))
             return {v: local[v] for v in outs}, ns
 
         out_acts, ns = jax.checkpoint(run_span)(p_sub, s_sub, ext, rngs)
@@ -414,8 +417,9 @@ class ComputationGraph:
             if name in plans:
                 from deeplearning4j_tpu.nn import fusion as _fusion
                 fb = plans[name]
-                y, bn_state_new = _fusion.execute_fused_tail(
-                    fb, self, params, state, acts)
+                with _opindex.scope(name):
+                    y, bn_state_new = _fusion.execute_fused_tail(
+                        fb, self, params, state, acts)
                 acts[name] = y
                 masks[name] = None
                 new_state[fb.bn] = bn_state_new
@@ -442,14 +446,16 @@ class ComputationGraph:
                     rng, lrng = jax.random.split(rng)
                 p = params.get(name, {})
                 s = state.get(name, {})
-                y, s_new = layer.apply(p, s, xs[0], train=train, rng=lrng,
-                                       mask=in_masks[0])
+                with _opindex.scope(name):
+                    y, s_new = layer.apply(p, s, xs[0], train=train,
+                                           rng=lrng, mask=in_masks[0])
                 if s_new:
                     new_state[name] = s_new
                 acts[name] = y
                 masks[name] = layer.feed_forward_mask(in_masks[0])
             else:
-                acts[name] = conf.forward(*xs, masks=in_masks)
+                with _opindex.scope(name):
+                    acts[name] = conf.forward(*xs, masks=in_masks)
                 masks[name] = conf.feed_forward_mask(*in_masks)
         return acts, saved_inputs, masks, new_state
 
@@ -486,21 +492,26 @@ class ComputationGraph:
             if lrng is not None:
                 lrng, this_rng = jax.random.split(lrng)
             lm = None if lmasks is None else lmasks[i]
-            if getattr(layer, "loss_uses_state", False):
-                s_out = state.get(name, {})
-                l = layer.loss(params.get(name, {}), xs[0], labels[i],
-                               train=train, rng=this_rng, mask=lm, state=s_out)
-                if train and hasattr(layer, "update_centers"):
-                    new_state[name] = layer.update_centers(
-                        s_out, jax.lax.stop_gradient(xs[0]), labels[i],
-                        mask=lm)
-            else:
-                l = layer.loss(params.get(name, {}), xs[0], labels[i],
-                               train=train, rng=this_rng, mask=lm)
+            # the output layer's own scope holds its matmul and data loss;
+            # "loss" is what no layer owns: regularization and the sum
+            with _opindex.scope(name):
+                if getattr(layer, "loss_uses_state", False):
+                    s_out = state.get(name, {})
+                    l = layer.loss(params.get(name, {}), xs[0], labels[i],
+                                   train=train, rng=this_rng, mask=lm,
+                                   state=s_out)
+                    if train and hasattr(layer, "update_centers"):
+                        new_state[name] = layer.update_centers(
+                            s_out, jax.lax.stop_gradient(xs[0]), labels[i],
+                            mask=lm)
+                else:
+                    l = layer.loss(params.get(name, {}), xs[0], labels[i],
+                                   train=train, rng=this_rng, mask=lm)
             total = l if total is None else total + l
-        for layer in self.layers:
-            if layer.name in params:
-                total = total + layer.regularization(params[layer.name])
+        with _opindex.scope("loss"):
+            for layer in self.layers:
+                if layer.name in params:
+                    total = total + layer.regularization(params[layer.name])
         return total, new_state
 
     # ---------------------------------------------------------- train step
@@ -749,9 +760,12 @@ class ComputationGraph:
                 lmasks = None
             it = jnp.asarray(self.iteration, jnp.int32)
         with tracer.span("device_step"):
+            args = (self.params, self.state, self.opt_state, it, inputs,
+                    labels, fmasks, lmasks, rng)
+            if self._mesh is None:  # a meshed step registers its inner jit
+                _opindex.register(self._train_step, args, args[4:8])
             self.params, self.state, self.opt_state, score = self._train_step(
-                self.params, self.state, self.opt_state, it, inputs, labels,
-                fmasks, lmasks, rng)
+                *args)
         self.iteration += 1
         self.score_value = score
         self.last_batch_examples = mds.num_examples
@@ -988,10 +1002,11 @@ class ComputationGraph:
             it0 = jnp.asarray(self.iteration, jnp.int32)
             steps = jnp.arange(len(batches), dtype=jnp.int32)
         with tracer.span("device_step", steps=len(batches)):
+            args = (self.params, self.state, self.opt_state, it0,
+                    self._rng_key, steps, (inputs, labels, fmasks, lmasks))
+            _opindex.register(jitted, args, args[6])
             (self.params, self.state, self.opt_state, self._rng_key,
-             scores) = jitted(self.params, self.state, self.opt_state, it0,
-                              self._rng_key, steps,
-                              (inputs, labels, fmasks, lmasks))
+             scores) = jitted(*args)
         start = self.iteration
         self.iteration += len(batches)
         self.score_value = scores[-1]

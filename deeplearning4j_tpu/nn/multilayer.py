@@ -33,6 +33,7 @@ from deeplearning4j_tpu.datasets.iterator import (
 from deeplearning4j_tpu.nn.conf.core import MultiLayerConfiguration
 from deeplearning4j_tpu.observability import goodput as _goodput
 from deeplearning4j_tpu.observability import metrics as _obs_metrics
+from deeplearning4j_tpu.observability import opindex as _opindex
 from deeplearning4j_tpu.observability.trace import get_tracer as _get_tracer
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf import layers as layer_confs
@@ -288,11 +289,12 @@ class MultiLayerNetwork:
             ns = {}
             for k, j in enumerate(range(i, end)):
                 ly = self.layers[j]
-                if self.preprocessors[j] is not None:
-                    x = self.preprocessors[j](x)
-                x, s_new = ly.apply(p_sub.get(ly.name, {}),
-                                    s_sub.get(ly.name, {}), x, train=train,
-                                    rng=rngs[k], mask=fmask)
+                with _opindex.scope(ly.name):
+                    if self.preprocessors[j] is not None:
+                        x = self.preprocessors[j](x)
+                    x, s_new = ly.apply(p_sub.get(ly.name, {}),
+                                        s_sub.get(ly.name, {}), x,
+                                        train=train, rng=rngs[k], mask=fmask)
                 fmask = ly.feed_forward_mask(fmask)
                 if s_new:
                     ns[ly.name] = s_new
@@ -320,14 +322,16 @@ class MultiLayerNetwork:
                 i = end
                 continue
             layer = self.layers[i]
-            if self.preprocessors[i] is not None:
-                x = self.preprocessors[i](x)
             lrng = None
             if rng is not None:
                 rng, lrng = jax.random.split(rng)
             p = params.get(layer.name, {})
             s = state.get(layer.name, {})
-            x, s_new = layer.apply(p, s, x, train=train, rng=lrng, mask=fmask)
+            with _opindex.scope(layer.name):
+                if self.preprocessors[i] is not None:
+                    x = self.preprocessors[i](x)
+                x, s_new = layer.apply(p, s, x, train=train, rng=lrng,
+                                       mask=fmask)
             fmask = layer.feed_forward_mask(fmask)
             if s_new:
                 new_state[layer.name] = s_new
@@ -344,24 +348,28 @@ class MultiLayerNetwork:
         h, new_state = self._forward(params, state, x, train=train, rng=rng_fwd,
                                      fmask=fmask, to_layer=len(self.layers) - 1)
         out_layer = self.layers[-1]
-        if self.preprocessors[-1] is not None:
-            h = self.preprocessors[-1](h)
         p_out = params.get(out_layer.name, {})
-        if getattr(out_layer, "loss_uses_state", False):
-            s_out = state.get(out_layer.name, {})
-            data_loss = out_layer.loss(p_out, h, labels, train=train,
-                                       rng=lrng, mask=lmask, state=s_out)
-            if train and hasattr(out_layer, "update_centers"):
-                new_state[out_layer.name] = out_layer.update_centers(
-                    s_out, jax.lax.stop_gradient(h), labels, mask=lmask)
-        else:
-            data_loss = out_layer.loss(p_out, h, labels, train=train,
-                                       rng=lrng, mask=lmask)
-        reg = jnp.zeros((), data_loss.dtype)
-        for layer in self.layers:
-            if layer.name in params:
-                reg = reg + layer.regularization(params[layer.name])
-        return data_loss + reg, new_state
+        # the output layer's own scope holds its matmul and data loss;
+        # "loss" is what no layer owns: regularization and the sum
+        with _opindex.scope(out_layer.name):
+            if self.preprocessors[-1] is not None:
+                h = self.preprocessors[-1](h)
+            if getattr(out_layer, "loss_uses_state", False):
+                s_out = state.get(out_layer.name, {})
+                data_loss = out_layer.loss(p_out, h, labels, train=train,
+                                           rng=lrng, mask=lmask, state=s_out)
+                if train and hasattr(out_layer, "update_centers"):
+                    new_state[out_layer.name] = out_layer.update_centers(
+                        s_out, jax.lax.stop_gradient(h), labels, mask=lmask)
+            else:
+                data_loss = out_layer.loss(p_out, h, labels, train=train,
+                                           rng=lrng, mask=lmask)
+        with _opindex.scope("loss"):
+            reg = jnp.zeros((), data_loss.dtype)
+            for layer in self.layers:
+                if layer.name in params:
+                    reg = reg + layer.regularization(params[layer.name])
+            return data_loss + reg, new_state
 
     # ---------------------------------------------------------- train step
     def _resolve_remat(self) -> tuple:
@@ -621,8 +629,12 @@ class MultiLayerNetwork:
             lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
             it = jnp.asarray(self.iteration, jnp.int32)
         with tracer.span("device_step"):
+            args = (self.params, self.state, self.opt_state, it, x, y, fmask,
+                    lmask, rng)
+            if self._mesh is None:  # a meshed step registers its inner jit
+                _opindex.register(self._train_step, args, args[4:8])
             self.params, self.state, self.opt_state, score = self._train_step(
-                self.params, self.state, self.opt_state, it, x, y, fmask, lmask, rng)
+                *args)
         self.iteration += 1
         self.score_value = score
         self.last_batch_examples = ds.num_examples
@@ -801,9 +813,11 @@ class MultiLayerNetwork:
             it0 = jnp.asarray(self.iteration, jnp.int32)
             steps = jnp.arange(len(batches), dtype=jnp.int32)
         with tracer.span("device_step", steps=len(batches)):
+            args = (self.params, self.state, self.opt_state, it0,
+                    self._rng_key, steps, (xs, ys, fmask, lmask))
+            _opindex.register(jitted, args, args[6])
             (self.params, self.state, self.opt_state, self._rng_key,
-             scores) = jitted(self.params, self.state, self.opt_state, it0,
-                              self._rng_key, steps, (xs, ys, fmask, lmask))
+             scores) = jitted(*args)
         start = self.iteration
         self.iteration += len(batches)
         self.score_value = scores[-1]

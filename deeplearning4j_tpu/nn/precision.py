@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.updater import apply_layer_updates
+from deeplearning4j_tpu.observability import opindex
 
 #: reserved top-level opt_state key holding {"scale", "good_steps"}
 LOSS_SCALE_KEY = "_loss_scale"
@@ -112,8 +113,9 @@ def build_step_fn(loss_fn, layers, gc, lr_scale):
         def step_fn(params, state, opt_state, it, *data_args):
             (score, new_state), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, state, *data_args)
-            new_params, new_opt = apply_layer_updates(
-                layers, gc, params, grads, opt_state, it, lr_scale)
+            with opindex.scope("update"):
+                new_params, new_opt = apply_layer_updates(
+                    layers, gc, params, grads, opt_state, it, lr_scale)
             return new_params, new_state, new_opt, score
 
         return step_fn
@@ -128,25 +130,28 @@ def build_step_fn(loss_fn, layers, gc, lr_scale):
             loss, new_state = loss_fn(p, s, *a)
             # aux carries the TRUE loss: the published score must not be
             # a scaled value, and the NaN sentinel keys off it
-            return loss * scale.astype(loss.dtype), (loss, new_state)
+            with opindex.scope("loss"):
+                scaled = loss * scale.astype(loss.dtype)
+            return scaled, (loss, new_state)
 
         (_, (score, new_state)), grads = jax.value_and_grad(
             scaled_loss, has_aux=True)(params, state, *data_args)
-        inv = (1.0 / scale).astype(master)
-        grads = jax.tree_util.tree_map(
-            lambda g: g.astype(master) * inv, grads)
-        finite = all_finite(grads)
-        new_params, new_opt = apply_layer_updates(
-            layers, gc, params, grads, opt_state, it, lr_scale)
-        # skip-step: a non-finite gradient selects every param and
-        # optimizer slot back to its pre-step value BIT-IDENTICALLY
-        # (jnp.where on a scalar predicate is an exact select)
-        new_params = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(finite, n, o), new_params, params)
-        new_opt = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(finite, n, o), new_opt, opt_state)
-        new_opt[LOSS_SCALE_KEY] = _next_scale_state(ls, finite, mode,
-                                                    policy)
+        with opindex.scope("update"):
+            inv = (1.0 / scale).astype(master)
+            grads = jax.tree_util.tree_map(
+                lambda g: g.astype(master) * inv, grads)
+            finite = all_finite(grads)
+            new_params, new_opt = apply_layer_updates(
+                layers, gc, params, grads, opt_state, it, lr_scale)
+            # skip-step: a non-finite gradient selects every param and
+            # optimizer slot back to its pre-step value BIT-IDENTICALLY
+            # (jnp.where on a scalar predicate is an exact select)
+            new_params = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(finite, n, o), new_params, params)
+            new_opt = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(finite, n, o), new_opt, opt_state)
+            new_opt[LOSS_SCALE_KEY] = _next_scale_state(ls, finite, mode,
+                                                        policy)
         return new_params, new_state, new_opt, score
 
     return step_fn

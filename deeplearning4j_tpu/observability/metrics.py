@@ -426,8 +426,11 @@ _RUNTIME_INSTALLED_ON: Optional[MetricsRegistry] = None
 _STEPS = {"count": 0.0, "per_sec": 0.0, "dispatch_lag_s": 0.0}
 # memory high-water marks, updated on every watermark sample
 # (render-time scrape, observe_rate, goodput run start/end — never on
-# the per-step hot path): device key -> peak bytes_in_use seen
+# the per-step hot path): device key -> peak bytes_in_use seen (arrays),
+# and device key + RESERVED_SUFFIX -> largest bytes_in_use +
+# bytes_reserved of one sample (arrays and the running program's scratch)
 _MEM_PEAK: dict = {}
+RESERVED_SUFFIX = "+reserved"
 
 
 def _on_jax_event_duration(event: str, duration: float, **kw):
@@ -543,8 +546,11 @@ def _runtime_collector() -> List[MetricFamily]:
         peak_fam = MetricFamily(
             "dl4j_device_memory_peak_bytes", "gauge",
             "High-water memory mark per device: max peak_bytes_in_use "
-            "from Device.memory_stats() across watermark samples; CPU "
-            "falls back to the process VmHWM RSS high-water mark")
+            "from Device.memory_stats() across watermark samples "
+            "(arrays), and under <device>+reserved the largest "
+            "bytes_in_use + bytes_reserved of one sample (arrays and "
+            "program scratch); CPU falls back to the process VmHWM RSS "
+            "high-water mark")
         for dev, v in sorted(peaks.items()):
             peak_fam.add(v, {"device": dev})
         fams.append(peak_fam)
@@ -623,9 +629,15 @@ def update_memory_watermark() -> None:
         if peak is None:
             continue
         dev = f"{d.platform}:{d.id}"
+        # the runtime keeps program scratch in a book of its own; the two
+        # peak at different moments, so their sum is taken per sample
+        held = stats.get("bytes_in_use", 0) + stats.get("bytes_reserved", 0)
         with _runtime_lock:
             if peak > _MEM_PEAK.get(dev, 0.0):
                 _MEM_PEAK[dev] = float(peak)
+            if "bytes_reserved" in stats and held > _MEM_PEAK.get(
+                    dev + RESERVED_SUFFIX, 0.0):
+                _MEM_PEAK[dev + RESERVED_SUFFIX] = float(held)
         reported = True
     if reported:
         return
@@ -637,19 +649,26 @@ def update_memory_watermark() -> None:
 
 
 def memory_watermarks() -> Dict[str, float]:
-    """High-water mark per source, sampled now: ``"<platform>:<id>"``
-    keys come from ``Device.memory_stats()``, the single ``"process"``
-    key is the host-RSS fallback of a backend that reports nothing."""
+    """High-water mark per source, sampled now. ``"<platform>:<id>"``
+    is the arrays-only peak (``peak_bytes_in_use`` of
+    ``Device.memory_stats()``: parameters, optimizer state, staged
+    batches). ``"<platform>:<id>+reserved"`` is the largest
+    ``bytes_in_use + bytes_reserved`` of one sample: arrays and the
+    scratch of the program that was running (activations, temporaries),
+    which is most of a training step's memory; a peak between two
+    samples is missed. The single ``"process"`` key is the host-RSS
+    fallback of a backend that reports nothing."""
     update_memory_watermark()
     with _runtime_lock:
         return dict(_MEM_PEAK)
 
 
 def memory_watermark_bytes() -> Optional[float]:
-    """The single-number memory watermark (max across devices) the
-    RunReport records. Samples current state first."""
-    peaks = memory_watermarks()
-    return max(peaks.values()) if peaks else None
+    """The single-number memory watermark (max across devices, arrays
+    only) the RunReport records. Samples current state first."""
+    peaks = [v for k, v in memory_watermarks().items()
+             if not k.endswith(RESERVED_SUFFIX)]
+    return max(peaks) if peaks else None
 
 
 def install_runtime_metrics(

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu.observability import opindex
+
 
 def replicate(mesh: Mesh, x):
     """Replicate a host value across the (possibly multi-process) mesh.
@@ -105,7 +107,9 @@ def shard_step(net, step_fn, mesh: Mesh, data_axis: str = "data"):
         fmask = shard_batch(mesh, data_axis, fmask)
         lmask = shard_batch(mesh, data_axis, lmask)
         rng = replicate(mesh, rng)
-        return jitted(params, state, opt_state, it, x, labels, fmask, lmask, rng)
+        args = (params, state, opt_state, it, x, labels, fmask, lmask, rng)
+        opindex.register(jitted, args, args[4:8])
+        return jitted(*args)
 
     return wrapped
 
@@ -157,8 +161,10 @@ def shard_step_multi(net, step_fn, mesh: Mesh, data_axis: str = "data"):
         if lmasks is not None:
             lmasks = [shard_batch(mesh, data_axis, m) for m in lmasks]
         rng = replicate(mesh, rng)
-        return jitted(params, state, opt_state, it, inputs, labels, fmasks,
-                      lmasks, rng)
+        args = (params, state, opt_state, it, inputs, labels, fmasks, lmasks,
+                rng)
+        opindex.register(jitted, args, args[4:8])
+        return jitted(*args)
 
     return wrapped
 

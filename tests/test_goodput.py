@@ -414,6 +414,32 @@ def test_memory_watermark_gauge_and_fallback(fresh_obs):
     assert "dl4j_device_memory_peak_bytes{" in reg.render_prometheus()
 
 
+def test_memory_watermarks_keep_arrays_and_reserved_apart(monkeypatch):
+    """The arrays-only peak keeps its key and meaning; the largest
+    in_use + reserved of one sample sits beside it."""
+    import jax
+
+    from deeplearning4j_tpu.observability import metrics
+
+    class FakeChip:
+        platform, id = "tpu", 7
+        samples = [{"peak_bytes_in_use": 5e9, "bytes_in_use": 4e9,
+                    "bytes_reserved": 9e9},
+                   {"peak_bytes_in_use": 6e9, "bytes_in_use": 6e9,
+                    "bytes_reserved": 1e9}]
+
+        def memory_stats(self):     # the program ends: scratch is freed
+            return self.samples.pop(0) if len(self.samples) > 1 \
+                else self.samples[0]
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [FakeChip()])
+    monkeypatch.setattr(metrics, "_MEM_PEAK", {})
+    metrics.update_memory_watermark()
+    peaks = metrics.memory_watermarks()
+    assert peaks == {"tpu:7": 6e9, "tpu:7+reserved": 13e9}
+    assert metrics.memory_watermark_bytes() == 6e9
+
+
 # ---------------------------------------------------- listener + UI
 
 
