@@ -190,12 +190,14 @@ def _lstm_args(t, b, n, seed=0):
     cd = jnp.bfloat16
     mask = (rng.random((t, b)) > 0.3).astype(np.float32)
     mask[0] = 1.0
-    return (jnp.asarray(rng.normal(0, 0.5, (t, b, 4 * n)), cd),
-            jnp.asarray(rng.normal(0, 0.5, (b, n)), cd),
-            jnp.asarray(rng.normal(0, 0.5, (b, n)), cd),
-            jnp.asarray(rng.normal(0, 0.05, (n, 4 * n)), cd),
-            jnp.asarray(rng.normal(0, 0.2, (3, n)), cd),
-            jnp.asarray(mask, cd))
+    xw, h0, c0, Wh, p = (
+        jnp.asarray(rng.normal(0, 0.5, (t, b, 4 * n)), cd),
+        jnp.asarray(rng.normal(0, 0.5, (b, n)), cd),
+        jnp.asarray(rng.normal(0, 0.5, (b, n)), cd),
+        jnp.asarray(rng.normal(0, 0.05, (n, 4 * n)), cd),
+        jnp.asarray(rng.normal(0, 0.2, (3, n)), cd))
+    bias = jnp.asarray(rng.normal(0, 0.3, (4 * n,)), cd)
+    return xw, bias, h0, c0, Wh, p, jnp.asarray(mask, cd)
 
 
 def _weighted_sum(y):
@@ -287,7 +289,7 @@ def phase_kernels(hidden=512, seq=64, batches=(32, 256), vocab=80,
         out["lstm"].append({"b": b, **_kernel_vs_reference(
             f"lstm b={b}", lstm_ops._lstm_seq_pallas,
             lstm_ops.lstm_sequence_xla, _lstm_loss,
-            _lstm_args(seq, b, hidden), (0, 1, 2, 3, 4))})
+            _lstm_args(seq, b, hidden), (0, 1, 2, 3, 4, 5))})
     out["flash"] = _check_flash_kernel(*flash_shape)
     return out
 

@@ -49,14 +49,16 @@ def _lstm_scan(params, x, h0, c0, mask, gate_act, cell_act):
     policy, so the recurrent matmul hits the MXU at full rate). The input
     projection for the whole sequence is one MXU matmul; the time loop is
     the ``lstm_sequence`` registry op (Pallas fused kernel on TPU, lax.scan
-    under autodiff elsewhere — the LSTMHelpers.java:57,271 seam)."""
+    under autodiff elsewhere — the LSTMHelpers.java:57,271 seam). The op
+    owns the bias: it adds it to the projection and returns its gradient,
+    which the Pallas backward kernel sums itself."""
     cd = x.dtype
     params = {k: v.astype(cd) for k, v in params.items()}
-    xz = jnp.einsum("btf,fg->btg", x, params["Wx"]) + params["b"]
-    xz_t = jnp.moveaxis(xz, 1, 0)  # [t, b, 4n]
+    xw = jnp.einsum("btf,fg->btg", x, params["Wx"])
+    xw_t = jnp.moveaxis(xw, 1, 0)  # [t, b, 4n]
     mask_t = None if mask is None else jnp.moveaxis(mask, 1, 0)  # [t, b]
     ys, hT, cT = ops.get("lstm_sequence")(
-        xz_t, h0, c0, params["Wh"], params["p"], mask_t,
+        xw_t, params["b"], h0, c0, params["Wh"], params["p"], mask_t,
         gate_act=gate_act, cell_act=cell_act)
     return jnp.moveaxis(ys, 0, 1), hT, cT
 

@@ -23,9 +23,13 @@ Gate math (Graves formulation with peepholes, order i, f, o, g):
     o = sigmoid(zo + p_o * c)           h = o * tanh(c)
 Masked steps carry (h, c) through unchanged and emit zero output.
 
-The op consumes the PRE-PROJECTED input xz[t] = x[t] @ Wx + b (one big
-MXU matmul outside the time loop); its backward emits dxz, from which
-dWx/db/dx are recovered by the caller with dense matmuls.
+The op consumes the PRE-PROJECTED input xw[t] = x[t] @ Wx (one big MXU
+matmul outside the time loop) and the bias b, which it adds itself:
+xz[t] = xw[t] + b. The Pallas backend adds b in plain jnp ahead of the
+forward kernel, so XLA keeps the add in the projection matmul's epilogue;
+its backward kernel emits dxz, from which the caller's autodiff recovers
+dWx/dx with dense matmuls, and db = sum over (t, rows) of dz, accumulated
+in VMEM beside dWh and dp. Nothing reads dxz a second time to reduce it.
 """
 
 from __future__ import annotations
@@ -63,14 +67,16 @@ def _cell_step(Wh, p, gate_act, cell_act, carry, inp):
 
 
 @registry.register("lstm_sequence", backend="xla")
-def lstm_sequence_xla(xz_t, h0, c0, Wh, p, mask_t, *, gate_act="sigmoid",
-                      cell_act="tanh"):
+def lstm_sequence_xla(xw_t, bias, h0, c0, Wh, p, mask_t, *,
+                      gate_act="sigmoid", cell_act="tanh"):
     """Time-major LSTM over pre-projected inputs.
 
-    xz_t: [t, b, 4n]; h0, c0: [b, n]; Wh: [n, 4n]; p: [3, n] peepholes;
-    mask_t: [t, b] or None. Returns (y_t [t, b, n], hT, cT)."""
+    xw_t: [t, b, 4n], x @ Wx without the bias; bias: [4n]; h0, c0: [b, n];
+    Wh: [n, 4n]; p: [3, n] peepholes; mask_t: [t, b] or None. Returns
+    (y_t [t, b, n], hT, cT)."""
     ga = act_mod.get(gate_act) if isinstance(gate_act, str) else gate_act
     ca = act_mod.get(cell_act) if isinstance(cell_act, str) else cell_act
+    xz_t = xw_t + bias
     step = partial(_cell_step, Wh, p, ga, ca)
     if mask_t is None:
         (hT, cT), ys = jax.lax.scan(
@@ -84,13 +90,13 @@ def lstm_sequence_xla(xz_t, h0, c0, Wh, p, mask_t, *, gate_act="sigmoid",
 _interpret = registry.pallas_interpret
 
 
-def _pallas_supported(xz_t, h0, gate_act, cell_act):
+def _pallas_supported(xw_t, h0, gate_act, cell_act):
     if gate_act != "sigmoid" or cell_act != "tanh":
         return False
-    if xz_t.dtype not in (jnp.bfloat16, jnp.float32):
+    if xw_t.dtype not in (jnp.bfloat16, jnp.float32):
         return False
     b, n = h0.shape[-2], h0.shape[-1]
-    sublane = 16 if xz_t.dtype == jnp.bfloat16 else 8
+    sublane = 16 if xw_t.dtype == jnp.bfloat16 else 8
     if n % 128 != 0 or b % sublane != 0:
         return False
     if not _interpret() and jax.default_backend() != "tpu":
@@ -145,8 +151,8 @@ def _fwd_kernel(xz_ref, m_ref, h0_ref, c0_ref, Wh_ref, p_ref,
 
 def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
                 dy_ref, dhT_ref, dcT_ref,
-                dxz_ref, dh0_ref, dc0_ref, dWh_ref, dp_ref,
-                dh_scr, dc_scr, dWh_scr, dp_scr):
+                dxz_ref, dh0_ref, dc0_ref, dWh_ref, dp_ref, db_ref,
+                dh_scr, dc_scr, dWh_scr, dp_scr, db_scr):
     import jax.experimental.pallas as pl
 
     pid = pl.program_id(0)
@@ -158,6 +164,7 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
         dc_scr[:] = dcT_ref[:].astype(jnp.float32)
         dWh_scr[:] = jnp.zeros_like(dWh_scr)
         dp_scr[:] = jnp.zeros_like(dp_scr)
+        db_scr[:] = jnp.zeros_like(db_scr)
 
     cd = G_ref.dtype
     n = hprev_ref.shape[-1]
@@ -203,6 +210,10 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
     dp_scr[0:1, :] += jnp.sum(dzi * c_prev, axis=0, keepdims=True)
     dp_scr[1:2, :] += jnp.sum(dzf * c_prev, axis=0, keepdims=True)
     dp_scr[2:3, :] += jnp.sum(dzo * c, axis=0, keepdims=True)
+    # db += column sums of the f32 dz (zero in masked rows), kept as eight
+    # sublane partials: the rows fold onto one [8, 4n] tile with
+    # whole-register adds, and the sublanes are reduced once, at the end
+    db_scr[:] += jnp.sum(dz.reshape(-1, 8, 4 * n), axis=0)
 
     dxz_ref[0] = dz_cd
     dh_scr[:] = dh_prev
@@ -214,6 +225,7 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
         dc0_ref[:] = dc_prev.astype(cd)
         dWh_ref[:] = dWh_scr[:].astype(cd)
         dp_ref[:] = dp_scr[:].astype(cd)
+        db_ref[:] = jnp.sum(db_scr[:], axis=0, keepdims=True).astype(cd)
 
 
 # Mosaic's default scoped-VMEM limit on a v5e is 16 MiB, and the backward
@@ -316,6 +328,7 @@ def _bwd_call(res, cts):
         sds((b, n), cd),       # dc0
         sds((n, n4), cd),      # dWh
         sds((3, n), cd),       # dp
+        sds((1, n4), cd),      # db
     )
     rev = lambda width: pl.BlockSpec(
         (1, b, width), lambda i: (T - 1 - i, 0, 0), memory_space=pltpu.VMEM)
@@ -325,7 +338,8 @@ def _bwd_call(res, cts):
     scratch = [pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((n, n4), jnp.float32),
-               pltpu.VMEM((3, n), jnp.float32)]
+               pltpu.VMEM((3, n), jnp.float32),
+               pltpu.VMEM((8, n4), jnp.float32)]
     return pl.pallas_call(
         _bwd_kernel,
         grid=(T,),
@@ -344,6 +358,7 @@ def _bwd_call(res, cts):
             fixed2(b, n), fixed2(b, n),                      # dh0, dc0
             fixed2(n, n4),                                   # dWh
             fixed2(3, n),                                    # dp
+            fixed2(1, n4),                                   # db
         ),
         out_shape=out_shapes,
         scratch_shapes=scratch,
@@ -353,37 +368,39 @@ def _bwd_call(res, cts):
 
 
 @jax.custom_vjp
-def _lstm_seq_pallas(xz_t, h0, c0, Wh, p, mask_t):
-    y, hT, cT, _, _, _ = _fwd_call(xz_t, h0, c0, Wh, p, mask_t)
-    return y, hT, cT
+def _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t):
+    return _lstm_seq_fwd(xw_t, bias, h0, c0, Wh, p, mask_t)[0]
 
 
-def _lstm_seq_fwd(xz_t, h0, c0, Wh, p, mask_t):
-    y, hT, cT, G, hprev, cprev = _fwd_call(xz_t, h0, c0, Wh, p, mask_t)
+def _lstm_seq_fwd(xw_t, bias, h0, c0, Wh, p, mask_t):
+    # the bias add stays in plain jnp, ahead of the kernel: XLA fuses it
+    # into the projection matmul that made xw_t
+    y, hT, cT, G, hprev, cprev = _fwd_call(xw_t + bias, h0, c0, Wh, p,
+                                           mask_t)
     return (y, hT, cT), (G, hprev, cprev, mask_t, Wh, p)
 
 
 def _lstm_seq_bwd(res, cts):
-    dxz, dh0, dc0, dWh, dp = _bwd_call(res, cts)
-    return dxz, dh0, dc0, dWh, dp, None
+    dxz, dh0, dc0, dWh, dp, db = _bwd_call(res, cts)
+    return dxz, db[0], dh0, dc0, dWh, dp, None
 
 
 _lstm_seq_pallas.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)
 
 
 @registry.register("lstm_sequence", backend="pallas")
-def lstm_sequence_pallas(xz_t, h0, c0, Wh, p, mask_t, *, gate_act="sigmoid",
-                         cell_act="tanh"):
+def lstm_sequence_pallas(xw_t, bias, h0, c0, Wh, p, mask_t, *,
+                         gate_act="sigmoid", cell_act="tanh"):
     """Pallas-fused LSTM sequence; silently delegates to the xla backend
     for configurations the kernel does not cover (non-sigmoid/tanh
     activations, unaligned shapes, non-TPU platforms) — the same graceful
     fallback the reference's helper loading performs when cuDNN is absent
     (ConvolutionLayer.java:69-76)."""
-    if not _pallas_supported(xz_t, h0, gate_act, cell_act):
-        return lstm_sequence_xla(xz_t, h0, c0, Wh, p, mask_t,
+    if not _pallas_supported(xw_t, h0, gate_act, cell_act):
+        return lstm_sequence_xla(xw_t, bias, h0, c0, Wh, p, mask_t,
                                  gate_act=gate_act, cell_act=cell_act)
     if mask_t is None:
-        mask_t = jnp.ones(xz_t.shape[:2], xz_t.dtype)
+        mask_t = jnp.ones(xw_t.shape[:2], xw_t.dtype)
     else:
-        mask_t = mask_t.astype(xz_t.dtype)
-    return _lstm_seq_pallas(xz_t, h0, c0, Wh, p, mask_t)
+        mask_t = mask_t.astype(xw_t.dtype)
+    return _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t)

@@ -24,27 +24,88 @@ from deeplearning4j_tpu.ops import lstm as lstm_ops
 
 def _data(t=5, b=8, n=128, dtype=jnp.float32, seed=0, masked=False):
     rng = np.random.default_rng(seed)
-    xz = jnp.asarray(rng.normal(0, 0.5, (t, b, 4 * n)), dtype)
+    xw = jnp.asarray(rng.normal(0, 0.5, (t, b, 4 * n)), dtype)
+    bias = jnp.asarray(rng.normal(0, 0.3, (4 * n,)), dtype)
     h0 = jnp.asarray(rng.normal(0, 0.5, (b, n)), dtype)
     c0 = jnp.asarray(rng.normal(0, 0.5, (b, n)), dtype)
     Wh = jnp.asarray(rng.normal(0, 0.2, (n, 4 * n)), dtype)
     p = jnp.asarray(rng.normal(0, 0.2, (3, n)), dtype)
     if masked:
         m = (rng.random((t, b)) > 0.3).astype(np.float32)
-        m[0] = 1.0  # keep step 0 alive for all examples
+        m[0] = 1.0  # keep step 0 alive for all examples...
+        m[:, -1] = 0.0  # ...but one: a fully masked row adds nothing to db
         mask = jnp.asarray(m, dtype)
     else:
         mask = jnp.ones((t, b), dtype)
-    return xz, h0, c0, Wh, p, mask
+    return xw, bias, h0, c0, Wh, p, mask
 
 
 def _loss_through(fn):
-    def loss(xz, h0, c0, Wh, p, mask):
-        y, hT, cT = fn(xz, h0, c0, Wh, p, mask)
+    def loss(xw, bias, h0, c0, Wh, p, mask):
+        y, hT, cT = (o.astype(jnp.float32)
+                     for o in fn(xw, bias, h0, c0, Wh, p, mask))
         w = jnp.cos(jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape)
         return (jnp.sum(y * w) + 2.0 * jnp.sum(jnp.sin(hT))
                 + 0.5 * jnp.sum(cT * cT))
     return loss
+
+
+GRAD_NAMES = ["dxw", "db", "dh0", "dc0", "dWh", "dp"]
+GRAD_ARGNUMS = tuple(range(len(GRAD_NAMES)))
+
+
+def _assert_close(got, want, dtype, err_msg=""):
+    """f32: elementwise. bf16: ``want`` is the f32 result on the same
+    rounded inputs, and the worst entry is held to a share of the largest
+    (rounding the result alone costs 0.4%; the worst seen is 0.55%)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4,
+                                   err_msg=err_msg)
+    else:
+        assert np.all(np.isfinite(got)), err_msg
+        scale = max(np.abs(want).max(), 1e-3)
+        assert np.abs(got - want).max() / scale < 0.02, err_msg
+
+
+def _lstm_net_loss(bidirectional=False, t=6, b=8, n=128, f=16):
+    """(params, loss(params)) of a Graves LSTM + RnnOutput net on a batch."""
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers_recurrent import (
+        GravesBidirectionalLSTM, GravesLSTM, RnnOutput)
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    lstm = GravesBidirectionalLSTM if bidirectional else GravesLSTM
+    conf = (NeuralNetConfiguration.builder().seed(3).list()
+            .layer(lstm(n_out=n, activation="tanh"))
+            .layer(RnnOutput(n_out=f, loss="mcxent", activation="softmax"))
+            .set_input_type(InputType.recurrent(f)).build())
+    net = MultiLayerNetwork(conf).init()
+    rng = np.random.default_rng(0)
+    x, y = (jnp.asarray(np.eye(f, dtype=np.float32)[rng.integers(0, f, (b, t))])
+            for _ in range(2))
+
+    def loss(params):
+        return net._loss(params, net.state, x, y, None, None, None)[0]
+
+    return net.params, loss
+
+
+def _reduce_sums_over(jaxpr, shapes):
+    """Every ``reduce_sum`` over an operand of one of ``shapes`` in
+    ``jaxpr`` and the jaxprs its equations carry, the inside of a
+    ``pallas_call`` excepted."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if (eqn.primitive.name == "reduce_sum"
+                and tuple(eqn.invars[0].aval.shape) in shapes):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _reduce_sums_over(sub, shapes)
+    return found
 
 
 class TestLstmBackendEquivalence:
@@ -59,8 +120,8 @@ class TestLstmBackendEquivalence:
     def _pallas(self, *args):
         return lstm_ops._lstm_seq_pallas(*args)
 
-    def _xla(self, xz, h0, c0, Wh, p, mask):
-        return lstm_ops.lstm_sequence_xla(xz, h0, c0, Wh, p, mask)
+    def _xla(self, *args):
+        return lstm_ops.lstm_sequence_xla(*args)
 
     @pytest.mark.parametrize("masked", [False, True])
     def test_forward_equivalence(self, masked):
@@ -72,41 +133,95 @@ class TestLstmBackendEquivalence:
         np.testing.assert_allclose(cT_p, cT_x, rtol=1e-5, atol=1e-5)
 
     @pytest.mark.parametrize("masked", [False, True])
-    def test_gradient_equivalence(self, masked):
-        # the CuDNNGradientChecks analogue: d/d{xz, h0, c0, Wh, p} must
-        # match between the hand-written backward kernel and autodiff of
-        # the scan path on identical inputs
-        args = _data(t=4, b=8, n=128, masked=masked)
-        g_p = jax.grad(_loss_through(self._pallas), argnums=(0, 1, 2, 3, 4))(
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_gradient_equivalence(self, dtype, masked):
+        # the CuDNNGradientChecks analogue: d/d{xw, b, h0, c0, Wh, p} must
+        # match between the hand-written backward kernel (which sums the
+        # bias gradient itself) and autodiff of the scan path on
+        # identical inputs
+        args = _data(t=4, b=16, n=128, dtype=dtype, masked=masked)
+        g_p = jax.grad(_loss_through(self._pallas), argnums=GRAD_ARGNUMS)(
             *args)
-        g_x = jax.grad(_loss_through(self._xla), argnums=(0, 1, 2, 3, 4))(
-            *args)
-        names = ["dxz", "dh0", "dc0", "dWh", "dp"]
-        for name, gp, gx in zip(names, g_p, g_x):
-            np.testing.assert_allclose(
-                gp, gx, rtol=2e-4, atol=2e-4,
-                err_msg=f"pallas/xla gradient mismatch for {name}")
+        # the scan in bf16 rounds every intermediate, so the kernel (f32
+        # inside) is held to the scan in f32 on the same rounded inputs
+        g_x = jax.grad(_loss_through(self._xla), argnums=GRAD_ARGNUMS)(
+            *(a.astype(jnp.float32) for a in args))
+        for name, gp, gx, a in zip(GRAD_NAMES, g_p, g_x, args):
+            assert gp.shape == a.shape and gp.dtype == dtype, name
+            _assert_close(gp, gx, dtype,
+                          f"pallas/xla gradient mismatch for {name}")
 
-    def test_wrapper_falls_back_when_unsupported(self):
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_bias_gradient_is_the_sum_of_dxw(self, masked):
+        # what autodiff would have reduced out of dxw, the kernel emits
+        args = _data(t=4, b=16, n=128, masked=masked)
+        dxw, db = jax.grad(_loss_through(self._pallas), argnums=(0, 1))(
+            *args)
+        np.testing.assert_allclose(db, jnp.sum(dxw, axis=(0, 1)),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_wrapper_falls_back_when_unsupported(self, masked):
         # unaligned hidden size -> the registered pallas backend must
-        # delegate to xla (the cuDNN-absent fallback path)
-        t, b, n = 3, 4, 24
-        rng = np.random.default_rng(1)
-        xz = jnp.asarray(rng.normal(0, 0.5, (t, b, 4 * n)), jnp.float32)
-        h0 = jnp.zeros((b, n), jnp.float32)
-        c0 = jnp.zeros((b, n), jnp.float32)
-        Wh = jnp.asarray(rng.normal(0, 0.2, (n, 4 * n)), jnp.float32)
-        p = jnp.zeros((3, n), jnp.float32)
-        y_w, hT_w, cT_w = lstm_ops.lstm_sequence_pallas(
-            xz, h0, c0, Wh, p, None)
-        y_x, hT_x, cT_x = lstm_ops.lstm_sequence_xla(
-            xz, h0, c0, Wh, p, None)
-        np.testing.assert_allclose(y_w, y_x, rtol=1e-6)
+        # delegate to xla (the cuDNN-absent fallback path), forward and
+        # every gradient, the bias's among them
+        xw, bias, h0, c0, Wh, p, mask = _data(t=3, b=4, n=24, seed=1,
+                                              masked=masked)
+        assert not lstm_ops._pallas_supported(xw, h0, "sigmoid", "tanh")
+        args = (xw, bias, h0, c0, Wh, p, mask if masked else None)
+        for out_w, out_x in zip(lstm_ops.lstm_sequence_pallas(*args),
+                                lstm_ops.lstm_sequence_xla(*args)):
+            np.testing.assert_allclose(out_w, out_x, rtol=1e-6)
+        g_w = jax.grad(_loss_through(lstm_ops.lstm_sequence_pallas),
+                       argnums=GRAD_ARGNUMS)(*args)
+        g_x = jax.grad(_loss_through(lstm_ops.lstm_sequence_xla),
+                       argnums=GRAD_ARGNUMS)(*args)
+        for name, gw, gx in zip(GRAD_NAMES, g_w, g_x):
+            np.testing.assert_allclose(gw, gx, rtol=1e-6, err_msg=name)
 
     def test_registry_prefers_pallas(self):
         from deeplearning4j_tpu.ops import registry
         assert set(registry.backends("lstm_sequence")) == {"pallas", "xla"}
         assert registry.get("lstm_sequence") is lstm_ops.lstm_sequence_pallas
+
+    @pytest.mark.parametrize("bidirectional", [False, True],
+                             ids=["GravesLSTM", "GravesBidirectionalLSTM"])
+    def test_layer_gradients_match_across_backends(self, bidirectional):
+        # through _lstm_scan: every parameter's gradient, each direction's
+        # bias among them, is the same whichever backend runs the loop
+        from deeplearning4j_tpu.ops import registry
+        params, loss = _lstm_net_loss(bidirectional)
+        grads = {}
+        for backend in ("pallas", "xla"):
+            with registry.use_backend(backend):
+                grads[backend] = jax.grad(loss)(params)
+        leaves_p, _ = jax.tree_util.tree_flatten_with_path(grads["pallas"])
+        leaves_x = jax.tree_util.tree_leaves(grads["xla"])
+        assert sum("'b'" in str(path) for path, _ in leaves_p) == (
+            3 if bidirectional else 2)
+        for (path, gp), gx in zip(leaves_p, leaves_x):
+            assert float(jnp.abs(gx).max()) > 0, path
+            np.testing.assert_allclose(gp, gx, rtol=2e-4, atol=2e-6,
+                                       err_msg=str(path))
+
+    @pytest.mark.parametrize("backend", ["pallas", "xla"])
+    def test_no_reduce_over_dxz_in_the_step(self, backend):
+        # the bias gradient of a GravesLSTM is the kernel's: nothing in
+        # the differentiated loss reads the [t, b, 4n] dxz again to sum
+        # it. Under autodiff of the scan (the xla backend) that reduce is
+        # how db is made, which also shows that the search finds one.
+        from deeplearning4j_tpu.ops import registry
+        t, b, n = 6, 8, 128
+        params, loss = _lstm_net_loss(t=t, b=b, n=n)
+        with registry.use_backend(backend):
+            jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+        hits = _reduce_sums_over(jaxpr, {(t, b, 4 * n), (b, t, 4 * n)})
+        assert ("pallas_call" in str(jaxpr)) == (backend == "pallas")
+        if backend == "pallas":
+            assert not hits, [str(e) for e in hits]
+        else:
+            assert hits
 
 
 from deeplearning4j_tpu.ops import attention as attn_ops  # noqa: E402
@@ -247,9 +362,9 @@ class TestLstmBackendEquivalenceTPU:
     def test_gradient_bf16_finite_and_close(self):
         args = _data(t=4, b=16, n=128, dtype=jnp.bfloat16, masked=True)
         g_p = jax.jit(jax.grad(_loss_through(lstm_ops._lstm_seq_pallas),
-                               argnums=(0, 3)))(*args)
+                               argnums=(0, 1, 4)))(*args)
         g_x = jax.jit(jax.grad(_loss_through(lstm_ops.lstm_sequence_xla),
-                               argnums=(0, 3)))(*args)
+                               argnums=(0, 1, 4)))(*args)
         for gp, gx in zip(g_p, g_x):
             gp = np.asarray(gp, np.float32)
             gx = np.asarray(gx, np.float32)

@@ -1,0 +1,103 @@
+"""Compiles for a TPU v5e that is described and not attached.
+
+The TPU's compiler is installed where the tests run, so what Mosaic or
+XLA would refuse on the chip (a slice off the tiling, too much VMEM) is
+refused here, and the compiled text shows which ops the step is made of.
+Nothing runs: no result and no time comes from this file. The topology
+is described inside a fixture, never at import, and every test that
+needs it lives in this one file (one process may load the TPU library).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu import zoo
+from deeplearning4j_tpu.observability import opindex
+from deeplearning4j_tpu.ops import lstm as lstm_ops
+
+# the char-RNN cell of BENCHMARK.json: 256 sequences of 1,024 characters
+T, B, N, VOCAB = 1024, 256, 512, 80
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def x32():
+    # the suite turns x64 on (conftest.py); the chip runs without it, and
+    # Mosaic refuses the i64 block indices that x64 traces
+    with jax.enable_x64(False):
+        yield
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32):
+    cd = jnp.bfloat16
+    seq = lambda width: jax.ShapeDtypeStruct((T, B, width), cd)
+    row = jax.ShapeDtypeStruct((B, N), cd)
+    Wh, p = (jax.ShapeDtypeStruct(s, cd) for s in ((N, 4 * N), (3, N)))
+    mask = jax.ShapeDtypeStruct((T, B), cd)
+    fwd_args = (seq(4 * N), row, row, Wh, p, mask)
+    bwd_args = ((seq(4 * N), seq(N), seq(N), mask, Wh, p),
+                (seq(N), row, row))
+    for call, args in ((lstm_ops._fwd_call, fwd_args),
+                       (lstm_ops._bwd_call, bwd_args)):
+        compiled = jax.jit(call).lower(*_on(one_chip, args)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    # dxz, dh0, dc0, dWh, dp, and the bias gradient as one row
+    db = jax.eval_shape(lstm_ops._bwd_call, *bwd_args)[-1]
+    assert (db.shape, db.dtype) == ((1, 4 * N), cd)
+
+
+def test_char_rnn_step_has_no_pass_over_dxz(one_chip, x32, monkeypatch):
+    # the step the benchmark's char-RNN cell runs. jax.default_backend()
+    # is the CPU here, so the support gate is steered; the lowering is for
+    # the described chip, where the kernels are Mosaic's.
+    monkeypatch.setattr(lstm_ops, "_pallas_supported", lambda *a: True)
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET", raising=False)
+    net = zoo.char_rnn(vocab_size=VOCAB, hidden=N, n_layers=2)
+    batch = jax.ShapeDtypeStruct((B, T, VOCAB), jnp.float32,
+                                 sharding=one_chip)
+    compiled = net._build_train_step().lower(
+        *_on(one_chip, (net.params, net.state, net.opt_state,
+                        jnp.zeros((), jnp.int32))),
+        batch, batch, None, None,
+        _on(one_chip, jax.random.PRNGKey(0))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    ops = {name: [(e["opcode"], e["op_name"])] + e["inner"]
+           for name, e in opindex.parse(text).items()}
+    for layer in ("layer_0", "layer_1"):
+        # the bias gradient is the backward kernel's: no reduce of this
+        # layer's backward is left (it read the 1.07 GB dxz to make 4 KB)
+        reduces = [n for n, inner in ops.items() if any(
+            op == "reduce" and f"transpose(jvp({layer}))" in name
+            for op, name in inner)]
+        assert not reduces, reduces
+        # and the forward bias add is still the projection matmul's
+        # epilogue, not a pass of its own over xz
+        adds = [n for n, inner in ops.items() if any(
+            op == "add" and name.endswith(f"/jvp({layer})/add")
+            for op, name in inner)]
+        assert len(adds) == 1, adds
+        assert any(op == "convolution" and name.endswith(
+            f"/jvp({layer})/btf,fg->btg/dot_general")
+            for op, name in ops[adds[0]]), ops[adds[0]]
