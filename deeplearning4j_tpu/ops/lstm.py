@@ -14,8 +14,12 @@ Why a Pallas kernel: the scan path issues ~10 small XLA ops per timestep
 and re-reads the recurrent weight Wh from HBM every step (measured 88us
 per timestep on a v5e for batch 32, hidden 512 — 0.7% MFU). The Pallas
 kernel runs the WHOLE time loop in one kernel launch with Wh and the
-(h, c) carry resident in VMEM, streaming xz[t] in and (y[t], saves[t])
-out — the cuDNN-class schedule.
+(h, c) carry resident in VMEM, streaming xz[t] in and the carried hidden
+state h[t], the gates G[t] and c_prev[t] out — the cuDNN-class schedule.
+h[t] is the one hidden stream: it is the output (times the mask, taken
+outside the kernel), and the backward reads it one block back as
+h_prev[t] = h[t-1], with h0 at t = 0. Without a mask (known at trace
+time) neither kernel takes a mask operand or does mask arithmetic.
 
 Gate math (Graves formulation with peepholes, order i, f, o, g):
     i = sigmoid(zi + p_i * c_prev)      f = sigmoid(zf + p_f * c_prev)
@@ -104,11 +108,12 @@ def _pallas_supported(xw_t, h0, gate_act, cell_act):
     return True
 
 
-def _fwd_kernel(xz_ref, m_ref, h0_ref, c0_ref, Wh_ref, p_ref,
-                y_ref, hT_ref, cT_ref, G_ref, hprev_ref, cprev_ref,
-                h_scr, c_scr):
+def _fwd_kernel(*refs, masked):
     import jax.experimental.pallas as pl
 
+    m_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
+    (xz_ref, h0_ref, c0_ref, Wh_ref, p_ref,
+     hk_ref, hT_ref, cT_ref, G_ref, cprev_ref, h_scr, c_scr) = refs
     t = pl.program_id(0)
     T = pl.num_programs(0)
 
@@ -132,29 +137,31 @@ def _fwd_kernel(xz_ref, m_ref, h0_ref, c0_ref, Wh_ref, p_ref,
     o = jax.nn.sigmoid(z[:, 2 * n:3 * n] + pvec[2:3, :] * c)
     h = o * jnp.tanh(c)
 
-    m = m_ref[0].astype(jnp.float32)
-    h_keep = jnp.where(m > 0, h, h_prev)
-    c_keep = jnp.where(m > 0, c, c_prev)
+    if masked:
+        keep = m_ref[0].astype(jnp.float32) > 0
+        h = jnp.where(keep, h, h_prev)
+        c = jnp.where(keep, c, c_prev)
 
-    y_ref[0] = (h * m).astype(cd)
+    hk_ref[0] = h.astype(cd)
     G_ref[0] = jnp.concatenate([i, f, o, g], axis=-1).astype(cd)
-    hprev_ref[0] = h_prev.astype(cd)
     cprev_ref[0] = c_prev.astype(cd)
-    h_scr[:] = h_keep
-    c_scr[:] = c_keep
+    h_scr[:] = h
+    c_scr[:] = c
 
     @pl.when(t == T - 1)
     def _():
-        hT_ref[:] = h_keep.astype(cd)
-        cT_ref[:] = c_keep.astype(cd)
+        hT_ref[:] = h.astype(cd)
+        cT_ref[:] = c.astype(cd)
 
 
-def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
-                dy_ref, dhT_ref, dcT_ref,
-                dxz_ref, dh0_ref, dc0_ref, dWh_ref, dp_ref, db_ref,
-                dh_scr, dc_scr, dWh_scr, dp_scr, db_scr):
+def _bwd_kernel(*refs, masked):
     import jax.experimental.pallas as pl
 
+    m_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
+    (G_ref, hk_ref, cprev_ref, h0_ref, Wh_ref, p_ref,
+     dhk_ref, dhT_ref, dcT_ref,
+     dxz_ref, dh0_ref, dc0_ref, dWh_ref, dp_ref, db_ref,
+     dh_scr, dc_scr, dWh_scr, dp_scr, db_scr) = refs
     pid = pl.program_id(0)
     T = pl.num_programs(0)
 
@@ -167,24 +174,29 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
         db_scr[:] = jnp.zeros_like(db_scr)
 
     cd = G_ref.dtype
-    n = hprev_ref.shape[-1]
+    n = cprev_ref.shape[-1]
     G = G_ref[0].astype(jnp.float32)
     i, f, o, g = (G[:, :n], G[:, n:2 * n], G[:, 2 * n:3 * n], G[:, 3 * n:])
-    h_prev = hprev_ref[0].astype(jnp.float32)
     c_prev = cprev_ref[0].astype(jnp.float32)
     pvec = p_ref[:].astype(jnp.float32)
-    m = m_ref[0].astype(jnp.float32)
 
     c = f * c_prev + i * g
     tc = jnp.tanh(c)
 
-    dh_next = dh_scr[:]
-    dc_next = dc_scr[:]
+    # what reaches the carried (h, c) of this step: a kept row hands it
+    # to the cell, a masked row hands it on to the step before
+    dh = dh_scr[:] + dhk_ref[0].astype(jnp.float32)
+    dc = dc_scr[:]
+    if masked:
+        keep = m_ref[0].astype(jnp.float32) > 0
+        dh_skip = jnp.where(keep, 0.0, dh)
+        dc_skip = jnp.where(keep, 0.0, dc)
+        dh = jnp.where(keep, dh, 0.0)
+        dc = jnp.where(keep, dc, 0.0)
 
-    dh = m * (dh_next + dy_ref[0].astype(jnp.float32))
     do = dh * tc
     dzo = do * o * (1.0 - o)
-    dc_in = m * dc_next + dh * o * (1.0 - tc * tc) + dzo * pvec[2:3, :]
+    dc_in = dc + dh * o * (1.0 - tc * tc) + dzo * pvec[2:3, :]
     di = dc_in * g
     df = dc_in * c_prev
     dg = dc_in * i
@@ -199,13 +211,19 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
     dh_prev = jax.lax.dot_general(
         dz_cd, Wh_ref[:], dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    dh_prev = dh_prev + (1.0 - m) * dh_next
-    dc_prev = dc_in * f + dzi * pvec[0:1, :] + dzf * pvec[1:2, :] \
-        + (1.0 - m) * dc_next
+    dc_prev = dc_in * f + dzi * pvec[0:1, :] + dzf * pvec[1:2, :]
+    if masked:
+        dh_prev = dh_prev + dh_skip
+        dc_prev = dc_prev + dc_skip
 
-    # dWh += h_prev^T @ dz  (contract the batch dim)
+    # dWh += h_prev^T @ dz  (contract the batch dim). h_prev of step t is
+    # the block the forward wrote at t - 1, which hk_ref holds here, and
+    # h0 at t = 0, the last grid step. A select, not two pl.when bodies:
+    # on the chip a branch round this matmul cost 0.65 us a grid step
+    # (b=256, n=512: 7.59 ms a call against 6.92)
+    h_prev = jnp.where(pid == T - 1, h0_ref[:], hk_ref[0])
     dWh_scr[:] += jax.lax.dot_general(
-        hprev_ref[0], dz_cd, dimension_numbers=(((0,), (0,)), ((), ())),
+        h_prev, dz_cd, dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     dp_scr[0:1, :] += jnp.sum(dzi * c_prev, axis=0, keepdims=True)
     dp_scr[1:2, :] += jnp.sum(dzf * c_prev, axis=0, keepdims=True)
@@ -232,7 +250,8 @@ def _bwd_kernel(G_ref, hprev_ref, cprev_ref, m_ref, Wh_ref, p_ref,
 # at b=256, n=512 in bf16 asks for 16.06 MiB (measured on the chip:
 # RESOURCE_EXHAUSTED by 64 KiB). So each call states what it needs, and
 # never less than the default. The largest request the chip has been
-# seen to grant is that shape's 35.8 MiB; the cap is not verified.
+# seen to grant is that shape's masked backward, 36.5 MiB (36.4 without
+# a mask; the forward asks 25.0); the cap is not verified.
 _VMEM_DEFAULT = 16 * 1024 * 1024
 _VMEM_CAP = 96 * 1024 * 1024
 
@@ -262,45 +281,49 @@ def _compiler_params(arrays, scratch):
 
 
 def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
+    """(hk, hT, cT, G, c_prev): hk[t] is the hidden state carried out of
+    step t, which is the step's output where the mask keeps the row.
+    ``mask_t`` None leaves the mask operand and its arithmetic out."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     T, b, n4 = xz_t.shape
     n = n4 // 4
     cd = xz_t.dtype
+    masked = mask_t is not None
     sds = jax.ShapeDtypeStruct
     out_shapes = (
-        sds((T, b, n), cd),    # y
+        sds((T, b, n), cd),    # hk
         sds((b, n), cd),       # hT
         sds((b, n), cd),       # cT
         sds((T, b, n4), cd),   # G (gates i,f,o,g)
-        sds((T, b, n), cd),    # h_prev per step
         sds((T, b, n), cd),    # c_prev per step
     )
     t_block = lambda width: pl.BlockSpec(
         (1, b, width), lambda t: (t, 0, 0), memory_space=pltpu.VMEM)
-    full = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     fixed2 = lambda r, cdim: pl.BlockSpec(
         (r, cdim), lambda t: (0, 0), memory_space=pltpu.VMEM)
-    args = (xz_t, mask_t[:, :, None], h0, c0, Wh, p)
+    args = (xz_t, h0, c0, Wh, p)
+    in_specs = [
+        t_block(n4),                                         # xz
+        fixed2(b, n), fixed2(b, n),                          # h0, c0
+        fixed2(n, n4),                                       # Wh
+        fixed2(3, n),                                        # p
+    ]
+    if masked:
+        args = (mask_t[:, :, None],) + args
+        in_specs.insert(0, t_block(1))                       # mask [t,b,1]
     scratch = [pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((b, n), jnp.float32)]
     return pl.pallas_call(
-        _fwd_kernel,
+        partial(_fwd_kernel, masked=masked),
         grid=(T,),
-        in_specs=[
-            t_block(n4),                                     # xz
-            pl.BlockSpec((1, b, 1), lambda t: (t, 0, 0),
-                         memory_space=pltpu.VMEM),           # mask [t,b,1]
-            fixed2(b, n), fixed2(b, n),                      # h0, c0
-            fixed2(n, n4),                                   # Wh
-            fixed2(3, n),                                    # p
-        ],
+        in_specs=in_specs,
         out_specs=(
-            t_block(n),                                      # y
+            t_block(n),                                      # hk
             fixed2(b, n), fixed2(b, n),                      # hT, cT
             t_block(n4),                                     # G
-            t_block(n), t_block(n),                          # h_prev, c_prev
+            t_block(n),                                      # c_prev
         ),
         out_shape=out_shapes,
         scratch_shapes=scratch,
@@ -313,12 +336,13 @@ def _bwd_call(res, cts):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    G, hprev, cprev, mask_t, Wh, p = res
-    dy, dhT, dcT = cts
-    T, b, n = hprev.shape
+    G, hk, cprev, h0, mask_t, Wh, p = res
+    dhk, dhT, dcT = cts
+    T, b, n = hk.shape
     n4 = 4 * n
     cd = G.dtype
-    dy = dy.astype(cd)
+    masked = mask_t is not None
+    dhk = dhk.astype(cd)
     dhT = dhT.astype(cd)
     dcT = dcT.astype(cd)
     sds = jax.ShapeDtypeStruct
@@ -334,25 +358,33 @@ def _bwd_call(res, cts):
         (1, b, width), lambda i: (T - 1 - i, 0, 0), memory_space=pltpu.VMEM)
     fixed2 = lambda r, cdim: pl.BlockSpec(
         (r, cdim), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    args = (G, hprev, cprev, mask_t[:, :, None], Wh, p, dy, dhT, dcT)
+    args = (G, hk, cprev, h0, Wh, p, dhk, dhT, dcT)
+    in_specs = [
+        rev(n4),                                             # G
+        # the hidden state carried INTO step t is hk's block t - 1; the
+        # last grid step (t = 0) reads h0 instead and the block it is
+        # given here, block 0 again, costs no new DMA
+        pl.BlockSpec((1, b, n), lambda i: (jnp.maximum(T - 2 - i, 0), 0, 0),
+                     memory_space=pltpu.VMEM),               # hk, one back
+        rev(n),                                              # c_prev
+        fixed2(b, n),                                        # h0
+        fixed2(n, n4),                                       # Wh
+        fixed2(3, n),                                        # p
+        rev(n),                                              # dhk
+        fixed2(b, n), fixed2(b, n),                          # dhT, dcT
+    ]
+    if masked:
+        args = (mask_t[:, :, None],) + args
+        in_specs.insert(0, rev(1))                           # mask [t,b,1]
     scratch = [pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((n, n4), jnp.float32),
                pltpu.VMEM((3, n), jnp.float32),
                pltpu.VMEM((8, n4), jnp.float32)]
     return pl.pallas_call(
-        _bwd_kernel,
+        partial(_bwd_kernel, masked=masked),
         grid=(T,),
-        in_specs=[
-            rev(n4),                                         # G
-            rev(n), rev(n),                                  # h_prev, c_prev
-            pl.BlockSpec((1, b, 1), lambda i: (T - 1 - i, 0, 0),
-                         memory_space=pltpu.VMEM),           # mask [t,b,1]
-            fixed2(n, n4),                                   # Wh
-            fixed2(3, n),                                    # p
-            rev(n),                                          # dy
-            fixed2(b, n), fixed2(b, n),                      # dhT, dcT
-        ],
+        in_specs=in_specs,
         out_specs=(
             rev(n4),                                         # dxz
             fixed2(b, n), fixed2(b, n),                      # dh0, dc0
@@ -368,16 +400,15 @@ def _bwd_call(res, cts):
 
 
 @jax.custom_vjp
-def _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t):
+def _lstm_seq_kernels(xw_t, bias, h0, c0, Wh, p, mask_t):
     return _lstm_seq_fwd(xw_t, bias, h0, c0, Wh, p, mask_t)[0]
 
 
 def _lstm_seq_fwd(xw_t, bias, h0, c0, Wh, p, mask_t):
     # the bias add stays in plain jnp, ahead of the kernel: XLA fuses it
     # into the projection matmul that made xw_t
-    y, hT, cT, G, hprev, cprev = _fwd_call(xw_t + bias, h0, c0, Wh, p,
-                                           mask_t)
-    return (y, hT, cT), (G, hprev, cprev, mask_t, Wh, p)
+    hk, hT, cT, G, cprev = _fwd_call(xw_t + bias, h0, c0, Wh, p, mask_t)
+    return (hk, hT, cT), (G, hk, cprev, h0, mask_t, Wh, p)
 
 
 def _lstm_seq_bwd(res, cts):
@@ -385,7 +416,17 @@ def _lstm_seq_bwd(res, cts):
     return dxz, db[0], dh0, dc0, dWh, dp, None
 
 
-_lstm_seq_pallas.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)
+_lstm_seq_kernels.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)
+
+
+def _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t):
+    """The two kernels under one custom VJP give the carried hidden
+    state; a masked step's output is zero, and that product is plain jnp
+    so that autodiff hands the kernel ``dy * mask``. ``mask_t`` is
+    [t, b] in the dtype of ``xw_t``, or None."""
+    hk, hT, cT = _lstm_seq_kernels(xw_t, bias, h0, c0, Wh, p, mask_t)
+    y = hk if mask_t is None else hk * mask_t[:, :, None]
+    return y, hT, cT
 
 
 @registry.register("lstm_sequence", backend="pallas")
@@ -399,8 +440,6 @@ def lstm_sequence_pallas(xw_t, bias, h0, c0, Wh, p, mask_t, *,
     if not _pallas_supported(xw_t, h0, gate_act, cell_act):
         return lstm_sequence_xla(xw_t, bias, h0, c0, Wh, p, mask_t,
                                  gate_act=gate_act, cell_act=cell_act)
-    if mask_t is None:
-        mask_t = jnp.ones(xw_t.shape[:2], xw_t.dtype)
-    else:
+    if mask_t is not None:
         mask_t = mask_t.astype(xw_t.dtype)
     return _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t)

@@ -30,7 +30,9 @@ def _data(t=5, b=8, n=128, dtype=jnp.float32, seed=0, masked=False):
     c0 = jnp.asarray(rng.normal(0, 0.5, (b, n)), dtype)
     Wh = jnp.asarray(rng.normal(0, 0.2, (n, 4 * n)), dtype)
     p = jnp.asarray(rng.normal(0, 0.2, (3, n)), dtype)
-    if masked:
+    if masked is None:
+        mask = None     # the program without a mask operand
+    elif masked:
         m = (rng.random((t, b)) > 0.3).astype(np.float32)
         m[0] = 1.0  # keep step 0 alive for all examples...
         m[:, -1] = 0.0  # ...but one: a fully masked row adds nothing to db
@@ -151,6 +153,46 @@ class TestLstmBackendEquivalence:
             assert gp.shape == a.shape and gp.dtype == dtype, name
             _assert_close(gp, gx, dtype,
                           f"pallas/xla gradient mismatch for {name}")
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("t", [6, 1], ids=["t6", "t1"])
+    @pytest.mark.parametrize("mask", ["none", "ragged"])
+    def test_one_hidden_stream(self, mask, t, dtype):
+        # the forward saves the carried hidden state alone: the backward
+        # takes h_prev[t] from that stream one block back and from h0 at
+        # t = 0 (the new edge: dh0 and the t = 0 term of dWh, which at
+        # T = 1 is all of dWh), and without a mask neither kernel is
+        # given one. Ragged: every length from 0 (masked from the first
+        # step, so the row hands h0 through to hT) to t, and a full row
+        # with one step taken out; h0 and c0 are non-zero throughout.
+        args = list(_data(t=t, b=16, n=128, dtype=dtype, masked=None))
+        if mask == "ragged":
+            m = (np.arange(t)[:, None] < np.arange(16)[None, :] % (t + 1))
+            m = m.astype(np.float32)
+            assert not m[:, 0].any() and m[:, t].all() and m[:, 2 * t + 1].all()
+            m[t // 2, 2 * t + 1] = 0.0
+            args[-1] = jnp.asarray(m, dtype)
+        as_f32 = [None if a is None else a.astype(jnp.float32) for a in args]
+        # a TPU's default f32 dot is one bf16 pass (the class also runs
+        # there, under DL4J_TPU_TESTS=1), which these bounds do not allow
+        with jax.default_matmul_precision("highest"):
+            outs_p, outs_x = self._pallas(*args), self._xla(*as_f32)
+            g_p = jax.grad(_loss_through(self._pallas),
+                           argnums=GRAD_ARGNUMS)(*args)
+            g_x = jax.grad(_loss_through(self._xla),
+                           argnums=GRAD_ARGNUMS)(*as_f32)
+        for name, out_p, out_x in zip(("y", "hT", "cT"), outs_p, outs_x):
+            assert out_p.dtype == dtype, name
+            if dtype == jnp.float32:
+                np.testing.assert_allclose(out_p, out_x, rtol=1e-5,
+                                           atol=1e-5, err_msg=name)
+            else:
+                _assert_close(out_p, out_x, dtype, name)
+        for name, gp, gx, a in zip(GRAD_NAMES, g_p, g_x, args):
+            assert gp.shape == a.shape and gp.dtype == dtype, name
+            assert float(jnp.abs(gx).max()) > 0, name
+            _assert_close(gp, gx, dtype, f"{mask} t={t}: {name}")
 
     @pytest.mark.parametrize("masked", [False, True])
     def test_bias_gradient_is_the_sum_of_dxw(self, masked):
@@ -349,25 +391,29 @@ class TestAttentionBackendEquivalenceTPU:
 @pytest.mark.skipif(jax.default_backend() != "tpu",
                     reason="needs a real TPU")
 class TestLstmBackendEquivalenceTPU:
-    """Same checks, compiled on hardware, bf16 — the dtype the bench runs."""
+    """Same checks, compiled on hardware, bf16 — the dtype the bench runs.
+    ``masked`` None is the program ``fit`` runs when it feeds no mask:
+    kernels without a mask operand."""
 
-    def test_forward_bf16(self):
-        args = _data(t=6, b=16, n=128, dtype=jnp.bfloat16)
+    @pytest.mark.parametrize("masked", [None, False])
+    def test_forward_bf16(self, masked):
+        args = _data(t=6, b=16, n=128, dtype=jnp.bfloat16, masked=masked)
         y_p, hT_p, cT_p = jax.jit(lstm_ops._lstm_seq_pallas)(*args)
         y_x, hT_x, cT_x = jax.jit(lstm_ops.lstm_sequence_xla)(*args)
         np.testing.assert_allclose(
             np.asarray(y_p, np.float32), np.asarray(y_x, np.float32),
             rtol=0.05, atol=0.05)
 
-    def test_gradient_bf16_finite_and_close(self):
-        args = _data(t=4, b=16, n=128, dtype=jnp.bfloat16, masked=True)
+    @pytest.mark.parametrize("masked", [None, True])
+    def test_gradient_bf16_finite_and_close(self, masked):
+        args = _data(t=4, b=16, n=128, dtype=jnp.bfloat16, masked=masked)
         g_p = jax.jit(jax.grad(_loss_through(lstm_ops._lstm_seq_pallas),
-                               argnums=(0, 1, 4)))(*args)
+                               argnums=GRAD_ARGNUMS))(*args)
         g_x = jax.jit(jax.grad(_loss_through(lstm_ops.lstm_sequence_xla),
-                               argnums=(0, 1, 4)))(*args)
-        for gp, gx in zip(g_p, g_x):
+                               argnums=GRAD_ARGNUMS))(*args)
+        for name, gp, gx in zip(GRAD_NAMES, g_p, g_x):
             gp = np.asarray(gp, np.float32)
             gx = np.asarray(gx, np.float32)
-            assert np.all(np.isfinite(gp))
+            assert np.all(np.isfinite(gp)), name
             scale = max(np.abs(gx).max(), 1e-3)
-            assert np.abs(gp - gx).max() / scale < 0.1
+            assert np.abs(gp - gx).max() / scale < 0.1, name

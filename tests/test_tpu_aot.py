@@ -49,19 +49,36 @@ def _on(sharding, tree):
         tree)
 
 
-def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32):
+def _pallas_operands(call, args):
+    """How many arrays the one ``pallas_call`` of ``call`` is given."""
+    eqns = [e for e in jax.make_jaxpr(call)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1, eqns
+    return len(eqns[0].invars)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32, masked):
     cd = jnp.bfloat16
     seq = lambda width: jax.ShapeDtypeStruct((T, B, width), cd)
     row = jax.ShapeDtypeStruct((B, N), cd)
     Wh, p = (jax.ShapeDtypeStruct(s, cd) for s in ((N, 4 * N), (3, N)))
-    mask = jax.ShapeDtypeStruct((T, B), cd)
+    mask = jax.ShapeDtypeStruct((T, B), cd) if masked else None
     fwd_args = (seq(4 * N), row, row, Wh, p, mask)
-    bwd_args = ((seq(4 * N), seq(N), seq(N), mask, Wh, p),
+    # residuals (G, hk, c_prev, h0, mask, Wh, p) and the cotangents
+    bwd_args = ((seq(4 * N), seq(N), seq(N), row, mask, Wh, p),
                 (seq(N), row, row))
     for call, args in ((lstm_ops._fwd_call, fwd_args),
                        (lstm_ops._bwd_call, bwd_args)):
         compiled = jax.jit(call).lower(*_on(one_chip, args)).compile()
         assert "tpu_custom_call" in compiled.as_text()
+    # one hidden stream: hk, hT, cT, G, c_prev and no h_prev; a mask
+    # operand only where a mask was given
+    fwd_out = jax.eval_shape(lstm_ops._fwd_call, *fwd_args)
+    assert [o.shape for o in fwd_out] == [
+        (T, B, N), (B, N), (B, N), (T, B, 4 * N), (T, B, N)]
+    assert _pallas_operands(lstm_ops._fwd_call, fwd_args) == 5 + masked
+    assert _pallas_operands(lstm_ops._bwd_call, bwd_args) == 9 + masked
     # dxz, dh0, dc0, dWh, dp, and the bias gradient as one row
     db = jax.eval_shape(lstm_ops._bwd_call, *bwd_args)[-1]
     assert (db.shape, db.dtype) == ((1, 4 * N), cd)
