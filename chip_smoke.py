@@ -18,7 +18,8 @@ a toy size; the defaults are the contract):
   multi_step 8 + device prefetch), then repeated ``fit_batch``.
 - kernels  — ``zoo.char_rnn()`` at b=32 and b=256 (the Pallas LSTM must be
   in the lowered step), then both Pallas kernels against their XLA
-  references, forward and gradient, at the bench shapes.
+  references, forward and gradient, at the bench shapes: 64 timesteps
+  (four to a grid step) and 50 (two).
 - serve    — ``ModelServer`` + ``DecodeEngine`` over ``zoo.gpt_mini`` f32:
   8 concurrent ``/predict`` and 2 ``/decode`` sessions over HTTP.
 - data-parallel — with >= 4 devices: ResNet-50 on a 4-way data mesh.
@@ -254,8 +255,9 @@ def _check_flash_kernel(b, t, h, dh) -> dict:
         _weighted_sum, (q, k, v), (0, 1, 2))
 
 
-def phase_kernels(hidden=512, seq=64, batches=(32, 256), vocab=80,
-                  fit_steps=4, flash_shape=(2, 256, 4, 128)) -> dict:
+def phase_kernels(hidden=512, seq=64, bptt_seq=50, batches=(32, 256),
+                  vocab=80, fit_steps=4,
+                  flash_shape=(2, 256, 4, 128)) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -286,10 +288,13 @@ def phase_kernels(hidden=512, seq=64, batches=(32, 256), vocab=80,
         out["char_rnn"].append({"b": b, "score": round(score, 4),
                                 "tpu_custom_calls": n_calls})
         del net
-        out["lstm"].append({"b": b, **_kernel_vs_reference(
-            f"lstm b={b}", lstm_ops._lstm_seq_pallas,
-            lstm_ops.lstm_sequence_xla, _lstm_loss,
-            _lstm_args(seq, b, hidden), (0, 1, 2, 3, 4, 5))})
+        # 64 timesteps go four to a grid step, and 50 (the BPTT segment
+        # of DL4J's example) two
+        for t in (seq, bptt_seq):
+            out["lstm"].append({"b": b, "t": t, **_kernel_vs_reference(
+                f"lstm b={b} t={t}", lstm_ops._lstm_seq_pallas,
+                lstm_ops.lstm_sequence_xla, _lstm_loss,
+                _lstm_args(t, b, hidden), (0, 1, 2, 3, 4, 5))})
     out["flash"] = _check_flash_kernel(*flash_shape)
     return out
 

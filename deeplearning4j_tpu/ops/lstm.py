@@ -17,9 +17,27 @@ kernel runs the WHOLE time loop in one kernel launch with Wh and the
 (h, c) carry resident in VMEM, streaming xz[t] in and the carried hidden
 state h[t], the gates G[t] and c_prev[t] out — the cuDNN-class schedule.
 h[t] is the one hidden stream: it is the output (times the mask, taken
-outside the kernel), and the backward reads it one block back as
+outside the kernel), and the backward reads it one row back as
 h_prev[t] = h[t-1], with h0 at t = 0. Without a mask (known at trace
 time) neither kernel takes a mask operand or does mask arithmetic.
+
+A grid step is Tb consecutive timesteps, not one: every streamed operand
+and result moves in (Tb, b, width) blocks, the backward walks the blocks
+and the timesteps inside a block in reverse, and the carry goes through
+the f32 scratch between grid steps only. The body runs the Tb timesteps
+as one straight-line block, a Python loop over static indices with the
+carry as values and no branch inside it (the ``pl.when`` set-up and
+write-out stand before and after it). A basic block ends at every grid
+step and at every branch, and nothing is scheduled across that edge: on
+a v5e at b=256, n=512 one branch round one matmul cost 0.65 us a grid
+step, and the backward, whose dWh matmul and gate derivatives wait for
+no dh, runs four timesteps a grid step in 6.00 us each where one takes
+6.71. Tb is the longest of 4, 2, 1 that divides T and whose blocks fit
+the VMEM cap (``_time_block``; why not 8 is told at ``_TIME_BLOCKS``):
+a function of the call's shapes, not an option. T = 1
+(``rnn_time_step``, decode) and a prime T get Tb = 1, the kernel of one
+timestep a grid step; which Tb a call got is counted in
+``dl4j_lstm_kernel_calls_total{direction, time_block}``.
 
 Gate math (Graves formulation with peepholes, order i, f, o, g):
     i = sigmoid(zi + p_i * c_prev)      f = sigmoid(zf + p_f * c_prev)
@@ -39,7 +57,7 @@ in VMEM beside dWh and dp. Nothing reads dxz a second time to reduce it.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -108,7 +126,7 @@ def _pallas_supported(xw_t, h0, gate_act, cell_act):
     return True
 
 
-def _fwd_kernel(*refs, masked):
+def _fwd_kernel(*refs, masked, Tb):
     import jax.experimental.pallas as pl
 
     m_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
@@ -122,29 +140,34 @@ def _fwd_kernel(*refs, masked):
         h_scr[:] = h0_ref[:].astype(jnp.float32)
         c_scr[:] = c0_ref[:].astype(jnp.float32)
 
-    h_prev = h_scr[:]
-    c_prev = c_scr[:]
+    h = h_scr[:]
+    c = c_scr[:]
     cd = xz_ref.dtype
-    n = h_prev.shape[-1]
-
-    z = xz_ref[0].astype(jnp.float32) + jnp.dot(
-        h_prev.astype(cd), Wh_ref[:], preferred_element_type=jnp.float32)
+    n = h.shape[-1]
     pvec = p_ref[:].astype(jnp.float32)
-    i = jax.nn.sigmoid(z[:, :n] + pvec[0:1, :] * c_prev)
-    f = jax.nn.sigmoid(z[:, n:2 * n] + pvec[1:2, :] * c_prev)
-    g = jnp.tanh(z[:, 3 * n:])
-    c = f * c_prev + i * g
-    o = jax.nn.sigmoid(z[:, 2 * n:3 * n] + pvec[2:3, :] * c)
-    h = o * jnp.tanh(c)
 
-    if masked:
-        keep = m_ref[0].astype(jnp.float32) > 0
-        h = jnp.where(keep, h, h_prev)
-        c = jnp.where(keep, c, c_prev)
+    # the block's Tb timesteps, straight-line: (h, c) are values from one
+    # to the next, and no branch stands between them
+    for s in range(Tb):
+        h_prev, c_prev = h, c
+        z = xz_ref[s].astype(jnp.float32) + jnp.dot(
+            h_prev.astype(cd), Wh_ref[:], preferred_element_type=jnp.float32)
+        i = jax.nn.sigmoid(z[:, :n] + pvec[0:1, :] * c_prev)
+        f = jax.nn.sigmoid(z[:, n:2 * n] + pvec[1:2, :] * c_prev)
+        g = jnp.tanh(z[:, 3 * n:])
+        c = f * c_prev + i * g
+        o = jax.nn.sigmoid(z[:, 2 * n:3 * n] + pvec[2:3, :] * c)
+        h = o * jnp.tanh(c)
 
-    hk_ref[0] = h.astype(cd)
-    G_ref[0] = jnp.concatenate([i, f, o, g], axis=-1).astype(cd)
-    cprev_ref[0] = c_prev.astype(cd)
+        if masked:
+            keep = m_ref[s].astype(jnp.float32) > 0
+            h = jnp.where(keep, h, h_prev)
+            c = jnp.where(keep, c, c_prev)
+
+        hk_ref[s] = h.astype(cd)
+        G_ref[s] = jnp.concatenate([i, f, o, g], axis=-1).astype(cd)
+        cprev_ref[s] = c_prev.astype(cd)
+
     h_scr[:] = h
     c_scr[:] = c
 
@@ -154,16 +177,20 @@ def _fwd_kernel(*refs, masked):
         cT_ref[:] = c.astype(cd)
 
 
-def _bwd_kernel(*refs, masked):
+def _bwd_kernel(*refs, masked, Tb):
     import jax.experimental.pallas as pl
 
     m_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    (G_ref, hk_ref, cprev_ref, h0_ref, Wh_ref, p_ref,
+    G_ref, refs = refs[0], refs[1:]
+    # the time block's own rows of the hidden stream, which a block of one
+    # timestep does not read
+    hk_ref, refs = (refs[0], refs[1:]) if Tb > 1 else (None, refs)
+    (hback_ref, cprev_ref, h0_ref, Wh_ref, p_ref,
      dhk_ref, dhT_ref, dcT_ref,
      dxz_ref, dh0_ref, dc0_ref, dWh_ref, dp_ref, db_ref,
      dh_scr, dc_scr, dWh_scr, dp_scr, db_scr) = refs
     pid = pl.program_id(0)
-    T = pl.num_programs(0)
+    nb = pl.num_programs(0)
 
     @pl.when(pid == 0)
     def _():
@@ -175,72 +202,103 @@ def _bwd_kernel(*refs, masked):
 
     cd = G_ref.dtype
     n = cprev_ref.shape[-1]
-    G = G_ref[0].astype(jnp.float32)
-    i, f, o, g = (G[:, :n], G[:, n:2 * n], G[:, 2 * n:3 * n], G[:, 3 * n:])
-    c_prev = cprev_ref[0].astype(jnp.float32)
     pvec = p_ref[:].astype(jnp.float32)
+    dh_next = dh_scr[:]
+    dc_next = dc_scr[:]
+    acc = None
 
-    c = f * c_prev + i * g
-    tc = jnp.tanh(c)
+    # the block's Tb timesteps from the last to the first, straight-line:
+    # (dh, dc) are values from one to the next, and no branch stands
+    # between them
+    for s in reversed(range(Tb)):
+        G = G_ref[s].astype(jnp.float32)
+        i, f, o, g = (G[:, :n], G[:, n:2 * n], G[:, 2 * n:3 * n],
+                      G[:, 3 * n:])
+        c_prev = cprev_ref[s].astype(jnp.float32)
 
-    # what reaches the carried (h, c) of this step: a kept row hands it
-    # to the cell, a masked row hands it on to the step before
-    dh = dh_scr[:] + dhk_ref[0].astype(jnp.float32)
-    dc = dc_scr[:]
-    if masked:
-        keep = m_ref[0].astype(jnp.float32) > 0
-        dh_skip = jnp.where(keep, 0.0, dh)
-        dc_skip = jnp.where(keep, 0.0, dc)
-        dh = jnp.where(keep, dh, 0.0)
-        dc = jnp.where(keep, dc, 0.0)
+        c = f * c_prev + i * g
+        tc = jnp.tanh(c)
 
-    do = dh * tc
-    dzo = do * o * (1.0 - o)
-    dc_in = dc + dh * o * (1.0 - tc * tc) + dzo * pvec[2:3, :]
-    di = dc_in * g
-    df = dc_in * c_prev
-    dg = dc_in * i
-    dzi = di * i * (1.0 - i)
-    dzf = df * f * (1.0 - f)
-    dzg = dg * (1.0 - g * g)
+        # what reaches the carried (h, c) of this step: a kept row hands
+        # it to the cell, a masked row hands it on to the step before
+        dh = dh_next + dhk_ref[s].astype(jnp.float32)
+        dc = dc_next
+        if masked:
+            keep = m_ref[s].astype(jnp.float32) > 0
+            dh_skip = jnp.where(keep, 0.0, dh)
+            dc_skip = jnp.where(keep, 0.0, dc)
+            dh = jnp.where(keep, dh, 0.0)
+            dc = jnp.where(keep, dc, 0.0)
 
-    dz = jnp.concatenate([dzi, dzf, dzo, dzg], axis=-1)
-    dz_cd = dz.astype(cd)
+        do = dh * tc
+        dzo = do * o * (1.0 - o)
+        dc_in = dc + dh * o * (1.0 - tc * tc) + dzo * pvec[2:3, :]
+        di = dc_in * g
+        df = dc_in * c_prev
+        dg = dc_in * i
+        dzi = di * i * (1.0 - i)
+        dzf = df * f * (1.0 - f)
+        dzg = dg * (1.0 - g * g)
 
-    # dh_prev = dz @ Wh^T  (contract the 4n dim)
-    dh_prev = jax.lax.dot_general(
-        dz_cd, Wh_ref[:], dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dc_prev = dc_in * f + dzi * pvec[0:1, :] + dzf * pvec[1:2, :]
-    if masked:
-        dh_prev = dh_prev + dh_skip
-        dc_prev = dc_prev + dc_skip
+        dz = jnp.concatenate([dzi, dzf, dzo, dzg], axis=-1)
+        dz_cd = dz.astype(cd)
 
-    # dWh += h_prev^T @ dz  (contract the batch dim). h_prev of step t is
-    # the block the forward wrote at t - 1, which hk_ref holds here, and
-    # h0 at t = 0, the last grid step. A select, not two pl.when bodies:
-    # on the chip a branch round this matmul cost 0.65 us a grid step
-    # (b=256, n=512: 7.59 ms a call against 6.92)
-    h_prev = jnp.where(pid == T - 1, h0_ref[:], hk_ref[0])
+        # dh_prev = dz @ Wh^T  (contract the 4n dim)
+        dh_next = jax.lax.dot_general(
+            dz_cd, Wh_ref[:], dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dc_next = dc_in * f + dzi * pvec[0:1, :] + dzf * pvec[1:2, :]
+        if masked:
+            dh_next = dh_next + dh_skip
+            dc_next = dc_next + dc_skip
+
+        # the sums a timestep adds to dp and db are values until the block
+        # ends: Tb read-modify-writes of the scratch in one body ran the
+        # backward at 7.88 ms a call where this runs at 6.10 (Tb = 8).
+        # db: column sums of the f32 dz (zero in masked rows), kept as
+        # eight sublane partials: the rows fold onto one [8, 4n] tile with
+        # whole-register adds, and the sublanes are reduced once, at the
+        # end
+        sums = [jnp.sum(dzi * c_prev, axis=0, keepdims=True),
+                jnp.sum(dzf * c_prev, axis=0, keepdims=True),
+                jnp.sum(dzo * c, axis=0, keepdims=True),
+                jnp.sum(dz.reshape(-1, 8, 4 * n), axis=0)]
+        acc = sums if acc is None else [a + x for a, x in zip(acc, sums)]
+
+        dxz_ref[s] = dz_cd
+
+    # dWh += h_prev^T @ dz  (contract the batch dim), the block's Tb terms
+    # at once. h_prev of a timestep is the row the forward wrote one
+    # timestep before. For the block's later timesteps that is the block's
+    # own rows 0 .. Tb - 2, against the dz just stored in rows 1 .. Tb - 1
+    # of the dxz block: one contraction over (Tb - 1) * b rows. For its
+    # first timestep (the dz still at hand) it is the last row of the time
+    # block before, which hback_ref holds, or h0 at t = 0, the last grid
+    # step. A select, not two pl.when bodies: on the chip a branch round
+    # this matmul cost 0.65 us a grid step (b=256, n=512, Tb = 1: 7.59 ms
+    # a call against 6.92)
+    by_rows = (((0,), (0,)), ((), ()))
+    if Tb > 1:
+        rows = (Tb - 1) * dz_cd.shape[0]
+        dWh_scr[:] += jax.lax.dot_general(
+            hk_ref[0:Tb - 1].reshape(rows, n),
+            dxz_ref[1:Tb].reshape(rows, 4 * n), dimension_numbers=by_rows,
+            preferred_element_type=jnp.float32)
+    h_prev = jnp.where(pid == nb - 1, h0_ref[:], hback_ref[0])
     dWh_scr[:] += jax.lax.dot_general(
-        h_prev, dz_cd, dimension_numbers=(((0,), (0,)), ((), ())),
+        h_prev, dz_cd, dimension_numbers=by_rows,
         preferred_element_type=jnp.float32)
-    dp_scr[0:1, :] += jnp.sum(dzi * c_prev, axis=0, keepdims=True)
-    dp_scr[1:2, :] += jnp.sum(dzf * c_prev, axis=0, keepdims=True)
-    dp_scr[2:3, :] += jnp.sum(dzo * c, axis=0, keepdims=True)
-    # db += column sums of the f32 dz (zero in masked rows), kept as eight
-    # sublane partials: the rows fold onto one [8, 4n] tile with
-    # whole-register adds, and the sublanes are reduced once, at the end
-    db_scr[:] += jnp.sum(dz.reshape(-1, 8, 4 * n), axis=0)
+    for k in range(3):
+        dp_scr[k:k + 1, :] += acc[k]
+    db_scr[:] += acc[3]
 
-    dxz_ref[0] = dz_cd
-    dh_scr[:] = dh_prev
-    dc_scr[:] = dc_prev
+    dh_scr[:] = dh_next
+    dc_scr[:] = dc_next
 
-    @pl.when(pid == T - 1)
+    @pl.when(pid == nb - 1)
     def _():
-        dh0_ref[:] = dh_prev.astype(cd)
-        dc0_ref[:] = dc_prev.astype(cd)
+        dh0_ref[:] = dh_next.astype(cd)
+        dc0_ref[:] = dc_next.astype(cd)
         dWh_ref[:] = dWh_scr[:].astype(cd)
         dp_ref[:] = dp_scr[:].astype(cd)
         db_ref[:] = jnp.sum(db_scr[:], axis=0, keepdims=True).astype(cd)
@@ -250,47 +308,105 @@ def _bwd_kernel(*refs, masked):
 # at b=256, n=512 in bf16 asks for 16.06 MiB (measured on the chip:
 # RESOURCE_EXHAUSTED by 64 KiB). So each call states what it needs, and
 # never less than the default. The largest request the chip has been
-# seen to grant is that shape's masked backward, 36.5 MiB (36.4 without
-# a mask; the forward asks 25.0); the cap is not verified.
+# seen to grant is 86.4 MiB (that shape's masked backward at eight
+# timesteps a block; Mosaic itself needed 62.1 MiB of it), and a
+# compile for a described v5e takes 127 MiB: the cap is below both.
 _VMEM_DEFAULT = 16 * 1024 * 1024
 _VMEM_CAP = 96 * 1024 * 1024
+# Timesteps per grid step, the longest first (``_time_block``). On a v5e
+# at b=256, n=512, T=1,024 in bf16 the backward takes 6,871 / 6,416 /
+# 6,146 / 6,068 us a call alone at 1 / 2 / 4 / 8 and the forward 4,165 /
+# 4,117 / 4,109 / 4,107 (it is held by its HBM streams). But inside the
+# char-RNN step program the backward of 8 takes 7,937 us, 14 us a grid
+# step more, and with a mask 7,581 even alone (7,026 at 1): the unrolled
+# body of 8 timesteps is 2.9 MB of instructions. At 4 it takes 6,233 in
+# the step and 6,312 masked. So the ladder starts at 4.
+_TIME_BLOCKS = (4, 2, 1)
 
 
-def _compiler_params(arrays, scratch):
-    """``vmem_limit_bytes`` from the call's own operands. Every 3-d
-    ``[T, ...]`` array streams one ``[1, ...]`` block per grid step and
-    every other array is one block at a constant index; Pallas
-    double-buffers both kinds. ``scratch`` is resident once, and the
-    gate math keeps about four f32 temporaries as wide as the widest
-    streamed block ([b, 4n]) live. 25% headroom over that sum for
-    Mosaic's own spills."""
-    from jax.experimental.pallas import tpu as pltpu
+def _vmem_request(blocks, scratch):
+    """What one call needs of VMEM, from its own ``blocks``, the (block
+    shape, dtype) of every operand and result. Pallas double-buffers
+    every block, streamed or at a constant index. ``scratch`` is resident
+    once, and the gate math keeps about four f32 temporaries as wide as
+    one timestep's widest streamed row ([b, 4n]) live. 25% headroom over
+    that sum for Mosaic's own spills."""
 
     def nbytes(shape, dtype):       # the last dim pads to a 128 lane
         return (math.prod(shape[:-1]) * -(-shape[-1] // 128) * 128
                 * jnp.dtype(dtype).itemsize)
 
-    blocks = [nbytes(a.shape[1:] if len(a.shape) == 3 else a.shape, a.dtype)
-              for a in arrays]
-    widest = max(math.prod(a.shape[1:]) for a in arrays
-                 if len(a.shape) == 3)
-    need = (2 * sum(blocks) + sum(nbytes(r.shape, r.dtype) for r in scratch)
+    widest = max(math.prod(shape[1:]) for shape, _ in blocks
+                 if len(shape) == 3)
+    need = (2 * sum(nbytes(shape, dtype) for shape, dtype in blocks)
+            + sum(nbytes(r.shape, r.dtype) for r in scratch)
             + 4 * widest * 4)
-    limit = min(_VMEM_CAP, max(_VMEM_DEFAULT, need + need // 4))
-    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
+    return need + need // 4
 
 
-def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
-    """(hk, hT, cT, G, c_prev): hk[t] is the hidden state carried out of
-    step t, which is the step's output where the mask keeps the row.
-    ``mask_t`` None leaves the mask operand and its arithmetic out."""
+def _time_block(T, request):
+    """Timesteps per grid step: the longest of ``_TIME_BLOCKS`` that
+    divides ``T`` and whose ``request(Tb)`` of VMEM is under the cap. A
+    function of the call's shapes alone; 1 is the kernel of one timestep
+    a grid step, whatever it asks."""
+    for Tb in _TIME_BLOCKS:
+        if Tb == 1 or (T % Tb == 0 and request(Tb) <= _VMEM_CAP):
+            return Tb
+
+
+def _blocked_call(kernel, Tb, T, in_specs, out_specs, out_shapes, scratch,
+                  interpret):
+    """(call, request): the ``pallas_call`` of ``kernel`` over ``T // Tb``
+    grid steps, and the VMEM it asks for. The call is jitted to be
+    inlined: the layers and programs of one process that run the same
+    shapes then trace and lower the kernel body once between them, where
+    an unrolled body traced at every call site cost the char-RNN job 0.7 s
+    of set-up."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    T, b, n4 = xz_t.shape
-    n = n4 // 4
-    cd = xz_t.dtype
-    masked = mask_t is not None
+    specs = list(in_specs) + list(out_specs)
+    request = _vmem_request(
+        [(spec.block_shape, out_shapes[0].dtype) for spec in specs], scratch)
+    limit = min(_VMEM_CAP, max(_VMEM_DEFAULT, request))
+    call = pl.pallas_call(
+        partial(kernel, Tb=Tb),
+        grid=(T // Tb,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shapes,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=int(limit)),
+        interpret=interpret,
+    )
+    return jax.jit(call, inline=True), request
+
+
+def _count_call(direction, Tb):
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_lstm_kernel_calls_total",
+        "Pallas LSTM kernel calls traced, by direction and by the "
+        "timesteps one grid step takes",
+        ("direction", "time_block")).labels(
+            direction=direction, time_block=str(Tb)).inc()
+
+
+def _fixed2(r, cdim):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.BlockSpec((r, cdim), lambda t: (0, 0),
+                        memory_space=pltpu.VMEM)
+
+
+@lru_cache(maxsize=None)
+def _fwd_blocked(T, b, n, cd, masked, *, Tb, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n4 = 4 * n
     sds = jax.ShapeDtypeStruct
     out_shapes = (
         sds((T, b, n), cd),    # hk
@@ -300,51 +416,49 @@ def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
         sds((T, b, n), cd),    # c_prev per step
     )
     t_block = lambda width: pl.BlockSpec(
-        (1, b, width), lambda t: (t, 0, 0), memory_space=pltpu.VMEM)
-    fixed2 = lambda r, cdim: pl.BlockSpec(
-        (r, cdim), lambda t: (0, 0), memory_space=pltpu.VMEM)
-    args = (xz_t, h0, c0, Wh, p)
+        (Tb, b, width), lambda t: (t, 0, 0), memory_space=pltpu.VMEM)
     in_specs = [
         t_block(n4),                                         # xz
-        fixed2(b, n), fixed2(b, n),                          # h0, c0
-        fixed2(n, n4),                                       # Wh
-        fixed2(3, n),                                        # p
+        _fixed2(b, n), _fixed2(b, n),                        # h0, c0
+        _fixed2(n, n4),                                      # Wh
+        _fixed2(3, n),                                       # p
     ]
     if masked:
-        args = (mask_t[:, :, None],) + args
         in_specs.insert(0, t_block(1))                       # mask [t,b,1]
+    out_specs = (
+        t_block(n),                                          # hk
+        _fixed2(b, n), _fixed2(b, n),                        # hT, cT
+        t_block(n4),                                         # G
+        t_block(n),                                          # c_prev
+    )
     scratch = [pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((b, n), jnp.float32)]
-    return pl.pallas_call(
-        partial(_fwd_kernel, masked=masked),
-        grid=(T,),
-        in_specs=in_specs,
-        out_specs=(
-            t_block(n),                                      # hk
-            fixed2(b, n), fixed2(b, n),                      # hT, cT
-            t_block(n4),                                     # G
-            t_block(n),                                      # c_prev
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=scratch,
-        compiler_params=_compiler_params(args + out_shapes, scratch),
-        interpret=_interpret(),
-    )(*args)
+    return _blocked_call(partial(_fwd_kernel, masked=masked), Tb, T,
+                         in_specs, out_specs, out_shapes, scratch, interpret)
 
 
-def _bwd_call(res, cts):
+def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
+    """(hk, hT, cT, G, c_prev): hk[t] is the hidden state carried out of
+    step t, which is the step's output where the mask keeps the row.
+    ``mask_t`` None leaves the mask operand and its arithmetic out."""
+    T, b, n4 = xz_t.shape
+    blocked = partial(_fwd_blocked, T, b, n4 // 4, jnp.dtype(xz_t.dtype),
+                      mask_t is not None, interpret=_interpret())
+    Tb = _time_block(T, lambda k: blocked(Tb=k)[1])
+    _count_call("forward", Tb)
+    args = (xz_t, h0, c0, Wh, p)
+    if mask_t is not None:
+        args = (mask_t[:, :, None],) + args
+    return blocked(Tb=Tb)[0](*args)
+
+
+@lru_cache(maxsize=None)
+def _bwd_blocked(T, b, n, cd, masked, *, Tb, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    G, hk, cprev, h0, mask_t, Wh, p = res
-    dhk, dhT, dcT = cts
-    T, b, n = hk.shape
     n4 = 4 * n
-    cd = G.dtype
-    masked = mask_t is not None
-    dhk = dhk.astype(cd)
-    dhT = dhT.astype(cd)
-    dcT = dcT.astype(cd)
+    nb = T // Tb
     sds = jax.ShapeDtypeStruct
     out_shapes = (
         sds((T, b, n4), cd),   # dxz
@@ -354,49 +468,60 @@ def _bwd_call(res, cts):
         sds((3, n), cd),       # dp
         sds((1, n4), cd),      # db
     )
+    # the time blocks from the last to the first
     rev = lambda width: pl.BlockSpec(
-        (1, b, width), lambda i: (T - 1 - i, 0, 0), memory_space=pltpu.VMEM)
-    fixed2 = lambda r, cdim: pl.BlockSpec(
-        (r, cdim), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    args = (G, hk, cprev, h0, Wh, p, dhk, dhT, dcT)
-    in_specs = [
-        rev(n4),                                             # G
-        # the hidden state carried INTO step t is hk's block t - 1; the
-        # last grid step (t = 0) reads h0 instead and the block it is
-        # given here, block 0 again, costs no new DMA
-        pl.BlockSpec((1, b, n), lambda i: (jnp.maximum(T - 2 - i, 0), 0, 0),
-                     memory_space=pltpu.VMEM),               # hk, one back
+        (Tb, b, width), lambda i: (nb - 1 - i, 0, 0),
+        memory_space=pltpu.VMEM)
+    in_specs = [rev(n4)]                                     # G
+    if Tb > 1:      # hk: h_prev of the block's later timesteps
+        in_specs.append(rev(n))
+    in_specs += [
+        # the hidden state carried INTO a block's first timestep t0 is
+        # hk's row t0 - 1, one row of the time block before; the last
+        # grid step (t0 = 0) reads h0 instead and the row it is given
+        # here, row 0, costs no new DMA when Tb = 1
+        pl.BlockSpec(
+            (1, b, n),
+            lambda i: (jnp.maximum((nb - 1 - i) * Tb - 1, 0), 0, 0),
+            memory_space=pltpu.VMEM),                        # hk, one back
         rev(n),                                              # c_prev
-        fixed2(b, n),                                        # h0
-        fixed2(n, n4),                                       # Wh
-        fixed2(3, n),                                        # p
+        _fixed2(b, n),                                       # h0
+        _fixed2(n, n4),                                      # Wh
+        _fixed2(3, n),                                       # p
         rev(n),                                              # dhk
-        fixed2(b, n), fixed2(b, n),                          # dhT, dcT
+        _fixed2(b, n), _fixed2(b, n),                        # dhT, dcT
     ]
     if masked:
-        args = (mask_t[:, :, None],) + args
         in_specs.insert(0, rev(1))                           # mask [t,b,1]
+    out_specs = (
+        rev(n4),                                             # dxz
+        _fixed2(b, n), _fixed2(b, n),                        # dh0, dc0
+        _fixed2(n, n4),                                      # dWh
+        _fixed2(3, n),                                       # dp
+        _fixed2(1, n4),                                      # db
+    )
     scratch = [pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((n, n4), jnp.float32),
                pltpu.VMEM((3, n), jnp.float32),
                pltpu.VMEM((8, n4), jnp.float32)]
-    return pl.pallas_call(
-        partial(_bwd_kernel, masked=masked),
-        grid=(T,),
-        in_specs=in_specs,
-        out_specs=(
-            rev(n4),                                         # dxz
-            fixed2(b, n), fixed2(b, n),                      # dh0, dc0
-            fixed2(n, n4),                                   # dWh
-            fixed2(3, n),                                    # dp
-            fixed2(1, n4),                                   # db
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=scratch,
-        compiler_params=_compiler_params(args + out_shapes, scratch),
-        interpret=_interpret(),
-    )(*args)
+    return _blocked_call(partial(_bwd_kernel, masked=masked), Tb, T,
+                         in_specs, out_specs, out_shapes, scratch, interpret)
+
+
+def _bwd_call(res, cts):
+    G, hk, cprev, h0, mask_t, Wh, p = res
+    T, b, n = hk.shape
+    cd = G.dtype
+    dhk, dhT, dcT = (ct.astype(cd) for ct in cts)
+    blocked = partial(_bwd_blocked, T, b, n, jnp.dtype(cd),
+                      mask_t is not None, interpret=_interpret())
+    Tb = _time_block(T, lambda k: blocked(Tb=k)[1])
+    _count_call("backward", Tb)
+    args = (G,) + (hk,) * (Tb > 1) + (hk, cprev, h0, Wh, p, dhk, dhT, dcT)
+    if mask_t is not None:
+        args = (mask_t[:, :, None],) + args
+    return blocked(Tb=Tb)[0](*args)
 
 
 @jax.custom_vjp

@@ -154,25 +154,9 @@ class TestLstmBackendEquivalence:
             _assert_close(gp, gx, dtype,
                           f"pallas/xla gradient mismatch for {name}")
 
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                             ids=["f32", "bf16"])
-    @pytest.mark.parametrize("t", [6, 1], ids=["t6", "t1"])
-    @pytest.mark.parametrize("mask", ["none", "ragged"])
-    def test_one_hidden_stream(self, mask, t, dtype):
-        # the forward saves the carried hidden state alone: the backward
-        # takes h_prev[t] from that stream one block back and from h0 at
-        # t = 0 (the new edge: dh0 and the t = 0 term of dWh, which at
-        # T = 1 is all of dWh), and without a mask neither kernel is
-        # given one. Ragged: every length from 0 (masked from the first
-        # step, so the row hands h0 through to hT) to t, and a full row
-        # with one step taken out; h0 and c0 are non-zero throughout.
-        args = list(_data(t=t, b=16, n=128, dtype=dtype, masked=None))
-        if mask == "ragged":
-            m = (np.arange(t)[:, None] < np.arange(16)[None, :] % (t + 1))
-            m = m.astype(np.float32)
-            assert not m[:, 0].any() and m[:, t].all() and m[:, 2 * t + 1].all()
-            m[t // 2, 2 * t + 1] = 0.0
-            args[-1] = jnp.asarray(m, dtype)
+    def _assert_matches_the_scan(self, args, dtype, label):
+        """Outputs and all six gradients of the kernels on ``args``
+        against the scan in f32 on the same (rounded) inputs."""
         as_f32 = [None if a is None else a.astype(jnp.float32) for a in args]
         # a TPU's default f32 dot is one bf16 pass (the class also runs
         # there, under DL4J_TPU_TESTS=1), which these bounds do not allow
@@ -192,7 +176,163 @@ class TestLstmBackendEquivalence:
         for name, gp, gx, a in zip(GRAD_NAMES, g_p, g_x, args):
             assert gp.shape == a.shape and gp.dtype == dtype, name
             assert float(jnp.abs(gx).max()) > 0, name
-            _assert_close(gp, gx, dtype, f"{mask} t={t}: {name}")
+            _assert_close(gp, gx, dtype, f"{label}: {name}")
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("t", [6, 1], ids=["t6", "t1"])
+    @pytest.mark.parametrize("mask", ["none", "ragged"])
+    def test_one_hidden_stream(self, mask, t, dtype):
+        # the forward saves the carried hidden state alone: the backward
+        # takes h_prev[t] from that stream one block back and from h0 at
+        # t = 0 (the new edge: dh0 and the t = 0 term of dWh, which at
+        # T = 1 is all of dWh), and without a mask neither kernel is
+        # given one. Ragged: every length from 0 (masked from the first
+        # step, so the row hands h0 through to hT) to t, and a full row
+        # with one step taken out; h0 and c0 are non-zero throughout.
+        args = list(_data(t=t, b=16, n=128, dtype=dtype, masked=None))
+        if mask == "ragged":
+            m = (np.arange(t)[:, None] < np.arange(16)[None, :] % (t + 1))
+            m = m.astype(np.float32)
+            assert not m[:, 0].any() and m[:, t].all() and m[:, 2 * t + 1].all()
+            m[t // 2, 2 * t + 1] = 0.0
+            args[-1] = jnp.asarray(m, dtype)
+        self._assert_matches_the_scan(args, dtype, f"{mask} t={t}")
+
+    def _time_blocks_traced(self, fn, *args):
+        """{direction: Tb} of the kernel calls one trace of ``fn`` makes."""
+        from deeplearning4j_tpu.observability import metrics
+        saved = metrics.set_registry(metrics.MetricsRegistry())
+        try:
+            jax.make_jaxpr(fn)(*args)
+            fam = metrics.get_registry().snapshot()[
+                "dl4j_lstm_kernel_calls_total"]
+        finally:
+            metrics.set_registry(saved)
+        return {s["labels"]["direction"]: int(s["labels"]["time_block"])
+                for s in fam if s["value"]}
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("mask", ["none", "ragged"])
+    @pytest.mark.parametrize("t,tb", [(16, 4), (12, 4), (6, 2), (7, 1),
+                                      (1, 1)])
+    def test_every_rung_of_the_time_block_ladder(self, t, tb, mask, dtype):
+        # a grid step is tb timesteps: forward and all six gradients
+        # against the scan at every rung (16 is four blocks of 4, 12 is
+        # three). Ragged: lengths 0 .. t, so rows end inside a block, at a
+        # block's edge and in the block after (16 rows: every length of
+        # t <= 15 is there, some twice), the longest row with a step taken
+        # out; h0 and c0 are non-zero.
+        args = list(_data(t=t, b=16, n=128, dtype=dtype, masked=None, seed=t))
+        if mask == "ragged":
+            m = (np.arange(t)[:, None] < np.arange(16)[None, :] % (t + 1))
+            m = m.astype(np.float32)
+            full = min(t, 15)       # the longest row: t steps, 15 of 16
+            assert m[:, full].sum() == full
+            m[t // 2, full] = 0.0
+            args[-1] = jnp.asarray(m, dtype)
+        grad = jax.grad(_loss_through(self._pallas), argnums=GRAD_ARGNUMS)
+        assert self._time_blocks_traced(grad, *args) == {
+            "forward": tb, "backward": tb}
+        self._assert_matches_the_scan(args, dtype, f"{mask} t={t}")
+
+    @pytest.mark.parametrize("only", ["h0_at_t0", "row_across_blocks"])
+    def test_dWh_term_that_crosses_a_block_edge(self, only):
+        # T is two blocks of the longest rung. dWh is the sum over t of
+        # h_prev[t]^T dz[t], and a masked step has dz = 0, so the mask
+        # leaves one term standing. h0_at_t0: only t = 0 is kept, and the
+        # term is h0^T dz[0], which the last grid step selects. row_across
+        # _blocks: only the last timestep of the first block and the
+        # first of the second (edge - 1 and edge) are kept and h0 is
+        # zero, so edge - 1 adds nothing and the term is
+        # hk[edge - 1]^T dz[edge]: a block's first timestep, whose h_prev
+        # is the last row of the block before.
+        edge = lstm_ops._TIME_BLOCKS[0]
+        t, b, n = 2 * edge, 16, 128
+        xw, bias, h0, c0, Wh, p, _ = _data(t=t, b=b, n=n, masked=None)
+        kept = [0] if only == "h0_at_t0" else [edge - 1, edge]
+        if only == "row_across_blocks":
+            h0 = jnp.zeros_like(h0)
+        m = np.zeros((t, b), np.float32)
+        m[kept] = 1.0
+        args = (xw, bias, h0, c0, Wh, p, jnp.asarray(m))
+        assert edge > 1 and self._time_blocks_traced(
+            self._pallas, *args) == {"forward": edge}
+        with jax.default_matmul_precision("highest"):
+            g_p = jax.grad(_loss_through(self._pallas),
+                           argnums=GRAD_ARGNUMS)(*args)
+            g_x = jax.grad(_loss_through(self._xla),
+                           argnums=GRAD_ARGNUMS)(*args)
+            hk = lstm_ops._lstm_seq_kernels(*args)[0]
+        for name, gp, gx in zip(GRAD_NAMES, g_p, g_x):
+            _assert_close(gp, gx, jnp.float32, f"{only}: {name}")
+        dxw, dWh = g_p[0], g_p[4]
+        assert not np.asarray(dxw)[[k for k in range(t) if k not in kept]].any()
+        h_prev = h0 if only == "h0_at_t0" else hk[edge - 1]
+        with jax.default_matmul_precision("highest"):
+            term = h_prev.T @ dxw[kept[-1]]
+        assert float(jnp.abs(term).max()) > 1e-2
+        np.testing.assert_allclose(dWh, term, rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("t,want", [(1, 1), (7, 1), (13, 1), (6, 2),
+                                        (50, 2), (12, 4), (16, 4), (64, 4),
+                                        (1000, 4), (1024, 4)])
+    def test_time_block_is_a_function_of_the_length(self, t, want):
+        # the longest of 4, 2, 1 that divides T, while the request
+        # fits: T = 1 (rnn_time_step, decode) and a prime T give 1
+        assert lstm_ops._time_block(t, lambda tb: 0) == want
+        assert t % want == 0
+        # a request over the cap sends the call down the ladder, to 1 at
+        # the last whatever 1 asks
+        over = lstm_ops._VMEM_CAP + 1
+        assert lstm_ops._time_block(
+            t, lambda tb: over if tb > 2 else 0) == min(want, 2)
+        assert lstm_ops._time_block(t, lambda tb: over - 1) == want
+        assert lstm_ops._time_block(t, lambda tb: over) == 1
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+    @pytest.mark.parametrize("t,tb", [(1024, 4), (1021, 1), (1, 1)])
+    def test_blocking_of_the_calls_at_the_bench_shape(self, t, tb, masked):
+        # b=256, n=512 in bf16, traced only. The request stays under the
+        # cap with and without a mask; the grid is T / Tb long; every
+        # [T, ...] operand and result moves in (Tb, b, width) blocks and
+        # the backward is given one more (1, b, n) row of hk; at Tb = 1
+        # grid and blocks are those of the kernel of one timestep a grid
+        # step (no second hk operand).
+        b, n, cd = 256, 512, jnp.bfloat16
+        seq = lambda width: jax.ShapeDtypeStruct((t, b, width), cd)
+        row = jax.ShapeDtypeStruct((b, n), cd)
+        Wh, p = (jax.ShapeDtypeStruct(s, cd) for s in ((n, 4 * n), (3, n)))
+        mask = jax.ShapeDtypeStruct((t, b), cd) if masked else None
+        calls = {
+            "forward": (lstm_ops._fwd_call, (seq(4 * n), row, row, Wh, p,
+                                             mask)),
+            "backward": (lstm_ops._bwd_call, (
+                (seq(4 * n), seq(n), seq(n), row, mask, Wh, p),
+                (seq(n), row, row)))}
+        streamed = {"forward": [4 * n, n, 4 * n, n],        # xz, hk, G, c_prev
+                    "backward": [4 * n] + [n] * (tb > 1) + [n, n, 4 * n]}
+        for direction, (call, args) in calls.items():
+            assert self._time_blocks_traced(call, *args) == {direction: tb}
+            eqn, = (e for e in jax.make_jaxpr(call)(*args).jaxpr.eqns
+                    if e.primitive.name == "pallas_call")
+            grid = eqn.params["grid_mapping"]
+            assert grid.grid == (t // tb,)
+            blocks = [tuple(getattr(d, "block_size", d)
+                            for d in m.block_shape)
+                      for m in grid.block_mappings]
+            in_time = [blk for blk in blocks if len(blk) == 3]
+            want = [(tb, b, 1)] * masked + [(tb, b, w)
+                                            for w in streamed[direction]]
+            if direction == "backward":     # ... and the row one back
+                want.insert(masked + 1 + (tb > 1), (1, b, n))
+            assert in_time == want, (direction, in_time)
+            limit = eqn.params["compiler_params"][
+                "mosaic_tpu"].vmem_limit_bytes
+            assert lstm_ops._VMEM_DEFAULT <= limit <= lstm_ops._VMEM_CAP
+            if tb == 1:     # what the one-timestep kernel asked before
+                assert limit < 40 * 2 ** 20
 
     @pytest.mark.parametrize("masked", [False, True])
     def test_bias_gradient_is_the_sum_of_dxw(self, masked):

@@ -49,12 +49,12 @@ def _on(sharding, tree):
         tree)
 
 
-def _pallas_operands(call, args):
-    """How many arrays the one ``pallas_call`` of ``call`` is given."""
+def _pallas_call(call, args):
+    """The one ``pallas_call`` equation of ``call``."""
     eqns = [e for e in jax.make_jaxpr(call)(*args).jaxpr.eqns
             if e.primitive.name == "pallas_call"]
     assert len(eqns) == 1, eqns
-    return len(eqns[0].invars)
+    return eqns[0]
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
@@ -68,17 +68,33 @@ def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32, masked):
     # residuals (G, hk, c_prev, h0, mask, Wh, p) and the cotangents
     bwd_args = ((seq(4 * N), seq(N), seq(N), row, mask, Wh, p),
                 (seq(N), row, row))
+    # the blocking the shapes choose: T = 1,024 gives the longest rung,
+    # and Mosaic takes the (Tb, b, width) blocks, the unrolled body and
+    # the VMEM the call asks for
+    tb = lstm_ops._TIME_BLOCKS[0]
+    assert tb > 1 and T % tb == 0
     for call, args in ((lstm_ops._fwd_call, fwd_args),
                        (lstm_ops._bwd_call, bwd_args)):
+        eqn = _pallas_call(call, args)
+        assert eqn.params["grid_mapping"].grid == (T // tb,)
+        limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert limit <= lstm_ops._VMEM_CAP
         compiled = jax.jit(call).lower(*_on(one_chip, args)).compile()
-        assert "tpu_custom_call" in compiled.as_text()
+        text = compiled.as_text()
+        # the lowered text holds the kernel as MLIR bytecode; what it
+        # shows of the call is the target and the VMEM it was given
+        assert "tpu_custom_call" in text
+        assert f'"size":"{limit}"' in text
     # one hidden stream: hk, hT, cT, G, c_prev and no h_prev; a mask
-    # operand only where a mask was given
+    # operand only where a mask was given; the backward reads hk twice,
+    # as the block's own rows and as the one row before them
     fwd_out = jax.eval_shape(lstm_ops._fwd_call, *fwd_args)
     assert [o.shape for o in fwd_out] == [
         (T, B, N), (B, N), (B, N), (T, B, 4 * N), (T, B, N)]
-    assert _pallas_operands(lstm_ops._fwd_call, fwd_args) == 5 + masked
-    assert _pallas_operands(lstm_ops._bwd_call, bwd_args) == 9 + masked
+    assert len(_pallas_call(lstm_ops._fwd_call, fwd_args).invars) == (
+        5 + masked)
+    assert len(_pallas_call(lstm_ops._bwd_call, bwd_args).invars) == (
+        10 + masked)
     # dxz, dh0, dc0, dWh, dp, and the bias gradient as one row
     db = jax.eval_shape(lstm_ops._bwd_call, *bwd_args)[-1]
     assert (db.shape, db.dtype) == ((1, 4 * N), cd)
