@@ -278,11 +278,8 @@ def phase_kernels(hidden=512, seq=64, bptt_seq=50, batches=(32, 256),
         # the Pallas LSTM must be IN the step, compiled — not the silent
         # xla delegate behind ops/lstm.py's support gate
         step = net._train_step or net._build_train_step()
-        lowered = step.lower(
-            net.params, net.state, net.opt_state,
-            jnp.asarray(net.iteration, jnp.int32), jnp.asarray(ds.features),
-            jnp.asarray(ds.labels), None, None,
-            jax.random.PRNGKey(0)).as_text()
+        lowered = step.lower(*net._step_args(
+            net._batch_args(ds), jax.random.PRNGKey(0))).as_text()
         n_calls = lowered.count("tpu_custom_call")
         _check(n_calls > 0, f"char_rnn b={b}: no tpu_custom_call in step")
         out["char_rnn"].append({"b": b, "score": round(score, 4),

@@ -159,27 +159,17 @@ def _fit_args(net, variant: int, row=None, label_row=None):
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.compilecache.precompile import (
-        _infer_row_shapes, _output_widths)
+        _dummy_batch, _infer_row_shapes)
 
-    batch = 2
     row_shapes = [row] if row is not None else _infer_row_shapes(net)
     if row_shapes is None:
         raise ValueError(f"cannot infer input shapes for {type(net)}")
-    fill = float(variant) * 0.25
-    it = jnp.asarray(variant, jnp.int32)
-    rng = jax.random.PRNGKey(variant)
-    if hasattr(net.conf, "network_inputs"):        # ComputationGraph
-        inputs = {name: jnp.full((batch,) + tuple(s), fill, jnp.float32)
-                  for name, s in zip(net.conf.network_inputs, row_shapes)}
-        labels = [jnp.full((batch, n), fill, jnp.float32)
-                  for n in _output_widths(net)]
-        return (net.params, net.state, net.opt_state, it, inputs, labels,
-                {}, None, rng)
-    label_row = label_row if label_row is not None \
-        else (_output_widths(net)[0],)
-    x = jnp.full((batch,) + tuple(row_shapes[0]), fill, jnp.float32)
-    y = jnp.full((batch,) + tuple(label_row), fill, jnp.float32)
-    return (net.params, net.state, net.opt_state, it, x, y, None, None, rng)
+    batch = _dummy_batch(net, 2, row_shapes,
+                         None if label_row is None else [label_row],
+                         fill=float(variant) * 0.25)
+    return (net.params, net.state, net.opt_state,
+            jnp.asarray(variant, jnp.int32), *net._batch_args(batch),
+            jax.random.PRNGKey(variant))
 
 
 def _forward_args(net, variant: int, row=None):

@@ -30,6 +30,7 @@ import numpy as np
 
 from deeplearning4j_tpu.compilecache import cache as _cache
 from deeplearning4j_tpu.compilecache import manifest as _manifest
+from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 
 
 def precompile_serving(net, *, cache_dir: str, max_batch: int = 1024,
@@ -88,21 +89,10 @@ def precompile_fit(net, *, cache_dir: str, batch: int = 32,
     if row_shapes is None:
         raise ValueError(
             "cannot infer training input shapes — pass input_shapes")
-    is_graph = hasattr(net.conf, "network_inputs")
-    it = jnp.asarray(0, jnp.int32)
-    rng = jax.random.PRNGKey(0)
-    if is_graph:
-        inputs = {name: jnp.zeros((batch,) + tuple(s), jnp.float32)
-                  for name, s in zip(net.conf.network_inputs, row_shapes)}
-        labels = [jnp.zeros((batch, n), jnp.float32)
-                  for n in _output_widths(net)]
-        lowered = step.lower(net.params, net.state, net.opt_state, it,
-                             inputs, labels, {}, None, rng)
-    else:
-        x = jnp.zeros((batch,) + tuple(row_shapes[0]), jnp.float32)
-        y = jnp.zeros((batch, _output_widths(net)[0]), jnp.float32)
-        lowered = step.lower(net.params, net.state, net.opt_state, it,
-                             x, y, None, None, rng)
+    zeros = net._batch_args(_dummy_batch(net, batch, row_shapes))
+    lowered = step.lower(net.params, net.state, net.opt_state,
+                         jnp.asarray(0, jnp.int32), *zeros,
+                         jax.random.PRNGKey(0))
     lowered.compile()
     return {
         "kind": "train_step",
@@ -110,6 +100,19 @@ def precompile_fit(net, *, cache_dir: str, batch: int = 32,
         "batch": int(batch),
         "row_shapes": [list(s) for s in row_shapes],
     }
+
+
+def _dummy_batch(net, batch: int, row_shapes, label_rows=None,
+                 fill: float = 0.0):
+    """A constant minibatch of the net's training shapes (label rows
+    default to every output head's ``n_out``). A DataSet suits both nets;
+    only a graph takes several inputs or heads."""
+    label_rows = label_rows or [(n,) for n in _output_widths(net)]
+    xs = [np.full((batch,) + tuple(s), fill, np.float32) for s in row_shapes]
+    ys = [np.full((batch,) + tuple(s), fill, np.float32) for s in label_rows]
+    if len(xs) == len(ys) == 1:
+        return DataSet(xs[0], ys[0])
+    return MultiDataSet(xs, ys)
 
 
 def _infer_row_shapes(net) -> Optional[list]:

@@ -57,8 +57,6 @@ def shard_batch(mesh: Mesh, data_axis: str, x):
     multi-process runtime each process passes its LOCAL slice of the
     global batch (the Spark-partition analogue — SURVEY.md §3.4); the
     slices are assembled into one global sharded Array."""
-    if x is None:
-        return None
     spec = P(data_axis) if np.ndim(x) >= 1 else P()
     sh = NamedSharding(mesh, spec)
     if jax.process_count() > 1:
@@ -66,65 +64,34 @@ def shard_batch(mesh: Mesh, data_axis: str, x):
     return jax.device_put(jnp.asarray(x), sh)
 
 
-def _pad_batch(x, labels, fmask, lmask, multiple: int):
-    """Pad a partial batch up to a multiple of the data-axis size. Padded
-    examples are masked out via the label mask, so the loss mean (and thus
-    gradients) are identical to the unpadded batch."""
-    n = x.shape[0]
-    target = -(-n // multiple) * multiple
-    if target == n:
-        return x, labels, fmask, lmask
-    pad = target - n
-
-    def pad0(a):
-        if a is None:
-            return None
-        widths = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
-        return jnp.pad(jnp.asarray(a), widths)
-
-    if lmask is None:
-        # per-example mask shaped like the label-mask convention
-        lead = labels.shape[:-1] if labels.ndim > 1 else labels.shape
-        lmask = jnp.ones(lead, jnp.float32)
-    return pad0(x), pad0(labels), pad0(fmask), pad0(lmask)
-
-
-def shard_step(net, step_fn, mesh: Mesh, data_axis: str = "data"):
-    """Jit the train step for mesh execution. Params arrive replicated and
-    batches sharded (set by apply_mesh/shard_batch); partial batches are
-    zero-padded + mask-excluded so any batch size divides the mesh."""
-    n_shards = mesh.shape[data_axis]
-    # each process pads its LOCAL slice to its local share of the data axis
-    pad_multiple = max(n_shards // jax.process_count(), 1)
-
-    jitted = jax.jit(step_fn, donate_argnums=(0, 1, 2))
-
-    def wrapped(params, state, opt_state, it, x, labels, fmask, lmask, rng):
-        x, labels, fmask, lmask = _pad_batch(x, labels, fmask, lmask,
-                                             pad_multiple)
-        x = shard_batch(mesh, data_axis, x)
-        labels = shard_batch(mesh, data_axis, labels)
-        fmask = shard_batch(mesh, data_axis, fmask)
-        lmask = shard_batch(mesh, data_axis, lmask)
-        rng = replicate(mesh, rng)
-        args = (params, state, opt_state, it, x, labels, fmask, lmask, rng)
-        opindex.register(jitted, args, args[4:8])
-        return jitted(*args)
-
-    return wrapped
+def _pad_batch(batch, multiple: int):
+    """Pad a partial batch ``(inputs, labels, fmasks, lmasks)`` up to a
+    multiple of the data-axis size. Padded examples are masked out via the
+    label masks (a ones mask for every label that came without one), so
+    the loss mean (and thus gradients) are identical to the unpadded
+    batch."""
+    inputs, labels, fmasks, lmasks = batch
+    pad = -jax.tree_util.tree_leaves(inputs)[0].shape[0] % multiple
+    if not pad:
+        return batch
+    label_leaves, treedef = jax.tree_util.tree_flatten(labels)
+    mask_leaves = ([None] * len(label_leaves) if lmasks is None
+                   else treedef.flatten_up_to(lmasks))
+    # per-example mask shaped like the label-mask convention: [b] for
+    # [b, c] labels, [b, t] for [b, t, c] sequence labels
+    lmasks = treedef.unflatten([
+        jnp.ones(l.shape[:-1] if l.ndim > 1 else l.shape, jnp.float32)
+        if m is None else m for l, m in zip(label_leaves, mask_leaves)])
+    return jax.tree_util.tree_map(
+        lambda a: jnp.pad(jnp.asarray(a), [(0, pad)] + [(0, 0)] * (a.ndim - 1)),
+        (inputs, labels, fmasks, lmasks))
 
 
-def _mask_lead_shape(label):
-    """Label-mask leading shape: [b] for [b, c] labels, [b, t] for
-    [b, t, c] sequence labels."""
-    return label.shape[:-1] if label.ndim > 1 else label.shape
-
-
-def shard_step_multi(net, step_fn, mesh: Mesh, data_axis: str = "data"):
-    """ComputationGraph variant of shard_step: inputs are a dict and labels/
-    masks are lists; every batch-leading tensor is sharded over the data
-    axis; partial batches are zero-padded with padded rows excluded via the
-    per-output label masks."""
+def shard_step(step_fn, mesh: Mesh, data_axis: str = "data"):
+    """Jit the train step for mesh execution. Params arrive replicated
+    (set by apply_mesh) and every leaf of the batch ``(inputs, labels,
+    fmasks, lmasks)`` is sharded over the data axis here; partial batches
+    are zero-padded + mask-excluded so any batch size divides the mesh."""
     n_shards = mesh.shape[data_axis]
     # each process pads its LOCAL slice to its local share of the data axis
     pad_multiple = max(n_shards // jax.process_count(), 1)
@@ -133,36 +100,10 @@ def shard_step_multi(net, step_fn, mesh: Mesh, data_axis: str = "data"):
 
     def wrapped(params, state, opt_state, it, inputs, labels, fmasks, lmasks,
                 rng):
-        n = next(iter(inputs.values())).shape[0]
-        target = -(-n // pad_multiple) * pad_multiple
-        if target != n:
-            pad = target - n
-
-            def pad0(a):
-                if a is None:
-                    return None
-                widths = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
-                return jnp.pad(jnp.asarray(a), widths)
-
-            inputs = {k: pad0(v) for k, v in inputs.items()}
-            if lmasks is None:
-                lmasks = [jnp.ones(_mask_lead_shape(l), jnp.float32)
-                          for l in labels]
-            else:
-                lmasks = [jnp.ones(_mask_lead_shape(l), jnp.float32)
-                          if m is None else m
-                          for l, m in zip(labels, lmasks)]
-            labels = [pad0(l) for l in labels]
-            lmasks = [pad0(m) for m in lmasks]
-            fmasks = {k: pad0(v) for k, v in fmasks.items()}
-        inputs = {k: shard_batch(mesh, data_axis, v) for k, v in inputs.items()}
-        labels = [shard_batch(mesh, data_axis, l) for l in labels]
-        fmasks = {k: shard_batch(mesh, data_axis, v) for k, v in fmasks.items()}
-        if lmasks is not None:
-            lmasks = [shard_batch(mesh, data_axis, m) for m in lmasks]
-        rng = replicate(mesh, rng)
-        args = (params, state, opt_state, it, inputs, labels, fmasks, lmasks,
-                rng)
+        batch = jax.tree_util.tree_map(
+            lambda a: shard_batch(mesh, data_axis, a),
+            _pad_batch((inputs, labels, fmasks, lmasks), pad_multiple))
+        args = (params, state, opt_state, it, *batch, replicate(mesh, rng))
         opindex.register(jitted, args, args[4:8])
         return jitted(*args)
 
@@ -218,13 +159,7 @@ class ParallelWrapper:
                         net._rng_key, rng = jax.random.split(net._rng_key)
                         it_c = jnp.asarray(net.iteration, jnp.int32)
                         p, s, o, score = step(
-                            p, s, o, it_c,
-                            jnp.asarray(ds.features), jnp.asarray(ds.labels),
-                            None if ds.features_mask is None
-                            else jnp.asarray(ds.features_mask),
-                            None if ds.labels_mask is None
-                            else jnp.asarray(ds.labels_mask),
-                            rng)
+                            p, s, o, it_c, *net._batch_args(ds), rng)
                         replicas[w] = (p, s, o)
                         scores.append(score)
                     if done:

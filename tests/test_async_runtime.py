@@ -27,11 +27,13 @@ from deeplearning4j_tpu.nn.conf.layers import Dense, Output
 from deeplearning4j_tpu.nn.conf.layers_recurrent import GravesLSTM, RnnOutput
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.nn.trainer import Trainer
 from deeplearning4j_tpu.nn.updater import Adam
 from deeplearning4j_tpu.optimize.listeners import (
     CollectScoresIterationListener,
     PerformanceListener,
     ScoreIterationListener,
+    TrainingListener,
 )
 
 
@@ -141,6 +143,86 @@ def test_chunked_replay_gives_listeners_identical_score_stream():
 
     assert len(pipe_scores.scores) == 10
     assert pipe_scores.scores == seq_scores.scores
+
+
+class CallRecorder(TrainingListener):
+    """Every listener call ``fit`` makes, in order."""
+
+    needs_per_iteration = False     # fit may keep its chunked path
+
+    def __init__(self):
+        self.calls = []
+
+    def on_epoch_start(self, net):
+        self.calls.append(("epoch_start", net.epoch))
+
+    def iteration_done(self, net, iteration, epoch):
+        self.calls.append(("iteration", iteration, epoch,
+                           net.last_batch_examples))
+
+    def on_epoch_end(self, net):
+        self.calls.append(("epoch_end", net.epoch))
+
+
+def build_net(kind):
+    return (MultiLayerNetwork(build_mlp(dim=10)).init() if kind == "mln"
+            else build_graph())
+
+
+@pytest.mark.parametrize("mode", ["per_batch", "chunked", "one_dataset"])
+@pytest.mark.parametrize("kind", ["mln", "graph"])
+def test_fit_gives_both_nets_the_same_listener_call_stream(kind, mode):
+    """One fit loop: epoch start, every iteration with its batch's size,
+    epoch end, on either net, per batch or chunked, from an iterator or
+    from one DataSet (which runs the same loop: no hook is skipped)."""
+    x, y = make_blobs(n=56, dim=10)             # 3 batches of 16 + one of 8
+    data = (DataSet(x[:16], y[:16]) if mode == "one_dataset"
+            else ArrayDataSetIterator(x, y, batch_size=16))
+    sizes = [16] if mode == "one_dataset" else [16, 16, 16, 8]
+    net = build_net(kind)
+    rec = CallRecorder()
+    net.set_listeners(rec)
+    net.fit(data, epochs=2, device_prefetch=False,
+            multi_step=4 if mode == "chunked" else 1)
+    expected, it = [], 0
+    for epoch in range(2):
+        expected.append(("epoch_start", epoch))
+        for n in sizes:
+            it += 1
+            expected.append(("iteration", it, epoch, n))
+        expected.append(("epoch_end", epoch))
+    assert rec.calls == expected
+    assert net.epoch == 2 and net.iteration == it
+
+
+@pytest.mark.parametrize("kind", ["mln", "graph"])
+def test_fit_batch_repeated_counts_its_batch_and_add_listener(kind):
+    """``last_batch_examples`` (what PerformanceListener counts) is set
+    by the scanned path too, and both nets take ``add_listener``."""
+    x, y = make_blobs(n=24, dim=10)
+    net = build_net(kind)
+    rec = CallRecorder()
+    assert net.add_listener(rec) is net and net.listeners == [rec]
+    net.fit_batch(DataSet(x[:8], y[:8]))
+    net.fit_batch_repeated(DataSet(x, y), 3)
+    assert net.last_batch_examples == 24
+    assert net.iteration == 4
+    assert rec.calls == [("iteration", 1, 0, 8)]    # a scan replays nothing
+
+
+@pytest.mark.parametrize("cls, own", [
+    (MultiLayerNetwork, set()),
+    # fit_batch_repeated's tBPTT rule is the graph's own (its docstring)
+    (ComputationGraph, {"_repeat_per_batch"}),
+])
+def test_nets_define_nothing_the_trainer_owns(cls, own):
+    """The fit loop, the step build and the mesh wrap live in Trainer
+    alone: a net that defines one of its names has forked the loop."""
+    owned = {n for n in vars(Trainer) if not n.startswith("__")}
+    assert cls.__mro__[1] is Trainer
+    assert (owned & set(vars(cls))) == own
+    for adapter in ("_batch_args", "_needs_tbptt", "_tbptt_length", "_loss"):
+        assert adapter in vars(cls) and adapter not in vars(Trainer)
 
 
 def test_per_iteration_listener_disables_chunking():

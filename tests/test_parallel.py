@@ -12,7 +12,9 @@ import pytest
 
 from deeplearning4j_tpu.datasets import ArrayDataSetIterator, DataSet
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import Dense, Output
+from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.nn.updater import Sgd
 from deeplearning4j_tpu.parallel import ParallelWrapper, make_mesh
@@ -82,12 +84,31 @@ def test_sharded_inference_matches():
     np.testing.assert_allclose(out_single, out_sharded, rtol=1e-5, atol=1e-6)
 
 
-def test_sharded_step_partial_batch():
+def build_mlp_graph(updater):
+    """``build_mlp`` as a ComputationGraph, layer names and all."""
+    conf = (NeuralNetConfiguration.builder().seed(123).updater(updater)
+            .weight_init("xavier").graph_builder().add_inputs("in")
+            .add_layer("layer_0", Dense(n_out=64, activation="relu"), "in")
+            .add_layer("layer_1", Dense(n_out=64, activation="relu"),
+                       "layer_0")
+            .add_layer("layer_2", Output(n_out=4, activation="softmax",
+                                         loss="mcxent"), "layer_1")
+            .set_outputs("layer_2")
+            .set_input_types(InputType.feed_forward(20)).build())
+    return ComputationGraph(conf)
+
+
+@pytest.mark.parametrize("make_net", [
+    lambda: MultiLayerNetwork(build_mlp(updater=Sgd(0.1))),
+    lambda: build_mlp_graph(Sgd(0.1)),
+], ids=["mln", "graph"])
+def test_sharded_step_partial_batch(make_net):
     """Partial final batches (not divisible by mesh size) must train without
-    error and match the unsharded result (pad+mask path)."""
+    error and match the unsharded result (pad+mask path), whichever net
+    arranges the batch."""
     x, y = make_blobs(n=250, seed=11)  # 250 % 64 = 58, 58 % 8 != 0
-    net_single = MultiLayerNetwork(build_mlp(updater=Sgd(0.1))).init()
-    net_sharded = MultiLayerNetwork(build_mlp(updater=Sgd(0.1))).init()
+    net_single = make_net().init()
+    net_sharded = make_net().init()
     net_sharded.use_mesh(make_mesh({"data": 8}))
     net_single.fit(ArrayDataSetIterator(x, y, batch_size=64), epochs=2,
                    async_prefetch=False)
