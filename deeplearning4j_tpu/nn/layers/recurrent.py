@@ -46,20 +46,20 @@ def _lstm_scan(params, x, h0, c0, mask, gate_act, cell_act):
     """Run an LSTM over [b, t, f]; returns (y [b,t,n], hT, cT).
 
     Runs entirely in x.dtype (the compute dtype — bf16 under the mixed
-    policy, so the recurrent matmul hits the MXU at full rate). The input
-    projection for the whole sequence is one MXU matmul; the time loop is
-    the ``lstm_sequence`` registry op (Pallas fused kernel on TPU, lax.scan
-    under autodiff elsewhere — the LSTMHelpers.java:57,271 seam). The op
-    owns the bias: it adds it to the projection and returns its gradient,
-    which the Pallas backward kernel sums itself."""
+    policy, so the recurrent matmul hits the MXU at full rate). The
+    whole layer is the ``lstm_sequence`` registry op (Pallas fused kernel
+    on TPU, lax.scan under autodiff elsewhere — the LSTMHelpers.java:57,
+    271 seam). The op owns the input projection and the bias: one MXU
+    matmul over the whole sequence ahead of the time loop, or, on the
+    Pallas backend for an input no wider than the hidden state, made
+    inside the forward kernel."""
     cd = x.dtype
     params = {k: v.astype(cd) for k, v in params.items()}
-    xw = jnp.einsum("btf,fg->btg", x, params["Wx"])
-    xw_t = jnp.moveaxis(xw, 1, 0)  # [t, b, 4n]
+    x_t = jnp.moveaxis(x, 1, 0)  # [t, b, n_in]
     mask_t = None if mask is None else jnp.moveaxis(mask, 1, 0)  # [t, b]
     ys, hT, cT = ops.get("lstm_sequence")(
-        xw_t, params["b"], h0, c0, params["Wh"], params["p"], mask_t,
-        gate_act=gate_act, cell_act=cell_act)
+        x_t, params["Wx"], params["b"], h0, c0, params["Wh"], params["p"],
+        mask_t, gate_act=gate_act, cell_act=cell_act)
     return jnp.moveaxis(ys, 0, 1), hT, cT
 
 

@@ -14,12 +14,13 @@ Why a Pallas kernel: the scan path issues ~10 small XLA ops per timestep
 and re-reads the recurrent weight Wh from HBM every step (measured 88us
 per timestep on a v5e for batch 32, hidden 512 — 0.7% MFU). The Pallas
 kernel runs the WHOLE time loop in one kernel launch with Wh and the
-(h, c) carry resident in VMEM, streaming xz[t] in and the carried hidden
-state h[t], the gates G[t] and c_prev[t] out — the cuDNN-class schedule.
-h[t] is the one hidden stream: it is the output (times the mask, taken
-outside the kernel), and the backward reads it one row back as
-h_prev[t] = h[t-1], with h0 at t = 0. Without a mask (known at trace
-time) neither kernel takes a mask operand or does mask arithmetic.
+(h, c) carry resident in VMEM, streaming the input rows in and the
+carried hidden state h[t], the gates G[t] and c_prev[t] out — the
+cuDNN-class schedule. h[t] is the one hidden stream: it is the output
+(times the mask, taken outside the kernel), and the backward reads it
+one row back as h_prev[t] = h[t-1], with h0 at t = 0. Without a mask
+(known at trace time) neither kernel takes a mask operand or does mask
+arithmetic.
 
 A grid step is Tb consecutive timesteps, not one: every streamed operand
 and result moves in (Tb, b, width) blocks, the backward walks the blocks
@@ -45,13 +46,28 @@ Gate math (Graves formulation with peepholes, order i, f, o, g):
     o = sigmoid(zo + p_o * c)           h = o * tanh(c)
 Masked steps carry (h, c) through unchanged and emit zero output.
 
-The op consumes the PRE-PROJECTED input xw[t] = x[t] @ Wx (one big MXU
-matmul outside the time loop) and the bias b, which it adds itself:
-xz[t] = xw[t] + b. The Pallas backend adds b in plain jnp ahead of the
-forward kernel, so XLA keeps the add in the projection matmul's epilogue;
-its backward kernel emits dxz, from which the caller's autodiff recovers
-dWx/dx with dense matmuls, and db = sum over (t, rows) of dz, accumulated
-in VMEM beside dWh and dp. Nothing reads dxz a second time to reduce it.
+The op owns the input projection and the bias: it takes x [t, b, n_in],
+Wx [n_in, 4n] and b, and xz[t] = x[t] @ Wx + b is its own first step. On
+the xla backend that is one einsum over the whole sequence, the bias
+added, ahead of the scan. The Pallas backend chooses by the shapes of
+the call (``_kernel_projects``). An input no wider than the hidden state
+(one-hot characters, an embedding, a stacked layer of the same width) is
+projected by the forward kernel itself: xz would be 4n / n_in times the
+bytes of x, written once by a matmul and read once by the kernel (1.07
+GB a step each way at b=256, n=512, T=1,024), so the kernel streams the
+(Tb, b, n_in) block of x instead, holds Wx and b in VMEM beside Wh, and
+forms z = x[t] @ Wx + b + h_prev @ Wh in f32 (xz no longer passes
+through the compute dtype). x[t] @ Wx waits for no h: it stands in the
+straight-line block off the recurrence's chain, where the MXU is idle
+while the gate math runs. A wider input (n_in > n), or weights that do
+not fit VMEM together, is projected by one matmul outside the time loop,
+with the bias add as its epilogue, and the forward kernel streams xz in.
+The backward kernel is the same whoever projected: it emits dxz, and
+db = sum over (t, rows) of dz, accumulated in VMEM beside dWh and dp
+(nothing reads dxz a second time to reduce it); the custom VJP then
+takes dWx = x^T dxz and dx = dxz Wx^T as dense matmuls over the whole
+sequence. Which way a call went is counted in
+``dl4j_lstm_kernel_calls_total{projection}``.
 """
 
 from __future__ import annotations
@@ -88,17 +104,23 @@ def _cell_step(Wh, p, gate_act, cell_act, carry, inp):
     return (h_keep, c_keep), h * mcol
 
 
-@registry.register("lstm_sequence", backend="xla")
-def lstm_sequence_xla(xw_t, bias, h0, c0, Wh, p, mask_t, *,
-                      gate_act="sigmoid", cell_act="tanh"):
-    """Time-major LSTM over pre-projected inputs.
+def _project(x_t, Wx, bias):
+    """xz_t [t, b, 4n] = x_t @ Wx + bias: one matmul over the whole
+    sequence, outside the time loop, with the bias add as its epilogue."""
+    return jnp.einsum("tbf,fg->tbg", x_t, Wx) + bias
 
-    xw_t: [t, b, 4n], x @ Wx without the bias; bias: [4n]; h0, c0: [b, n];
+
+@registry.register("lstm_sequence", backend="xla")
+def lstm_sequence_xla(x_t, Wx, bias, h0, c0, Wh, p, mask_t, *,
+                      gate_act="sigmoid", cell_act="tanh"):
+    """Time-major LSTM over a sequence of inputs.
+
+    x_t: [t, b, n_in]; Wx: [n_in, 4n]; bias: [4n]; h0, c0: [b, n];
     Wh: [n, 4n]; p: [3, n] peepholes; mask_t: [t, b] or None. Returns
     (y_t [t, b, n], hT, cT)."""
     ga = act_mod.get(gate_act) if isinstance(gate_act, str) else gate_act
     ca = act_mod.get(cell_act) if isinstance(cell_act, str) else cell_act
-    xz_t = xw_t + bias
+    xz_t = _project(x_t, Wx, bias)
     step = partial(_cell_step, Wh, p, ga, ca)
     if mask_t is None:
         (hT, cT), ys = jax.lax.scan(
@@ -112,13 +134,13 @@ def lstm_sequence_xla(xw_t, bias, h0, c0, Wh, p, mask_t, *,
 _interpret = registry.pallas_interpret
 
 
-def _pallas_supported(xw_t, h0, gate_act, cell_act):
+def _pallas_supported(x_t, h0, gate_act, cell_act):
     if gate_act != "sigmoid" or cell_act != "tanh":
         return False
-    if xw_t.dtype not in (jnp.bfloat16, jnp.float32):
+    if x_t.dtype not in (jnp.bfloat16, jnp.float32):
         return False
     b, n = h0.shape[-2], h0.shape[-1]
-    sublane = 16 if xw_t.dtype == jnp.bfloat16 else 8
+    sublane = 16 if x_t.dtype == jnp.bfloat16 else 8
     if n % 128 != 0 or b % sublane != 0:
         return False
     if not _interpret() and jax.default_backend() != "tpu":
@@ -126,11 +148,15 @@ def _pallas_supported(xw_t, h0, gate_act, cell_act):
     return True
 
 
-def _fwd_kernel(*refs, masked, Tb):
+def _fwd_kernel(*refs, masked, projected, Tb):
     import jax.experimental.pallas as pl
 
     m_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    (xz_ref, h0_ref, c0_ref, Wh_ref, p_ref,
+    # the streamed input: xz rows, or the x rows the kernel projects
+    x_ref, refs = refs[0], refs[1:]
+    Wx_ref, b_ref, refs = (refs[0], refs[1], refs[2:]) if projected else (
+        None, None, refs)
+    (h0_ref, c0_ref, Wh_ref, p_ref,
      hk_ref, hT_ref, cT_ref, G_ref, cprev_ref, h_scr, c_scr) = refs
     t = pl.program_id(0)
     T = pl.num_programs(0)
@@ -142,15 +168,24 @@ def _fwd_kernel(*refs, masked, Tb):
 
     h = h_scr[:]
     c = c_scr[:]
-    cd = xz_ref.dtype
+    cd = x_ref.dtype
     n = h.shape[-1]
     pvec = p_ref[:].astype(jnp.float32)
+    if projected:
+        bias = b_ref[:].astype(jnp.float32)
 
     # the block's Tb timesteps, straight-line: (h, c) are values from one
     # to the next, and no branch stands between them
     for s in range(Tb):
         h_prev, c_prev = h, c
-        z = xz_ref[s].astype(jnp.float32) + jnp.dot(
+        if projected:
+            # x[s] @ Wx + b waits for no h: it stands in the block beside
+            # the recurrence's chain, not on it
+            xz = jnp.dot(x_ref[s], Wx_ref[:],
+                         preferred_element_type=jnp.float32) + bias
+        else:
+            xz = x_ref[s].astype(jnp.float32)
+        z = xz + jnp.dot(
             h_prev.astype(cd), Wh_ref[:], preferred_element_type=jnp.float32)
         i = jax.nn.sigmoid(z[:, :n] + pvec[0:1, :] * c_prev)
         f = jax.nn.sigmoid(z[:, n:2 * n] + pvec[1:2, :] * c_prev)
@@ -354,6 +389,22 @@ def _time_block(T, request):
             return Tb
 
 
+def _kernel_projects(n_in, n, request):
+    """Whether the forward kernel makes x @ Wx + b itself, from the call's
+    shapes alone: where the input is no wider than the hidden state, and
+    the kernel of one timestep a grid step with Wx resident beside Wh
+    asks for no more VMEM than the cap (``request(1)``; f32 at n = 1,536
+    is over it). Then the [t, b, 4n] xz that an outside matmul would
+    write and the kernel read back is 4n / n_in times the x rows read
+    instead, and the projection's n_in / n of the recurrent matmul's work
+    finds the MXU idle while the gate math of the recurrence runs (on a
+    v5e at b=256, n=512 a timestep takes 4.0 us given xz, 3.9 us with 80
+    columns to project and 5.95 us with 512, where the outside matmul
+    took 2.8 us more). A wider input costs the kernel more MXU time than
+    its recurrence leaves idle, and stays one matmul ahead of it."""
+    return n_in <= n and request(1) <= _VMEM_CAP
+
+
 def _blocked_call(kernel, Tb, T, in_specs, out_specs, out_shapes, scratch,
                   interpret):
     """(call, request): the ``pallas_call`` of ``kernel`` over ``T // Tb``
@@ -382,15 +433,16 @@ def _blocked_call(kernel, Tb, T, in_specs, out_specs, out_shapes, scratch,
     return jax.jit(call, inline=True), request
 
 
-def _count_call(direction, Tb):
+def _count_call(direction, Tb, projected=False):
     from deeplearning4j_tpu.observability.metrics import get_registry
 
     get_registry().counter(
         "dl4j_lstm_kernel_calls_total",
-        "Pallas LSTM kernel calls traced, by direction and by the "
-        "timesteps one grid step takes",
-        ("direction", "time_block")).labels(
-            direction=direction, time_block=str(Tb)).inc()
+        "Pallas LSTM kernel calls traced, by direction, by the timesteps "
+        "one grid step takes and by where the input projection is made",
+        ("direction", "time_block", "projection")).labels(
+            direction=direction, time_block=str(Tb),
+            projection="kernel" if projected else "outside").inc()
 
 
 def _fixed2(r, cdim):
@@ -402,11 +454,14 @@ def _fixed2(r, cdim):
 
 
 @lru_cache(maxsize=None)
-def _fwd_blocked(T, b, n, cd, masked, *, Tb, interpret):
+def _fwd_blocked(T, b, n, n_in, cd, masked, *, Tb, interpret):
+    """``n_in`` is the width of the x rows the kernel projects, or None
+    where it is given xz."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n4 = 4 * n
+    projected = n_in is not None
     sds = jax.ShapeDtypeStruct
     out_shapes = (
         sds((T, b, n), cd),    # hk
@@ -417,8 +472,12 @@ def _fwd_blocked(T, b, n, cd, masked, *, Tb, interpret):
     )
     t_block = lambda width: pl.BlockSpec(
         (Tb, b, width), lambda t: (t, 0, 0), memory_space=pltpu.VMEM)
-    in_specs = [
-        t_block(n4),                                         # xz
+    if projected:
+        in_specs = [t_block(n_in),                           # x
+                    _fixed2(n_in, n4), _fixed2(1, n4)]       # Wx, b
+    else:
+        in_specs = [t_block(n4)]                             # xz
+    in_specs += [
         _fixed2(b, n), _fixed2(b, n),                        # h0, c0
         _fixed2(n, n4),                                      # Wh
         _fixed2(3, n),                                       # p
@@ -433,20 +492,33 @@ def _fwd_blocked(T, b, n, cd, masked, *, Tb, interpret):
     )
     scratch = [pltpu.VMEM((b, n), jnp.float32),
                pltpu.VMEM((b, n), jnp.float32)]
-    return _blocked_call(partial(_fwd_kernel, masked=masked), Tb, T,
-                         in_specs, out_specs, out_shapes, scratch, interpret)
+    return _blocked_call(
+        partial(_fwd_kernel, masked=masked, projected=projected), Tb, T,
+        in_specs, out_specs, out_shapes, scratch, interpret)
 
 
-def _fwd_call(xz_t, h0, c0, Wh, p, mask_t):
+def _fwd_blocked_for(x_t, h0, mask_t, n_in):
+    """``_fwd_blocked`` of a call's shapes, with ``Tb`` left open."""
+    T, b, _ = x_t.shape
+    return partial(_fwd_blocked, T, b, h0.shape[-1], n_in,
+                   jnp.dtype(x_t.dtype), mask_t is not None,
+                   interpret=_interpret())
+
+
+def _fwd_call(x_t, h0, c0, Wh, p, mask_t, Wx=None, bias=None):
     """(hk, hT, cT, G, c_prev): hk[t] is the hidden state carried out of
     step t, which is the step's output where the mask keeps the row.
-    ``mask_t`` None leaves the mask operand and its arithmetic out."""
-    T, b, n4 = xz_t.shape
-    blocked = partial(_fwd_blocked, T, b, n4 // 4, jnp.dtype(xz_t.dtype),
-                      mask_t is not None, interpret=_interpret())
+    ``x_t`` is xz, or with ``Wx`` and ``bias`` the x rows the kernel
+    projects itself. ``mask_t`` None leaves the mask operand and its
+    arithmetic out."""
+    T = x_t.shape[0]
+    projected = Wx is not None
+    blocked = _fwd_blocked_for(x_t, h0, mask_t,
+                               x_t.shape[-1] if projected else None)
     Tb = _time_block(T, lambda k: blocked(Tb=k)[1])
-    _count_call("forward", Tb)
-    args = (xz_t, h0, c0, Wh, p)
+    _count_call("forward", Tb, projected)
+    args = (h0, c0, Wh, p)
+    args = (x_t, Wx, bias[None, :]) + args if projected else (x_t,) + args
     if mask_t is not None:
         args = (mask_t[:, :, None],) + args
     return blocked(Tb=Tb)[0](*args)
@@ -525,46 +597,56 @@ def _bwd_call(res, cts):
 
 
 @jax.custom_vjp
-def _lstm_seq_kernels(xw_t, bias, h0, c0, Wh, p, mask_t):
-    return _lstm_seq_fwd(xw_t, bias, h0, c0, Wh, p, mask_t)[0]
+def _lstm_seq_kernels(x_t, Wx, bias, h0, c0, Wh, p, mask_t):
+    return _lstm_seq_fwd(x_t, Wx, bias, h0, c0, Wh, p, mask_t)[0]
 
 
-def _lstm_seq_fwd(xw_t, bias, h0, c0, Wh, p, mask_t):
-    # the bias add stays in plain jnp, ahead of the kernel: XLA fuses it
-    # into the projection matmul that made xw_t
-    hk, hT, cT, G, cprev = _fwd_call(xw_t + bias, h0, c0, Wh, p, mask_t)
-    return (hk, hT, cT), (G, hk, cprev, h0, mask_t, Wh, p)
+def _lstm_seq_fwd(x_t, Wx, bias, h0, c0, Wh, p, mask_t):
+    n_in = Wx.shape[0]
+    blocked = _fwd_blocked_for(x_t, h0, mask_t, n_in)
+    if _kernel_projects(n_in, h0.shape[-1], lambda k: blocked(Tb=k)[1]):
+        hk, hT, cT, G, cprev = _fwd_call(x_t, h0, c0, Wh, p, mask_t,
+                                         Wx, bias)
+    else:
+        hk, hT, cT, G, cprev = _fwd_call(_project(x_t, Wx, bias), h0, c0,
+                                         Wh, p, mask_t)
+    return (hk, hT, cT), ((G, hk, cprev, h0, mask_t, Wh, p), x_t, Wx)
 
 
 def _lstm_seq_bwd(res, cts):
-    dxz, dh0, dc0, dWh, dp, db = _bwd_call(res, cts)
-    return dxz, db[0], dh0, dc0, dWh, dp, None
+    # the backward kernel ends at dxz, whoever made xz: the projection's
+    # own two gradients are dense matmuls over the whole sequence
+    kernel_res, x_t, Wx = res
+    dxz, dh0, dc0, dWh, dp, db = _bwd_call(kernel_res, cts)
+    dx_t = jnp.einsum("tbg,fg->tbf", dxz, Wx)
+    dWx = jnp.einsum("tbf,tbg->fg", x_t, dxz)
+    return dx_t, dWx, db[0], dh0, dc0, dWh, dp, None
 
 
 _lstm_seq_kernels.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)
 
 
-def _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t):
+def _lstm_seq_pallas(x_t, Wx, bias, h0, c0, Wh, p, mask_t):
     """The two kernels under one custom VJP give the carried hidden
     state; a masked step's output is zero, and that product is plain jnp
     so that autodiff hands the kernel ``dy * mask``. ``mask_t`` is
-    [t, b] in the dtype of ``xw_t``, or None."""
-    hk, hT, cT = _lstm_seq_kernels(xw_t, bias, h0, c0, Wh, p, mask_t)
+    [t, b] in the dtype of ``x_t``, or None."""
+    hk, hT, cT = _lstm_seq_kernels(x_t, Wx, bias, h0, c0, Wh, p, mask_t)
     y = hk if mask_t is None else hk * mask_t[:, :, None]
     return y, hT, cT
 
 
 @registry.register("lstm_sequence", backend="pallas")
-def lstm_sequence_pallas(xw_t, bias, h0, c0, Wh, p, mask_t, *,
+def lstm_sequence_pallas(x_t, Wx, bias, h0, c0, Wh, p, mask_t, *,
                          gate_act="sigmoid", cell_act="tanh"):
     """Pallas-fused LSTM sequence; silently delegates to the xla backend
     for configurations the kernel does not cover (non-sigmoid/tanh
     activations, unaligned shapes, non-TPU platforms) — the same graceful
     fallback the reference's helper loading performs when cuDNN is absent
     (ConvolutionLayer.java:69-76)."""
-    if not _pallas_supported(xw_t, h0, gate_act, cell_act):
-        return lstm_sequence_xla(xw_t, bias, h0, c0, Wh, p, mask_t,
+    if not _pallas_supported(x_t, h0, gate_act, cell_act):
+        return lstm_sequence_xla(x_t, Wx, bias, h0, c0, Wh, p, mask_t,
                                  gate_act=gate_act, cell_act=cell_act)
     if mask_t is not None:
-        mask_t = mask_t.astype(xw_t.dtype)
-    return _lstm_seq_pallas(xw_t, bias, h0, c0, Wh, p, mask_t)
+        mask_t = mask_t.astype(x_t.dtype)
+    return _lstm_seq_pallas(x_t, Wx, bias, h0, c0, Wh, p, mask_t)

@@ -75,23 +75,21 @@ def sequence_parallel_lstm(mesh: Mesh, seq_axis: str, params, x, h0, c0,
         idx = jax.lax.axis_index(seq_axis)
         cd = x_local.dtype
         p_cd = {k: v.astype(cd) for k, v in params.items()}
-        # input projection: fully parallel over the local time chunk
-        xw = jnp.einsum("btf,fg->btg", x_local, p_cd["Wx"])
-        xw_t = jnp.moveaxis(xw, 1, 0)                     # [t_local, b, 4n]
+        x_t = jnp.moveaxis(x_local, 1, 0)                 # [t_local, b, f]
         m_t = (jnp.moveaxis(m_local.astype(cd), 1, 0)     # [t_local, b]
                if has_mask else None)
 
         def turn(carry):
             h, c = carry
-            ys, hT, cT = lstm_seq(xw_t, p_cd["b"], h, c, p_cd["Wh"],
-                                  p_cd["p"], m_t,
+            ys, hT, cT = lstm_seq(x_t, p_cd["Wx"], p_cd["b"], h, c,
+                                  p_cd["Wh"], p_cd["p"], m_t,
                                   gate_act=gate_act, cell_act=cell_act)
             return ys, (hT, cT)
 
         def wait(carry):
-            return jnp.zeros(xw_t.shape[:2] + (n,), cd), carry
+            return jnp.zeros(x_t.shape[:2] + (n,), cd), carry
 
-        y0 = jnp.zeros(xw_t.shape[:2] + (n,), cd)
+        y0 = jnp.zeros(x_t.shape[:2] + (n,), cd)
 
         def body(carry, s):
             ring, y_acc, fin = carry

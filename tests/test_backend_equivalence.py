@@ -22,9 +22,15 @@ import pytest
 from deeplearning4j_tpu.ops import lstm as lstm_ops
 
 
-def _data(t=5, b=8, n=128, dtype=jnp.float32, seed=0, masked=False):
+def _data(t=5, b=8, n=128, dtype=jnp.float32, seed=0, masked=False,
+          n_in=136):
+    """(x, Wx, bias, h0, c0, Wh, p, mask). ``n_in`` 136 is wider than
+    ``n`` = 128: the projection is a matmul ahead of the kernel; 128 and
+    under, the forward kernel makes it."""
     rng = np.random.default_rng(seed)
-    xw = jnp.asarray(rng.normal(0, 0.5, (t, b, 4 * n)), dtype)
+    x = jnp.asarray(rng.normal(0, 1.0, (t, b, n_in)), dtype)
+    Wx = jnp.asarray(rng.normal(0, 0.5 / np.sqrt(n_in), (n_in, 4 * n)),
+                     dtype)
     bias = jnp.asarray(rng.normal(0, 0.3, (4 * n,)), dtype)
     h0 = jnp.asarray(rng.normal(0, 0.5, (b, n)), dtype)
     c0 = jnp.asarray(rng.normal(0, 0.5, (b, n)), dtype)
@@ -39,20 +45,20 @@ def _data(t=5, b=8, n=128, dtype=jnp.float32, seed=0, masked=False):
         mask = jnp.asarray(m, dtype)
     else:
         mask = jnp.ones((t, b), dtype)
-    return xw, bias, h0, c0, Wh, p, mask
+    return x, Wx, bias, h0, c0, Wh, p, mask
 
 
 def _loss_through(fn):
-    def loss(xw, bias, h0, c0, Wh, p, mask):
+    def loss(x, Wx, bias, h0, c0, Wh, p, mask):
         y, hT, cT = (o.astype(jnp.float32)
-                     for o in fn(xw, bias, h0, c0, Wh, p, mask))
+                     for o in fn(x, Wx, bias, h0, c0, Wh, p, mask))
         w = jnp.cos(jnp.arange(y.size, dtype=y.dtype)).reshape(y.shape)
         return (jnp.sum(y * w) + 2.0 * jnp.sum(jnp.sin(hT))
                 + 0.5 * jnp.sum(cT * cT))
     return loss
 
 
-GRAD_NAMES = ["dxw", "db", "dh0", "dc0", "dWh", "dp"]
+GRAD_NAMES = ["dx", "dWx", "db", "dh0", "dc0", "dWh", "dp"]
 GRAD_ARGNUMS = tuple(range(len(GRAD_NAMES)))
 
 
@@ -138,7 +144,7 @@ class TestLstmBackendEquivalence:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                              ids=["f32", "bf16"])
     def test_gradient_equivalence(self, dtype, masked):
-        # the CuDNNGradientChecks analogue: d/d{xw, b, h0, c0, Wh, p} must
+        # the CuDNNGradientChecks analogue: d/d{x, Wx, b, h0, c0, Wh, p} must
         # match between the hand-written backward kernel (which sums the
         # bias gradient itself) and autodiff of the scan path on
         # identical inputs
@@ -155,7 +161,7 @@ class TestLstmBackendEquivalence:
                           f"pallas/xla gradient mismatch for {name}")
 
     def _assert_matches_the_scan(self, args, dtype, label):
-        """Outputs and all six gradients of the kernels on ``args``
+        """Outputs and all seven gradients of the kernels on ``args``
         against the scan in f32 on the same (rounded) inputs."""
         as_f32 = [None if a is None else a.astype(jnp.float32) for a in args]
         # a TPU's default f32 dot is one bf16 pass (the class also runs
@@ -199,18 +205,28 @@ class TestLstmBackendEquivalence:
             args[-1] = jnp.asarray(m, dtype)
         self._assert_matches_the_scan(args, dtype, f"{mask} t={t}")
 
-    def _time_blocks_traced(self, fn, *args):
-        """{direction: Tb} of the kernel calls one trace of ``fn`` makes."""
+    def _calls_traced(self, fn, *args):
+        """The labels of ``dl4j_lstm_kernel_calls_total`` that one trace
+        of ``fn`` counts, each with its count."""
         from deeplearning4j_tpu.observability import metrics
         saved = metrics.set_registry(metrics.MetricsRegistry())
         try:
             jax.make_jaxpr(fn)(*args)
-            fam = metrics.get_registry().snapshot()[
-                "dl4j_lstm_kernel_calls_total"]
+            fam = metrics.get_registry().snapshot().get(
+                "dl4j_lstm_kernel_calls_total", [])
         finally:
             metrics.set_registry(saved)
-        return {s["labels"]["direction"]: int(s["labels"]["time_block"])
-                for s in fam if s["value"]}
+        return [(s["labels"], s["value"]) for s in fam if s["value"]]
+
+    def _time_blocks_traced(self, fn, *args):
+        """{direction: Tb} of the kernel calls one trace of ``fn`` makes."""
+        return {labels["direction"]: int(labels["time_block"])
+                for labels, _ in self._calls_traced(fn, *args)}
+
+    def _projections_traced(self, fn, *args):
+        """{(direction, projection): calls} of one trace of ``fn``."""
+        return {(labels["direction"], labels["projection"]): int(count)
+                for labels, count in self._calls_traced(fn, *args)}
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                              ids=["f32", "bf16"])
@@ -218,7 +234,7 @@ class TestLstmBackendEquivalence:
     @pytest.mark.parametrize("t,tb", [(16, 4), (12, 4), (6, 2), (7, 1),
                                       (1, 1)])
     def test_every_rung_of_the_time_block_ladder(self, t, tb, mask, dtype):
-        # a grid step is tb timesteps: forward and all six gradients
+        # a grid step is tb timesteps: forward and all seven gradients
         # against the scan at every rung (16 is four blocks of 4, 12 is
         # three). Ragged: lengths 0 .. t, so rows end inside a block, at a
         # block's edge and in the block after (16 rows: every length of
@@ -237,6 +253,134 @@ class TestLstmBackendEquivalence:
             "forward": tb, "backward": tb}
         self._assert_matches_the_scan(args, dtype, f"{mask} t={t}")
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("mask", ["none", "ragged"])
+    @pytest.mark.parametrize("n_in", [80, 128])
+    @pytest.mark.parametrize("t,tb", [(8, 4), (6, 2), (3, 1)])
+    def test_kernel_projects_a_narrow_input(self, t, tb, n_in, mask, dtype):
+        # one-hot-wide rows (80, no whole lane tile of K) and rows as wide
+        # as the hidden state (128, a stacked layer): the forward kernel
+        # reads x, Wx and the bias and forms x Wx + h Wh + b itself,
+        # at every rung; outputs and all seven gradients, dx and dWx among
+        # them, against the scan
+        args = list(_data(t=t, b=16, n=128, dtype=dtype, masked=None,
+                          seed=t, n_in=n_in))
+        if mask == "ragged":
+            m = (np.arange(t)[:, None] < np.arange(16)[None, :] % (t + 1))
+            args[-1] = jnp.asarray(m.astype(np.float32), dtype)
+        grad = jax.grad(_loss_through(self._pallas), argnums=GRAD_ARGNUMS)
+        # (one trace: a second make_jaxpr of the same function is cached)
+        assert sorted(self._calls_traced(grad, *args), key=str) == [
+            ({"direction": "backward", "time_block": str(tb),
+              "projection": "outside"}, 1),
+            ({"direction": "forward", "time_block": str(tb),
+              "projection": "kernel"}, 1)]
+        eqn, = (e for e in jax.make_jaxpr(self._pallas)(*args).jaxpr.eqns
+                if e.primitive.name == "custom_vjp_call")
+        # nothing [t, b, 4n] goes into the kernels: no xz exists
+        assert (t, 16, 4 * 128) not in [v.aval.shape for v in eqn.invars]
+        self._assert_matches_the_scan(args, dtype, f"n_in={n_in} {mask}")
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+    def test_wide_input_is_projected_outside(self, masked):
+        # 136 columns are wider than the 128 of the hidden state: xz is
+        # made by a matmul ahead of the forward kernel, which is given no Wx
+        args = _data(t=4, b=16, n=128, masked=masked, n_in=136)
+        grad = jax.grad(_loss_through(self._pallas), argnums=GRAD_ARGNUMS)
+        assert self._projections_traced(grad, *args) == {
+            ("forward", "outside"): 1, ("backward", "outside"): 1}
+        fwd, = (e for e in jax.make_jaxpr(
+            lambda *a: lstm_ops._lstm_seq_fwd(*a)[0])(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call")
+        assert (4, 16, 4 * 128) in [v.aval.shape for v in fwd.invars]
+        assert (136, 4 * 128) not in [v.aval.shape for v in fwd.invars]
+
+    @pytest.mark.parametrize("n_in,n,want", [
+        (1, 128, True), (80, 512, True), (128, 128, True), (200, 200, True),
+        (512, 512, True), (512, 1024, True), (129, 128, False),
+        (136, 128, False), (513, 512, False), (1024, 512, False)])
+    def test_who_projects_is_a_function_of_the_shapes(self, n_in, n, want):
+        # no wider than the hidden state: characters, an embedding, a
+        # stacked layer of the same width; a wider input is left to the
+        # matmul. And only while the kernel of one timestep a grid step,
+        # Wx resident, asks for no more VMEM than the cap
+        asked = []
+        fits = lambda tb: asked.append(tb) or lstm_ops._VMEM_CAP
+        assert lstm_ops._kernel_projects(n_in, n, fits) is want
+        assert asked == [1] * (n_in <= n)
+        assert not lstm_ops._kernel_projects(
+            n_in, n, lambda tb: lstm_ops._VMEM_CAP + 1)
+
+    @pytest.mark.parametrize("dtype,n,want", [
+        (jnp.bfloat16, 512, "kernel"), (jnp.float32, 512, "kernel"),
+        (jnp.bfloat16, 1024, "kernel"), (jnp.float32, 1536, "outside"),
+        (jnp.float32, 2048, "outside")], ids=str)
+    def test_weights_that_do_not_fit_vmem_are_projected_outside(
+            self, dtype, n, want):
+        # a stacked layer of its own width at b=256, traced only: Wx and
+        # Wh are both whole in VMEM where the kernel projects, and in f32
+        # at n = 1,536 Mosaic has no room for the two (compiled for a
+        # described v5e: RESOURCE_EXHAUSTED, where the kernel given xz
+        # compiles). The rule sees it in the request, and the call takes
+        # the matmul ahead of the kernel
+        sds = jax.ShapeDtypeStruct
+        row = sds((256, n), dtype)
+        args = (sds((8, 256, n), dtype), sds((n, 4 * n), dtype),
+                sds((4 * n,), dtype), row, row, sds((n, 4 * n), dtype),
+                sds((3, n), dtype), None)
+        calls = self._calls_traced(
+            lambda *a: lstm_ops._lstm_seq_fwd(*a)[0], *args)
+        assert [labels["projection"] for labels, _ in calls] == [want]
+
+    def test_counter_names_where_each_call_projects(self):
+        # a stack: 80 columns into 128 cells, those 128 into 256, those
+        # 256 into 128 (wider than the hidden state: a matmul ahead of the
+        # kernel). One forward and one backward call a layer
+        def stack(x, layers):
+            for Wx, bias, h0, c0, Wh, p in layers:
+                x = self._pallas(x, Wx, bias, h0, c0, Wh, p, None)[0]
+            return jnp.sum(x.astype(jnp.float32))
+
+        x = _data(t=4, b=16, n_in=80, masked=None)[0]
+        layers = [_data(t=4, b=16, n=n, n_in=n_in, masked=None)[1:-1]
+                  for n_in, n in ((80, 128), (128, 256), (256, 128))]
+        assert self._projections_traced(jax.grad(stack), x, layers) == {
+            ("forward", "kernel"): 2, ("forward", "outside"): 1,
+            ("backward", "outside"): 3}
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("masked", [None, True], ids=["no_mask", "mask"])
+    def test_xla_backend_is_the_scan_over_x_Wx_plus_b(self, masked, dtype):
+        # what the layer wrote before the op owned the projection: one
+        # einsum over [b, t, f], the bias added to its time-major result,
+        # the scan of the cell. The xla backend is that to the bit, under
+        # jit as the nets run it
+        x, Wx, bias, h0, c0, Wh, p, mask = _data(
+            t=7, b=8, n=128, dtype=dtype, masked=masked, n_in=80)
+
+        def before(x_bt, Wx, bias, h0, c0, Wh, p, mask):
+            xw = jnp.einsum("btf,fg->btg", x_bt, Wx)
+            xz_t = jnp.moveaxis(xw, 1, 0) + bias
+            step = lambda carry, inp: lstm_ops._cell_step(
+                Wh, p, jax.nn.sigmoid, jnp.tanh, carry, inp)
+            if mask is None:
+                (hT, cT), ys = jax.lax.scan(
+                    lambda carry, z: step(carry, (z, None)), (h0, c0), xz_t)
+            else:
+                (hT, cT), ys = jax.lax.scan(step, (h0, c0), (xz_t, mask))
+            return ys, hT, cT
+
+        want = jax.jit(before)(jnp.moveaxis(x, 0, 1), Wx, bias, h0, c0, Wh,
+                               p, mask)
+        got = jax.jit(lambda *a: self._xla(jnp.moveaxis(a[0], 1, 0), *a[1:]))(
+            jnp.moveaxis(x, 0, 1), Wx, bias, h0, c0, Wh, p, mask)
+        for name, g, w in zip(("y", "hT", "cT"), got, want):
+            assert g.dtype == dtype, name
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32), name)
+
     @pytest.mark.parametrize("only", ["h0_at_t0", "row_across_blocks"])
     def test_dWh_term_that_crosses_a_block_edge(self, only):
         # T is two blocks of the longest rung. dWh is the sum over t of
@@ -250,13 +394,16 @@ class TestLstmBackendEquivalence:
         # is the last row of the block before.
         edge = lstm_ops._TIME_BLOCKS[0]
         t, b, n = 2 * edge, 16, 128
-        xw, bias, h0, c0, Wh, p, _ = _data(t=t, b=b, n=n, masked=None)
+        # x is xw and Wx the identity, so that dx is the kernel's dxz
+        xw, _, bias, h0, c0, Wh, p, _ = _data(t=t, b=b, n=n, masked=None,
+                                              n_in=4 * n)
         kept = [0] if only == "h0_at_t0" else [edge - 1, edge]
         if only == "row_across_blocks":
             h0 = jnp.zeros_like(h0)
         m = np.zeros((t, b), np.float32)
         m[kept] = 1.0
-        args = (xw, bias, h0, c0, Wh, p, jnp.asarray(m))
+        args = (xw, jnp.eye(4 * n, dtype=xw.dtype), bias, h0, c0, Wh, p,
+                jnp.asarray(m))
         assert edge > 1 and self._time_blocks_traced(
             self._pallas, *args) == {"forward": edge}
         with jax.default_matmul_precision("highest"):
@@ -267,7 +414,7 @@ class TestLstmBackendEquivalence:
             hk = lstm_ops._lstm_seq_kernels(*args)[0]
         for name, gp, gx in zip(GRAD_NAMES, g_p, g_x):
             _assert_close(gp, gx, jnp.float32, f"{only}: {name}")
-        dxw, dWh = g_p[0], g_p[4]
+        dxw, dWh = g_p[0], g_p[5]
         assert not np.asarray(dxw)[[k for k in range(t) if k not in kept]].any()
         h_prev = h0 if only == "h0_at_t0" else hk[edge - 1]
         with jax.default_matmul_precision("highest"):
@@ -290,6 +437,39 @@ class TestLstmBackendEquivalence:
             t, lambda tb: over if tb > 2 else 0) == min(want, 2)
         assert lstm_ops._time_block(t, lambda tb: over - 1) == want
         assert lstm_ops._time_block(t, lambda tb: over) == 1
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+    @pytest.mark.parametrize("t,tb", [(1024, 4), (1021, 1), (1, 1)])
+    def test_blocking_of_the_projected_forward_at_the_bench_shape(
+            self, t, tb, masked):
+        # layer 0 of the char-RNN: b=256, n=512, 80 one-hot columns in
+        # bf16, traced only. The streamed input is the (Tb, b, 80) block
+        # of x; Wx [80, 4n] and the bias [1, 4n] are whole, at a constant
+        # index, beside Wh; the results are those of the forward given xz
+        b, n, n_in, cd = 256, 512, 80, jnp.bfloat16
+        sds = jax.ShapeDtypeStruct
+        row = sds((b, n), cd)
+        args = (sds((t, b, n_in), cd), row, row, sds((n, 4 * n), cd),
+                sds((3, n), cd), sds((t, b), cd) if masked else None,
+                sds((n_in, 4 * n), cd), sds((4 * n,), cd))
+        assert self._calls_traced(lstm_ops._fwd_call, *args) == [
+            ({"direction": "forward", "time_block": str(tb),
+              "projection": "kernel"}, 1)]
+        eqn, = (e for e in jax.make_jaxpr(lstm_ops._fwd_call)(*args).jaxpr.eqns
+                if e.primitive.name == "pallas_call")
+        grid = eqn.params["grid_mapping"]
+        assert grid.grid == (t // tb,)
+        blocks = [tuple(getattr(d, "block_size", d) for d in m.block_shape)
+                  for m in grid.block_mappings]
+        assert [blk for blk in blocks if len(blk) == 3] == (
+            [(tb, b, 1)] * masked
+            + [(tb, b, w) for w in (n_in, n, 4 * n, n)])    # x; hk, G, c_prev
+        assert blocks[masked + 1:masked + 3] == [(n_in, 4 * n), (1, 4 * n)]
+        limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert lstm_ops._VMEM_DEFAULT <= limit <= lstm_ops._VMEM_CAP
+        assert [(o.shape, o.dtype) for o in jax.eval_shape(lstm_ops._fwd_call, *args)] == [
+            (s, cd) for s in ((t, b, n), (b, n), (b, n), (t, b, 4 * n),
+                              (t, b, n))]      # hk, hT, cT, G, c_prev
 
     @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
     @pytest.mark.parametrize("t,tb", [(1024, 4), (1021, 1), (1, 1)])
@@ -337,9 +517,12 @@ class TestLstmBackendEquivalence:
     @pytest.mark.parametrize("masked", [False, True])
     def test_bias_gradient_is_the_sum_of_dxw(self, masked):
         # what autodiff would have reduced out of dxw, the kernel emits
-        args = _data(t=4, b=16, n=128, masked=masked)
-        dxw, db = jax.grad(_loss_through(self._pallas), argnums=(0, 1))(
-            *args)
+        # (Wx is the identity, so dx is dxw)
+        args = list(_data(t=4, b=16, n=128, masked=masked, n_in=512))
+        args[1] = jnp.eye(512, dtype=args[0].dtype)
+        with jax.default_matmul_precision("highest"):
+            dxw, db = jax.grad(_loss_through(self._pallas),
+                               argnums=(0, 2))(*args)
         np.testing.assert_allclose(db, jnp.sum(dxw, axis=(0, 1)),
                                    rtol=1e-5, atol=1e-5)
 
@@ -348,10 +531,10 @@ class TestLstmBackendEquivalence:
         # unaligned hidden size -> the registered pallas backend must
         # delegate to xla (the cuDNN-absent fallback path), forward and
         # every gradient, the bias's among them
-        xw, bias, h0, c0, Wh, p, mask = _data(t=3, b=4, n=24, seed=1,
-                                              masked=masked)
-        assert not lstm_ops._pallas_supported(xw, h0, "sigmoid", "tanh")
-        args = (xw, bias, h0, c0, Wh, p, mask if masked else None)
+        x, Wx, bias, h0, c0, Wh, p, mask = _data(t=3, b=4, n=24, seed=1,
+                                                 masked=masked, n_in=12)
+        assert not lstm_ops._pallas_supported(x, h0, "sigmoid", "tanh")
+        args = (x, Wx, bias, h0, c0, Wh, p, mask if masked else None)
         for out_w, out_x in zip(lstm_ops.lstm_sequence_pallas(*args),
                                 lstm_ops.lstm_sequence_xla(*args)):
             np.testing.assert_allclose(out_w, out_x, rtol=1e-6)
@@ -533,20 +716,26 @@ class TestAttentionBackendEquivalenceTPU:
 class TestLstmBackendEquivalenceTPU:
     """Same checks, compiled on hardware, bf16 — the dtype the bench runs.
     ``masked`` None is the program ``fit`` runs when it feeds no mask:
-    kernels without a mask operand."""
+    kernels without a mask operand. 80 columns (Mosaic masks the K that
+    is no whole lane tile) and 128 are projected by the forward kernel,
+    136 by the matmul ahead of it."""
 
+    @pytest.mark.parametrize("n_in", [80, 128, 136])
     @pytest.mark.parametrize("masked", [None, False])
-    def test_forward_bf16(self, masked):
-        args = _data(t=6, b=16, n=128, dtype=jnp.bfloat16, masked=masked)
+    def test_forward_bf16(self, masked, n_in):
+        args = _data(t=6, b=16, n=128, dtype=jnp.bfloat16, masked=masked,
+                     n_in=n_in)
         y_p, hT_p, cT_p = jax.jit(lstm_ops._lstm_seq_pallas)(*args)
         y_x, hT_x, cT_x = jax.jit(lstm_ops.lstm_sequence_xla)(*args)
         np.testing.assert_allclose(
             np.asarray(y_p, np.float32), np.asarray(y_x, np.float32),
             rtol=0.05, atol=0.05)
 
+    @pytest.mark.parametrize("n_in", [80, 128, 136])
     @pytest.mark.parametrize("masked", [None, True])
-    def test_gradient_bf16_finite_and_close(self, masked):
-        args = _data(t=4, b=16, n=128, dtype=jnp.bfloat16, masked=masked)
+    def test_gradient_bf16_finite_and_close(self, masked, n_in):
+        args = _data(t=4, b=16, n=128, dtype=jnp.bfloat16, masked=masked,
+                     n_in=n_in)
         g_p = jax.jit(jax.grad(_loss_through(lstm_ops._lstm_seq_pallas),
                                argnums=GRAD_ARGNUMS))(*args)
         g_x = jax.jit(jax.grad(_loss_through(lstm_ops.lstm_sequence_xla),

@@ -25,9 +25,9 @@ def _lstm_params(n_in, n, seed=0, dtype=jnp.float64):
 
 def _reference(params, x, h0, c0):
     from deeplearning4j_tpu.ops.lstm import lstm_sequence_xla
-    xw = jnp.einsum("btf,fg->btg", x, params["Wx"])
-    ys, hT, cT = lstm_sequence_xla(jnp.moveaxis(xw, 1, 0), params["b"],
-                                   h0, c0, params["Wh"], params["p"], None)
+    ys, hT, cT = lstm_sequence_xla(jnp.moveaxis(x, 1, 0), params["Wx"],
+                                   params["b"], h0, c0, params["Wh"],
+                                   params["p"], None)
     return jnp.moveaxis(ys, 0, 1), hT, cT
 
 
@@ -114,10 +114,9 @@ def test_sequence_parallel_masked_matches_single_device():
     h0 = jnp.zeros((b, n)); c0 = jnp.zeros((b, n))
 
     # single-device reference through the same registry op
-    xw = jnp.einsum("btf,fg->btg", x, params["Wx"])
     ys_ref, hT_ref, cT_ref = ops.get("lstm_sequence")(
-        jnp.moveaxis(xw, 1, 0), params["b"], h0, c0, params["Wh"],
-        params["p"], jnp.moveaxis(jnp.asarray(mask), 1, 0))
+        jnp.moveaxis(x, 1, 0), params["Wx"], params["b"], h0, c0,
+        params["Wh"], params["p"], jnp.moveaxis(jnp.asarray(mask), 1, 0))
     y_ref = jnp.moveaxis(ys_ref, 0, 1)
 
     xs = shard_sequence(mesh8, "seq", x)

@@ -57,14 +57,25 @@ def _pallas_call(call, args):
     return eqns[0]
 
 
+@pytest.mark.parametrize("projected", [False, True],
+                         ids=["given_xz", "projects_x"])
 @pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
-def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32, masked):
+def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32, masked,
+                                                 projected):
     cd = jnp.bfloat16
     seq = lambda width: jax.ShapeDtypeStruct((T, B, width), cd)
     row = jax.ShapeDtypeStruct((B, N), cd)
     Wh, p = (jax.ShapeDtypeStruct(s, cd) for s in ((N, 4 * N), (3, N)))
     mask = jax.ShapeDtypeStruct((T, B), cd) if masked else None
     fwd_args = (seq(4 * N), row, row, Wh, p, mask)
+    if projected:
+        # layer 0 of the cell: the forward is given the 80 one-hot columns
+        # (a K that is no whole lane tile, which Mosaic takes), Wx and the
+        # bias, and the same backward follows it (layer 1's 512 columns:
+        # the test below compiles them in the step)
+        fwd_args = (seq(VOCAB),) + fwd_args[1:] + (
+            jax.ShapeDtypeStruct((VOCAB, 4 * N), cd),
+            jax.ShapeDtypeStruct((4 * N,), cd))
     # residuals (G, hk, c_prev, h0, mask, Wh, p) and the cotangents
     bwd_args = ((seq(4 * N), seq(N), seq(N), row, mask, Wh, p),
                 (seq(N), row, row))
@@ -92,7 +103,7 @@ def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32, masked):
     assert [o.shape for o in fwd_out] == [
         (T, B, N), (B, N), (B, N), (T, B, 4 * N), (T, B, N)]
     assert len(_pallas_call(lstm_ops._fwd_call, fwd_args).invars) == (
-        5 + masked)
+        5 + masked + 2 * projected)
     assert len(_pallas_call(lstm_ops._bwd_call, bwd_args).invars) == (
         10 + masked)
     # dxz, dh0, dc0, dWh, dp, and the bias gradient as one row
@@ -100,7 +111,7 @@ def test_lstm_kernels_compile_at_the_bench_shape(one_chip, x32, masked):
     assert (db.shape, db.dtype) == ((1, 4 * N), cd)
 
 
-def test_char_rnn_step_has_no_pass_over_dxz(one_chip, x32, monkeypatch):
+def test_char_rnn_step_has_no_pass_over_dxz_or_xz(one_chip, x32, monkeypatch):
     # the step the benchmark's char-RNN cell runs. jax.default_backend()
     # is the CPU here, so the support gate is steered; the lowering is for
     # the described chip, where the kernels are Mosaic's.
@@ -125,12 +136,24 @@ def test_char_rnn_step_has_no_pass_over_dxz(one_chip, x32, monkeypatch):
             op == "reduce" and f"transpose(jvp({layer}))" in name
             for op, name in inner)]
         assert not reduces, reduces
-        # and the forward bias add is still the projection matmul's
-        # epilogue, not a pass of its own over xz
-        adds = [n for n, inner in ops.items() if any(
-            op == "add" and name.endswith(f"/jvp({layer})/add")
+    for layer in ("layer_0", "layer_1"):
+        # both layers read rows no wider than their hidden state (80
+        # one-hot columns, 512): the forward kernel projects them, and no
+        # matmul or bias add of a layer's forward stands outside the
+        # kernels, nor anything else that makes a [t, b, 4n] array there
+        # (layer 0's matmul wrote 1.07 GB of xz for the kernel to read
+        # back, layer 1's the same)
+        outside = [n for n, inner in ops.items() if any(
+            op in ("convolution", "add") and f"/jvp({layer})/" in name
             for op, name in inner)]
-        assert len(adds) == 1, adds
-        assert any(op == "convolution" and name.endswith(
-            f"/jvp({layer})/btf,fg->btg/dot_general")
-            for op, name in ops[adds[0]]), ops[adds[0]]
+        assert not outside, outside
+        assert f"bf16[{T},{B},{4 * N}]" not in "".join(
+            line for line in text.splitlines()
+            if f"/jvp({layer})/" in line and "tpu_custom_call" not in line
+            and "get-tuple-element" not in line)
+    # the one-hot input has no gradient: dxz of layer 0 is contracted
+    # with x for dWx and never with Wx, as layer 1's is for its input
+    for layer in ("layer_0", "layer_1"):
+        assert f"/transpose(jvp({layer}))/tbf,tbg->fg/dot_general" in text
+    assert "/transpose(jvp(layer_0))/tbg,fg->tbf/dot_general" not in text
+    assert "/transpose(jvp(layer_1))/tbg,fg->tbf/dot_general" in text
