@@ -12,6 +12,10 @@ from deeplearning4j_tpu.datasets.iterator import (
     SamplingDataSetIterator,
     ReconstructionDataSetIterator,
 )
+from deeplearning4j_tpu.datasets.preprocessors import (
+    BlockDiffusionPreProcessor,
+    PreProcessingIterator,
+)
 from deeplearning4j_tpu.datasets.fetchers import (
     CifarDataSetIterator,
     CurvesDataSetIterator,

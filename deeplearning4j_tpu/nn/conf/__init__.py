@@ -12,4 +12,5 @@ from deeplearning4j_tpu.nn.conf import layers
 from deeplearning4j_tpu.nn.conf import layers_conv
 from deeplearning4j_tpu.nn.conf import layers_recurrent
 from deeplearning4j_tpu.nn.conf import layers_attention
+from deeplearning4j_tpu.nn.conf import layers_decoder
 from deeplearning4j_tpu.nn.conf import layers_pretrain

@@ -41,6 +41,7 @@ from deeplearning4j_tpu.datasets.iterator import (
 from deeplearning4j_tpu.nn import precision
 from deeplearning4j_tpu.observability import goodput as _goodput
 from deeplearning4j_tpu.observability import metrics as _obs_metrics
+from deeplearning4j_tpu.observability import moe as _obs_moe
 from deeplearning4j_tpu.observability import opindex as _opindex
 from deeplearning4j_tpu.observability.trace import get_tracer as _get_tracer
 
@@ -504,6 +505,7 @@ class Trainer:
         chunk = self._resolve_multi_step(multi_step)
         device_prefetch = self._resolve_device_prefetch(device_prefetch)
         _obs_metrics.install_runtime_metrics()
+        _obs_moe.install(self)
         from deeplearning4j_tpu.compilecache import ensure_configured
         ensure_configured()  # JAX_COMPILATION_CACHE_DIR, if set
         tracer = _get_tracer()

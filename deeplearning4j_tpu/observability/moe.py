@@ -1,0 +1,68 @@
+"""Routing counts of expert layers, as metrics.
+
+An expert layer (nn/layers/decoder.py) counts the rows a step gave each
+expert it holds and returns the counts in the net's state, beside the
+score: ``expert_rows`` (the last step's, int32 ``[experts_held]``) and
+``expert_rows_total`` (all steps', two int32 limbs). Nothing in the fit
+loop reads them.
+``install(net)`` (``Trainer.fit`` calls it) registers a collector that
+reads them when the registry is scraped, the moment an operator reads
+the score gauge too:
+
+- ``dl4j_moe_pairs_total{layer}``: (row, expert) pairs computed here;
+- ``dl4j_moe_expert_rows{layer, expert}``: rows of the last step, the
+  expert numbered as the router numbers it.
+"""
+
+from __future__ import annotations
+
+import weakref
+
+import jax
+import numpy as np
+
+from deeplearning4j_tpu.observability.metrics import (
+    MetricFamily, get_registry)
+
+
+def expert_rows(net) -> dict:
+    """``{layer: (last step's rows, rows so far)}`` as numpy, for every
+    layer of ``net`` that counts them, in the net's order. One host
+    read."""
+    from deeplearning4j_tpu.nn.layers.decoder import rows_total
+
+    state = {name: s for name, s in (net.state or {}).items()
+             if "expert_rows_total" in s}
+    return {name: (np.asarray(s["expert_rows"]),
+                   rows_total(np.asarray(s["expert_rows_total"])))
+            for name, s in jax.device_get(state).items()}
+
+
+def install(net) -> None:
+    """Register the collector for ``net`` once; a net without expert
+    layers registers nothing."""
+    if getattr(net, "_moe_collector", None) is not None or not any(
+            "expert_rows_total" in s for s in (net.state or {}).values()):
+        return
+    ref = weakref.ref(net)
+    first = {layer.name: int(getattr(layer.conf, "first_expert", 0))
+             for layer in net.layers}
+
+    def collect():
+        live = ref()
+        if live is None:
+            return []
+        pairs = MetricFamily(
+            "dl4j_moe_pairs_total", "counter",
+            "(row, expert) pairs the experts held here computed, by layer")
+        rows = MetricFamily(
+            "dl4j_moe_expert_rows", "gauge",
+            "Rows the last step gave each expert held here")
+        for name, (last, total) in expert_rows(live).items():
+            pairs.add(float(total.sum()), {"layer": name})
+            for i, n in enumerate(last):
+                rows.add(float(n), {"layer": name,
+                                    "expert": str(first[name] + i)})
+        return [pairs, rows]
+
+    net._moe_collector = get_registry().register_collector(collect)

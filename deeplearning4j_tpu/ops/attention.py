@@ -21,18 +21,24 @@ Three backends behind the ``causal_mha`` registry seam:
 - ``pallas``: a flash-style forward — online softmax over kv tiles with
   the running (m, l, acc) carried in f32 VMEM scratch, causal tile-skip
   above the diagonal, the [t, t] score matrix never materialized to HBM.
-  Guarded by ``attention_supported``: hand-DMA'd streaming kernels
-  measured 13-73 GB/s against XLA's ~700-800 GB/s on the previous
-  software stack (PERF.md Findings, rounds 3-5; not re-measured on the
-  installed one), so the kernel only runs where its VMEM-residency win
-  (no score-matrix traffic) is structural, and it silently delegates to
-  the xla backend everywhere else — the same graceful fallback as
+  Guarded by ``attention_supported``; it silently delegates to the xla
+  backend everywhere else, the same graceful fallback as
   ops/fused_block.py. It compiles under Mosaic on the v5e and matches
-  ``xla_dot`` there (``chip_smoke.py``, kernels phase). The backward
-  recomputes through the xla_dot formulation (a custom_vjp): that same
-  old verdict priced a hand-written flash backward as a net loss, and
-  grad parity against the xla backend is what
-  tests/test_backend_equivalence.py pins either way.
+  ``xla_dot`` there (``chip_smoke.py``, kernels phase); no benchmark
+  cell runs it, so its speed is still unmeasured. Its backward
+  recomputes through the xla_dot formulation (a custom_vjp), which
+  writes the [t, t] scores: grad parity against the xla backend is what
+  tests/test_backend_equivalence.py pins. The tiled kernels further
+  down (``block_diffusion_mha``) are the measured ones: on the v5e, in
+  the cell ``sdar_30b_a3b-train-b1-l4096`` (2 x 4,096 rows, 32 query
+  heads on 4 key/value heads of 128, tiles of 8 x 128 rows by 512
+  keys), the forward runs at 26% and the tiled backward at 43% of the
+  MXU's roofline for the visible pairs (PERF.md, Findings PR 31). The
+  causal path was not moved onto that kernel body: the body carries a
+  window of key blocks per row and could carry "keys up to my own", but
+  the causal path's contract is the decode bit-identity above, pinned
+  on the xla lowering, and ROADMAP D1/D5 decide the flash forward's
+  fate first.
 
 Incremental decode (``decode_mha`` + ``extend_cache``): a step's new-token
 queries attend over a KV cache instead of recomputing the prefix. The
@@ -352,3 +358,455 @@ def extend_cache(k_cache, v_cache, k_new, v_new, pos):
 
     return (jax.vmap(_write)(k_cache, k_new, pos),
             jax.vmap(_write)(v_cache, v_new, pos))
+
+
+# ------------------------------------------------- block-diffusion attention
+# Attention under the block-diffusion training mask (SDAR; PERF.md
+# section 4). A sequence of L tokens enters the layers twice, as 2L rows:
+# rows 0..L-1 are the noised copy, rows L..2L-1 the clean copy, both at
+# positions 0..L-1, in blocks of ``block_len`` tokens. Row i sees row j
+# where ``block_diffusion_visible(i, j, L, block_len)``. The rule is a
+# function of the two indices, computed from iotas wherever it is needed:
+# no [2L, 2L] array exists on any path. Grouped-query heads: Hq query
+# heads read Hkv key/value heads, query head h reading head h // (Hq/Hkv).
+#
+# Two backends behind ``block_diffusion_mha``:
+#
+# - ``xla``: masked softmax over the dense scores, for the CPU and for
+#   shapes the kernels do not cover. The scores reach memory.
+# - ``pallas``: a tiled forward (online softmax) and a tiled backward
+#   (dK/dV kernel and dQ kernel, from the forward's saved row statistics)
+#   whose scores never leave VMEM. One grid step takes the G = Hq/Hkv
+#   query heads of a group against one key/value tile, so a key tile is
+#   read once for 8 heads and the MXU streams G * bq rows per tile. The
+#   grid is the list of LIVE (query tile, key tile) pairs, made on the
+#   host from the rule and handed to the kernels as scalar-prefetch
+#   tables: a tile pair with no visible entry costs no grid step and no
+#   DMA. Of the 4 L^2 pairs L^2 + L * block_len are visible; at L = 4096
+#   and tiles of 128 x 512 the live tiles hold 1.25 times that.
+
+
+def block_diffusion_visible(i, j, seq_len: int, block_len: int):
+    """Whether row ``i`` may attend to row ``j`` of the ``2 * seq_len``
+    rows (integer arrays that broadcast against each other)."""
+    noised_i, noised_j = i < seq_len, j < seq_len
+    bi = jnp.where(noised_i, i, i - seq_len) // block_len
+    bj = jnp.where(noised_j, j, j - seq_len) // block_len
+    return jnp.where(noised_i,
+                     jnp.where(noised_j, bi == bj, bj < bi),
+                     (~noised_j) & (bj <= bi))
+
+
+def _count_block_attention(direction: str, backend: str) -> None:
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_block_attention_calls_total",
+        "Block-diffusion attention calls traced, by direction and backend",
+        ("direction", "backend")).labels(
+            direction=direction, backend=backend).inc()
+
+
+@registry.register("block_diffusion_mha", backend="xla")
+def block_diffusion_mha_xla(q, k, v, *, seq_len: int, block_len: int):
+    """q [b, 2L, Hq, dh], k and v [b, 2L, Hkv, dh] -> [b, 2L, Hq, dh].
+    Scores and softmax in float32, both products with float32
+    accumulation; autodiff gives the backward."""
+    _count_block_attention("forward", "xla")
+    b, t, hq, dh = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, t, hkv, hq // hkv, dh)
+    s = jnp.einsum("bikgd,bjkd->bkgij", qg, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(dh)
+    rows = jnp.arange(t, dtype=jnp.int32)
+    visible = block_diffusion_visible(rows[:, None], rows[None, :],
+                                      seq_len, block_len)
+    p = jax.nn.softmax(jnp.where(visible, s, _NEG_INF), axis=-1)
+    out = jnp.einsum("bkgij,bjkd->bikgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, t, hq, dh).astype(q.dtype)
+
+
+_BD_BQ = 128            # query positions a tile (times G heads = rows)
+
+
+def _bd_key_tile(seq_len: int) -> int:
+    return next(bk for bk in (512, 256, 128) if seq_len % bk == 0)
+
+
+def block_attention_supported(q, k, v, seq_len: int, block_len: int) -> bool:
+    """Whether the kernels cover this call: tiles may not straddle the
+    two halves, blocks are a power of two (the rule is shifts and
+    compares in the kernel), heads of 128."""
+    b, t, hq, dh = q.shape
+    if t != 2 * seq_len or seq_len % 128 or dh % 128:
+        return False
+    if block_len & (block_len - 1) or _BD_BQ % block_len:
+        return False
+    if q.dtype not in (jnp.bfloat16, jnp.float32) or hq % k.shape[2]:
+        return False
+    if k.dtype != q.dtype or v.dtype != q.dtype:
+        return False
+    return _interpret() or jax.default_backend() == "tpu"
+
+
+@functools.lru_cache(maxsize=None)
+def _bd_live_tiles(seq_len: int, block_len: int, bq: int, bk: int):
+    """The (query tile, key tile) pairs that hold a visible entry, as
+    rows ``(qi, ki, kind)`` in query-major order; kind 0 is noised rows
+    on noised keys (the same block), 1 noised rows on clean keys (blocks
+    before), 2 clean on clean (blocks up to its own)."""
+    import numpy as np
+
+    def blocks(lo, n):
+        lo %= seq_len
+        return lo // block_len, (lo + n - 1) // block_len
+
+    pairs = []
+    for qi in range(2 * seq_len // bq):
+        q_noised = qi * bq < seq_len
+        qb_lo, qb_hi = blocks(qi * bq, bq)
+        for ki in range(2 * seq_len // bk):
+            k_noised = ki * bk < seq_len
+            kb_lo, kb_hi = blocks(ki * bk, bk)
+            if q_noised and k_noised:
+                live, kind = kb_lo <= qb_hi and qb_lo <= kb_hi, 0
+            elif q_noised:
+                live, kind = kb_lo < qb_hi, 1
+            else:
+                live, kind = (not k_noised) and kb_lo <= qb_hi, 2
+            if live:
+                pairs.append((qi, ki, kind))
+    return np.asarray(pairs, np.int32)
+
+
+def _bd_tables(seq_len, block_len, bq, bk, key_major: bool):
+    """Scalar-prefetch tables of the live pairs in the order a kernel
+    walks them: ``qi, ki, kind, first, last`` where first/last mark the
+    run of steps that share the tile the kernel accumulates for."""
+    import numpy as np
+
+    pairs = _bd_live_tiles(seq_len, block_len, bq, bk)
+    owner = 1 if key_major else 0
+    if key_major:
+        pairs = pairs[np.lexsort((pairs[:, 0], pairs[:, 1]))]
+    change = np.flatnonzero(np.diff(pairs[:, owner])) + 1
+    first = np.zeros(len(pairs), np.int32)
+    last = np.zeros(len(pairs), np.int32)
+    first[np.r_[0, change]] = 1
+    last[np.r_[change - 1, len(pairs) - 1]] = 1
+    return tuple(jnp.asarray(a) for a in
+                 (pairs[:, 0], pairs[:, 1], pairs[:, 2], first, last))
+
+
+def _bd_bounds(kind, q_lo, k_lo, seq_len, shift, q_iota, k_iota):
+    """Block numbers of the tile's rows and keys and the window of key
+    blocks each row sees: ``lower <= key block <= upper``. ``q_iota`` and
+    ``k_iota`` are int32 iotas shaped to broadcast against each other."""
+    q0 = jnp.where(q_lo >= seq_len, q_lo - seq_len, q_lo)
+    k0 = jnp.where(k_lo >= seq_len, k_lo - seq_len, k_lo)
+    qb = jnp.right_shift(q0 + q_iota, jnp.int32(shift))
+    kb = jnp.right_shift(k0 + k_iota, jnp.int32(shift))
+    upper = qb - jnp.where(kind == 1, 1, 0)
+    lower = jnp.where(kind == 0, qb, -1)
+    return kb, lower, upper
+
+
+def _col_to_rows(col, g, bq):
+    """[g * bq, 1] -> [g, bq] with elementwise ops and a sublane
+    reduction only (no relayout): row r of head h lands in lane r."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(eye, col[h * bq:(h + 1) * bq], 0.0), axis=0,
+                 keepdims=True) for h in range(g)], axis=0)
+
+
+def _rows_to_col(rows, g, bq):
+    """[g, bq] -> [g * bq, 1], the inverse of ``_col_to_rows``."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(eye, rows[h:h + 1], 0.0), axis=1, keepdims=True)
+         for h in range(g)], axis=0)
+
+
+def _rows_to_lanes(rows, g):
+    """[g, bq] -> [1, g * bq]: the heads side by side along the lanes."""
+    return jnp.concatenate([rows[h:h + 1] for h in range(g)], axis=1)
+
+
+def _bd_scores(qi, ki, kind, q_ref, k_ref, scale, seq_len, shift):
+    """The masked scaled scores of one live tile pair, [g * bq, bk]
+    float32: the G heads' rows of the query tile against the key tile,
+    the rule computed once for the tile and laid over every head."""
+    g, bq, dh = q_ref.shape[1:]
+    bk = k_ref.shape[1]
+    kb, lower, upper = _bd_bounds(
+        kind, qi * bq, ki * bk, seq_len, shift,
+        jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0),
+        jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
+    visible = (kb <= upper) & (kb >= lower)                 # [bq, bk]
+    s = jax.lax.dot_general(
+        q_ref[0].reshape(g * bq, dh), k_ref[0],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    return jnp.where(visible[None], s.reshape(g, bq, bk),
+                     _MASK_VALUE).reshape(g * bq, bk)
+
+
+def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
+                   q_ref, k_ref, v_ref, o_ref, lse_ref,
+                   m_scr, l_scr, acc_scr, *, scale, seq_len, shift):
+    import jax.experimental.pallas as pl
+
+    s_id = pl.program_id(1)
+    g, bq, dh = q_ref.shape[1:]
+
+    @pl.when(first_ref[s_id] == 1)
+    def _():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    s = _bd_scores(qi_ref[s_id], ki_ref[s_id], kind_ref[s_id], q_ref, k_ref,
+                   scale, seq_len, shift)
+    m_prev, l_prev = m_scr[:], l_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[:] = m_new
+    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(last_ref[s_id] == 1)
+    def _():
+        l = l_scr[:]
+        o_ref[0] = (acc_scr[:] / l).reshape(g, bq, dh).astype(o_ref.dtype)
+        lse_ref[0] = _col_to_rows(m_scr[:] + jnp.log(l), g, bq)
+
+
+def _bd_dq_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
+                  q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
+                  lse_scr, di_scr, acc_scr, *, scale, seq_len, shift):
+    import jax.experimental.pallas as pl
+
+    s_id = pl.program_id(1)
+    g, bq, dh = q_ref.shape[1:]
+
+    @pl.when(first_ref[s_id] == 1)
+    def _():
+        lse_scr[:] = _rows_to_col(lse_ref[0], g, bq)
+        di_scr[:] = _rows_to_col(di_ref[0], g, bq)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    s = _bd_scores(qi_ref[s_id], ki_ref[s_id], kind_ref[s_id], q_ref, k_ref,
+                   scale, seq_len, shift)
+    p = jnp.exp(s - lse_scr[:])
+    dp = jax.lax.dot_general(
+        do_ref[0].reshape(g * bq, dh), v_ref[0],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - di_scr[:]) * scale
+    acc_scr[:] += jax.lax.dot_general(
+        ds.astype(k_ref.dtype), k_ref[0],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(last_ref[s_id] == 1)
+    def _():
+        dq_ref[0] = acc_scr[:].reshape(g, bq, dh).astype(dq_ref.dtype)
+
+
+def _bd_dkv_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
+                   q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                   dk_ref, dv_ref, dk_scr, dv_scr, *, scale, seq_len, shift):
+    """The transposed products: scores as [keys, rows], so the row
+    statistics lie along the lanes as they are stored, and dV, dK are
+    plain products with the G heads summed by the contraction."""
+    import jax.experimental.pallas as pl
+
+    s_id = pl.program_id(1)
+    g, bq, dh = q_ref.shape[1:]
+    bk = k_ref.shape[1]
+
+    @pl.when(first_ref[s_id] == 1)
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
+    kb, lower, upper = _bd_bounds(
+        kind_ref[s_id], qi_ref[s_id] * bq, ki_ref[s_id] * bk, seq_len, shift,
+        lanes & (bq - 1),
+        jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
+    q = q_ref[0].reshape(g * bq, dh)
+    do = do_ref[0].reshape(g * bq, dh)
+    st = jax.lax.dot_general(
+        k_ref[0], q, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale          # [bk, g*bq]
+    st = jnp.where((kb <= upper) & (kb >= lower), st, _MASK_VALUE)
+    pt = jnp.exp(st - _rows_to_lanes(lse_ref[0], g))
+    dv_scr[:] += jax.lax.dot_general(
+        pt.astype(do.dtype), do, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dpt = jax.lax.dot_general(
+        v_ref[0], do, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dst = pt * (dpt - _rows_to_lanes(di_ref[0], g)) * scale
+    dk_scr[:] += jax.lax.dot_general(
+        dst.astype(q.dtype), q, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(last_ref[s_id] == 1)
+    def _():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+_BD_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _bd_call(kernel, tables, n_steps, bh, in_specs, out_specs, out_shape,
+             scratch, **static):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.pallas_call(
+        functools.partial(kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=(bh, n_steps),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BD_VMEM_LIMIT),
+        interpret=_interpret(),
+    )
+
+
+def _bd_specs(g, bq, bk, dh):
+    """Block specs by what a block follows: the step's query tile or its
+    key tile (``qi_ref`` and ``ki_ref`` are the first two tables)."""
+    import jax.experimental.pallas as pl
+
+    def rows(bh, s, qi, ki, *_):
+        return bh, 0, qi[s], 0
+
+    def stats(bh, s, qi, ki, *_):
+        return bh, 0, qi[s]
+
+    def keys(bh, s, qi, ki, *_):
+        return bh, ki[s], 0
+
+    return (pl.BlockSpec((1, g, bq, dh), rows),
+            pl.BlockSpec((1, g, bq), stats),
+            pl.BlockSpec((1, bk, dh), keys))
+
+
+def _bd_split(q, k, v):
+    """[b, T, H, dh] -> queries [b*Hkv, G, T, dh], keys and values
+    [b*Hkv, T, dh]."""
+    b, t, hq, dh = q.shape
+    hkv = k.shape[2]
+    qg = jnp.moveaxis(q.reshape(b, t, hkv, hq // hkv, dh), 1, 3)
+    return (qg.reshape(b * hkv, hq // hkv, t, dh),
+            jnp.moveaxis(k, 1, 2).reshape(b * hkv, t, dh),
+            jnp.moveaxis(v, 1, 2).reshape(b * hkv, t, dh))
+
+
+def _bd_join(og, b):
+    """The inverse of ``_bd_split`` for a query-shaped array."""
+    bh, g, t, dh = og.shape
+    return jnp.moveaxis(og.reshape(b, bh // b, g, t, dh), 3, 1).reshape(
+        b, t, bh // b * g, dh)
+
+
+def _bd_forward(qg, kg, vg, seq_len, block_len):
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, g, t, dh = qg.shape
+    bq, bk = _BD_BQ, _bd_key_tile(seq_len)
+    tables = _bd_tables(seq_len, block_len, bq, bk, key_major=False)
+    rows, stats, keys = _bd_specs(g, bq, bk, dh)
+    return _bd_call(
+        _bd_fwd_kernel, tables, len(tables[0]), bh,
+        in_specs=[rows, keys, keys], out_specs=[rows, stats],
+        out_shape=[jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+                   jax.ShapeDtypeStruct((bh, g, t), jnp.float32)],
+        scratch=[pltpu.VMEM((g * bq, 1), jnp.float32),
+                 pltpu.VMEM((g * bq, 1), jnp.float32),
+                 pltpu.VMEM((g * bq, dh), jnp.float32)],
+        scale=1.0 / math.sqrt(dh), seq_len=seq_len,
+        shift=block_len.bit_length() - 1)(*tables, qg, kg, vg)
+
+
+def _bd_backward(qg, kg, vg, og, lse, dog, seq_len, block_len):
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, g, t, dh = qg.shape
+    bq, bk = _BD_BQ, _bd_key_tile(seq_len)
+    static = dict(scale=1.0 / math.sqrt(dh), seq_len=seq_len,
+                  shift=block_len.bit_length() - 1)
+    di = jnp.sum(og.astype(jnp.float32) * dog.astype(jnp.float32), axis=-1)
+    rows, stats, keys = _bd_specs(g, bq, bk, dh)
+    operands = (qg, kg, vg, dog, lse, di)
+    in_specs = [rows, keys, keys, rows, stats, stats]
+    tables = _bd_tables(seq_len, block_len, bq, bk, key_major=False)
+    dq = _bd_call(
+        _bd_dq_kernel, tables, len(tables[0]), bh,
+        in_specs=in_specs, out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        scratch=[pltpu.VMEM((g * bq, 1), jnp.float32),
+                 pltpu.VMEM((g * bq, 1), jnp.float32),
+                 pltpu.VMEM((g * bq, dh), jnp.float32)],
+        **static)(*tables, *operands)
+    tables = _bd_tables(seq_len, block_len, bq, bk, key_major=True)
+    dk, dv = _bd_call(
+        _bd_dkv_kernel, tables, len(tables[0]), bh,
+        in_specs=in_specs, out_specs=[keys, keys],
+        out_shape=[jax.ShapeDtypeStruct(kg.shape, kg.dtype),
+                   jax.ShapeDtypeStruct(vg.shape, vg.dtype)],
+        scratch=[pltpu.VMEM((bk, dh), jnp.float32),
+                 pltpu.VMEM((bk, dh), jnp.float32)],
+        **static)(*tables, *operands)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _bd_attention(qg, kg, vg, seq_len, block_len):
+    return _bd_forward(qg, kg, vg, seq_len, block_len)[0]
+
+
+def _bd_attention_fwd(qg, kg, vg, seq_len, block_len):
+    _count_block_attention("forward", "pallas")
+    og, lse = _bd_forward(qg, kg, vg, seq_len, block_len)
+    return og, (qg, kg, vg, og, lse)
+
+
+def _bd_attention_bwd(seq_len, block_len, residuals, dog):
+    _count_block_attention("backward", "pallas")
+    return _bd_backward(*residuals, dog, seq_len, block_len)
+
+
+_bd_attention.defvjp(_bd_attention_fwd, _bd_attention_bwd)
+
+
+@registry.register("block_diffusion_mha", backend="pallas")
+def block_diffusion_mha_pallas(q, k, v, *, seq_len: int, block_len: int):
+    """The tiled forward and backward; delegates to the xla backend for
+    calls ``block_attention_supported`` refuses."""
+    if not block_attention_supported(q, k, v, seq_len, block_len):
+        return block_diffusion_mha_xla(q, k, v, seq_len=seq_len,
+                                       block_len=block_len)
+    og = _bd_attention(*_bd_split(q, k, v), seq_len, block_len)
+    return _bd_join(og, q.shape[0])
+
+
+def block_diffusion_mha(q, k, v, *, seq_len: int, block_len: int):
+    """Resolve the registered backend order and apply (layer-facing)."""
+    return registry.get("block_diffusion_mha")(
+        q, k, v, seq_len=seq_len, block_len=block_len)

@@ -14,6 +14,10 @@ Capacity semantics: each expert processes at most ``capacity`` tokens
 per batch; overflow tokens are DROPPED from the expert path (standard
 GShard behavior) and pass through with zero expert contribution —
 training remains differentiable through the router probabilities.
+
+The expert layer of the net path is ``nn/layers/decoder.py``
+(``RoutedExpertsLayer``: told which experts it holds, drops nothing);
+this module stays outside it (ROADMAP D6).
 """
 
 from __future__ import annotations

@@ -14,10 +14,11 @@ from deeplearning4j_tpu.zoo.models import (
     mnist_mlp,
     resnet18,
     resnet50,
+    sdar_moe,
     vgg16,
     vgg16_preprocess,
 )
 
 __all__ = ["BF16", "F32", "VGG16_MEAN_RGB", "char_rnn", "gpt_mini",
            "gpt_mini_draft", "gpt_mini_tp_rules", "lenet", "mnist_mlp",
-           "resnet18", "resnet50", "vgg16", "vgg16_preprocess"]
+           "resnet18", "resnet50", "sdar_moe", "vgg16", "vgg16_preprocess"]

@@ -16,6 +16,9 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax.numpy as jnp
+import numpy as np
+
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.core import DtypePolicy
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -232,6 +235,74 @@ def gpt_mini(vocab_size: int = 80, width: int = 256, n_layers: int = 4,
             .set_input_type(InputType.recurrent(vocab_size))
             .build())
     return MultiLayerNetwork(conf).init()
+
+
+def sdar_moe(seed: int = 42, n_layers: int = 48, n_experts: int = 128,
+             experts_held: Optional[int] = None, first_expert: int = 0,
+             vocab_size: int = 151_936, hidden: int = 2048,
+             n_heads: int = 32, n_kv_heads: int = 4, head_dim: int = 128,
+             expert_width: int = 768, experts_per_token: int = 8,
+             rope_theta: float = 1e6, eps: float = 1e-6,
+             block_len: int = 4, learning_rate: float = 1e-5,
+             dtype: Optional[DtypePolicy] = None) -> MultiLayerNetwork:
+    """SDAR-30B-A3B-Chat (``model_type`` sdar_moe; the defaults are its
+    published config.json): a decoder of RMS-normed grouped-query
+    attention with per-head query/key norm and rotary positions, and
+    routed SwiGLU experts, trained by diffusion over blocks of
+    ``block_len`` tokens. A batch row is the noised copy and the clean
+    copy of one sequence (``datasets.BlockDiffusionPreProcessor`` makes
+    it), integer ids in and integer labels with a weight per token out.
+
+    ``experts_held`` and ``first_expert`` give this chip's share of the
+    experts under expert parallelism (the router still scores all
+    ``n_experts``); ``vocab_size`` is the slice of the vocabulary held
+    here, its last id the ``[MASK]`` token.
+
+    Init: normal(0, 0.02), the family's ``initializer_range``, with two
+    departures, both for every seed alike. The embedding rows are
+    normal(0, 1): at 0.02 the first layer's attention output is 12 times
+    its input, every row's router sees the same average of values, and
+    one expert takes nearly every row. And where the experts are shared
+    out, a router starts balanced between the shares: the columns of
+    every share of ``experts_held`` experts start as copies of the first
+    share's, so a row's ``n_experts / experts_held`` best experts are
+    one a share, and every chip is given exactly one pair a row until
+    training moves the columns apart. A trained router is balanced by
+    its auxiliary loss; a seeded one gives a chip anything from 0.7 to
+    1.3 of its share, because a quarter of a block-diffusion batch's
+    rows are the same ``[MASK]`` token and take the same experts
+    (PERF.md, Findings PR 31)."""
+    from deeplearning4j_tpu.nn.conf.layers_decoder import (
+        MoeDecoderBlock, RmsNorm, TokenEmbedding, TokenOutput)
+    held = n_experts if experts_held is None else experts_held
+    if n_experts % held:
+        raise ValueError(
+            f"sdar_moe: shares of {held} experts do not divide {n_experts}")
+    b = (NeuralNetConfiguration.builder()
+         .seed(seed).updater(Adam(learning_rate)).dtype(dtype or BF16)
+         .weight_init({"type": "normal", "mean": 0.0, "std": 0.02})
+         .list()
+         .layer(TokenEmbedding(n_out=hidden, weight_init={
+             "type": "normal", "mean": 0.0, "std": 1.0})))
+    for _ in range(n_layers):
+        b = b.layer(MoeDecoderBlock(
+            n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
+            rope_theta=rope_theta, block_len=block_len, eps=eps,
+            n_experts=n_experts, experts_per_token=experts_per_token,
+            expert_width=expert_width, experts_held=experts_held,
+            first_expert=first_expert))
+    conf = (b.layer(RmsNorm(eps=eps))
+            .layer(TokenOutput(n_out=vocab_size, activation="identity"))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    for p in net.params.values():
+        if "Wr" in p:
+            # on the host: the v5e's compiler aborts on some programs that
+            # move 16 of 128 columns (IsFusibleUnalignedDUS, PR 31)
+            first = np.asarray(p["Wr"])[:, :held]
+            p["Wr"] = jnp.asarray(np.tile(first, (1, n_experts // held)))
+    return net
 
 
 def gpt_mini_draft(vocab_size: int = 80, width: int = 128,

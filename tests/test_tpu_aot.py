@@ -157,3 +157,26 @@ def test_char_rnn_step_has_no_pass_over_dxz_or_xz(one_chip, x32, monkeypatch):
         assert f"/transpose(jvp({layer}))/tbf,tbg->fg/dot_general" in text
     assert "/transpose(jvp(layer_0))/tbg,fg->tbf/dot_general" not in text
     assert "/transpose(jvp(layer_1))/tbg,fg->tbf/dot_general" in text
+
+
+def test_block_attention_kernels_compile_at_the_bench_shape(one_chip, x32):
+    """The forward, dQ and dK/dV kernels of ops/attention.py at the
+    SDAR cell's shape: 2 x 4,096 rows, 32 query heads on 4 key/value
+    heads of 128, blocks of 4 (Mosaic accepts the tiles, the scalar
+    prefetch tables and the 64 MB VMEM limit)."""
+    from deeplearning4j_tpu.ops import attention as att
+
+    seq, block = 4096, 4
+    q, k, v = (jax.ShapeDtypeStruct((1, 2 * seq, h, 128), jnp.bfloat16,
+                                    sharding=one_chip) for h in (32, 4, 4))
+
+    def loss(q, k, v):
+        out = att._bd_join(att._bd_attention(*att._bd_split(q, k, v), seq,
+                                             block), 1)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # live tiles only: 320 of the 1,024 tile pairs of 128 x 512
+    assert len(att._bd_live_tiles(seq, block, 128, 512)) == 320
