@@ -13,10 +13,15 @@ in the compute dtype, as in every other net of this package.
 Named scopes inside a block, under the layer's own: ``attn`` (norm,
 projections, head norms, rotation, output projection, and inside it
 ``block_attention`` round the attention itself, whatever backend runs),
-``route`` (norm, router, top-k, the sort of the pairs, and inside the
-chunk loop the gathers and scatter-adds) and ``experts`` (the grouped
-products and the gating between them). ``observability/opindex.py``
-places a device op by the innermost scope it is asked about.
+``route`` (norm, router, top-k, the sort of the pairs, and what
+``ops/grouped.py`` does to move rows in XLA: a gather a block of pairs
+before its kernels, or a gather and a scatter-add a chunk inside its
+loop) and ``experts`` (the grouped products and the gating between
+them: on a TPU one Pallas call a block forward and two backward, which
+add their rows to the result themselves; the chunk loop's dots
+elsewhere).
+``observability/opindex.py`` places a device op by the innermost scope
+it is asked about.
 """
 
 from __future__ import annotations
@@ -58,14 +63,19 @@ def _rotate(x, theta):
 
 def expert_chunk_rows(rows: int, experts_per_token: int,
                       n_experts: int) -> int:
-    """Rows of one chunk of the grouped products (ops/grouped.py) for a
-    layer that routes ``rows`` rows: what an expert expects, half as
-    many again to spare, in whole tiles of 128 and at most 1,024. An
-    expert near its expected load is then one chunk whatever the batch,
-    and the MXU streams several hundred rows a weight tile. A chunk is
-    the unit of the experts' work: a step's time follows the number of
-    chunks that hold a pair, 0.14% a chunk on the v5e (PERF.md, Findings
-    PR 31)."""
+    """The pairs one held expert expects from a layer that routes
+    ``rows`` rows, half as many again to spare, in whole tiles of 128 and
+    at most 1,024: the grain ``ops/grouped.py`` is told.
+
+    Its chunk loop (the CPU, widths off the lane tile) runs chunks of so
+    many pairs of one expert: an expert near its expected load is then
+    one chunk whatever the batch, and a step's time follows the number
+    of chunks that hold a pair (0.14% of the rate a chunk on the v5e
+    when the loop ran there, PERF.md Findings PR 31). Its kernels (a
+    TPU) take this many pairs times the held experts as one block of
+    the sorted pairs, so the usual step is one block a layer and a
+    biased router pays a second; their row tile is the kernels' own
+    (``grouped.TILE``), and nothing else about them is sized here."""
     expected = rows * experts_per_token / n_experts
     return 128 * min(max(math.ceil(1.5 * expected / 128), 1), 8)
 

@@ -29,6 +29,7 @@ from deeplearning4j_tpu.observability import opindex
 from deeplearning4j_tpu.observability.metrics import get_registry
 from deeplearning4j_tpu.observability.trace import Tracer, set_tracer
 from deeplearning4j_tpu.ops import attention as att
+from deeplearning4j_tpu.ops import grouped
 from deeplearning4j_tpu.ops import registry
 
 RTOL = 2e-5
@@ -215,22 +216,26 @@ def test_unsupported_shapes_fall_back_to_xla(monkeypatch):
 D, F, EXPERTS, TOP = 32, 24, 16, 4
 
 
-def _expert_net(held, first, seed=5):
+WIDE = 128      # a width the grouped kernels take (ops/grouped.py)
+
+
+def _expert_net(held, first, seed=5, d=D, f=F):
     conf = (NeuralNetConfiguration.builder().seed(seed).updater(Adam(1e-3))
             .dtype(zoo.F32)
             .weight_init({"type": "normal", "mean": 0.0, "std": 0.3}).list()
-            .layer(RoutedExperts(n_out=D, n_experts=EXPERTS,
-                                 experts_per_token=TOP, expert_width=F,
+            .layer(RoutedExperts(n_out=d, n_experts=EXPERTS,
+                                 experts_per_token=TOP, expert_width=f,
                                  experts_held=held, first_expert=first))
             .layer(TokenOutput(n_out=8))
-            .set_input_type(InputType.recurrent(D)).build())
+            .set_input_type(InputType.recurrent(d)).build())
     return MultiLayerNetwork(conf).init()
 
 
 def _share(whole, held, first):
     """The net holding ``held`` experts from ``first`` on, with the
     weights ``whole`` (a net holding all of them) has for them."""
-    part = _expert_net(held, first)
+    d, f = whole.params["layer_0"]["Wg"].shape[1:]
+    part = _expert_net(held, first, d=d, f=f)
     p = dict(whole.params["layer_0"])
     for name in ("Wg", "Wu", "Wd"):
         p[name] = p[name][first:first + held]
@@ -256,16 +261,21 @@ def test_the_shares_add_up_to_the_uncut_layer():
     _close(part.feed_forward(a)[0][0], want)
 
 
-def test_a_biased_router_drops_and_pads_nothing():
+@pytest.mark.parametrize("path,d,f", [("xla_chunks", D, F),
+                                      ("pallas", WIDE, WIDE)])
+def test_a_biased_router_drops_and_pads_nothing(monkeypatch, path, d, f):
     """Three times the expected pairs land here, on experts of unequal
-    load spanning several chunks, and the result is still the
+    load spanning several chunks (of the loop) or two blocks (of the
+    kernels, in interpret mode), and the result is still the
     reference's, pair for pair."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     held, first, rows = 4, 8, 600
-    part = _share(_expert_net(EXPERTS, 0), held, first)
+    part = _share(_expert_net(EXPERTS, 0, d=d, f=f), held, first)
     p = dict(part.params["layer_0"])
+    ran = _count("dl4j_moe_grouped_matmul_calls_total", backend=path)
     # rows with a common positive part, and router columns of the held
     # experts that like it
-    a = 0.5 + jax.random.normal(jax.random.PRNGKey(3), (1, rows, D),
+    a = 0.5 + jax.random.normal(jax.random.PRNGKey(3), (1, rows, d),
                                 jnp.float32)
     p["Wr"] = p["Wr"].at[:, first:first + held].add(
         jnp.asarray([0.6, 0.5, 0.4, 0.3], jnp.float32)[None, :])
@@ -276,11 +286,150 @@ def test_a_biased_router_drops_and_pads_nothing():
     counts = np.asarray(state["layer_0"]["expert_rows"])
     expected = rows * TOP * held / EXPERTS
     assert counts.sum() >= 3 * expected and counts.max() > 128 * 2
+    assert _count("dl4j_moe_grouped_matmul_calls_total",
+                  backend=path) == ran + 1
+    if path == "pallas":        # a block is 4 experts x 256 pairs
+        assert counts.sum() > 4 * 256
     want = ref.experts(p, a[0], top_k=TOP, first_expert=first)[0]
     _close(acts[0], want)
     # the counts are the reference's routing too
     c, _ = ref.routing(ref.rms_norm(a[0], p["ln_g"]), p, TOP, first)
     np.testing.assert_array_equal(counts, np.asarray((c > 0).sum(axis=0)))
+
+
+def _pairs(counts, elsewhere, rows, d, f, seed=0):
+    """Sorted pairs with these counts (an expert takes a row once) and
+    ``elsewhere`` trailing pairs of experts held on other chips."""
+    rng = np.random.default_rng(seed)
+    n = len(counts)
+    idx = np.concatenate([rng.permutation(rows)[:c] for c in counts]
+                         + [rng.integers(0, rows, elsewhere)])
+    coef = rng.uniform(0.1, 1.0, len(idx))
+    x = rng.normal(size=(rows, d))
+    wg, wu = (0.1 * rng.normal(size=(n, d, f)) for _ in range(2))
+    wd = 0.1 * rng.normal(size=(n, f, d))
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    return (f32(x), jnp.asarray(idx, jnp.int32), f32(coef),
+            jnp.asarray(counts, jnp.int32), f32(wg), f32(wu), f32(wd))
+
+
+def _dense_experts(x, rows, coef, counts, wg, wu, wd):
+    """Expert by expert over its slice of the pairs, in jnp."""
+    y, start = jnp.zeros(x.shape, jnp.float32), 0
+    for e, c in enumerate(np.asarray(counts)):
+        idx = rows[start:start + c]
+        xs = x[idx]
+        out = (jax.nn.silu(xs @ wg[e]) * (xs @ wu[e])) @ wd[e]
+        y = y.at[idx].add(out * coef[start:start + c, None])
+        start += c
+    return y
+
+
+# counts of 4 held experts, trailing pairs, the chunk the layer would pass
+KERNEL_CASES = {
+    "balanced": ((300, 300, 300, 300), 0, 512),
+    "one_expert_takes_every_pair": ((0, 500, 0, 0), 0, 256),
+    "an_expert_with_none": ((200, 0, 310, 90), 0, 256),
+    # tiles are 256 rows: expert 1 ends its own tile part full, and
+    # three experts together fill less than one tile's rows
+    "counts_off_the_tile_two_experts": ((256, 300, 0, 0), 0, 256),
+    "counts_off_the_tile_three_experts": ((5, 130, 1, 300), 0, 256),
+    "no_pairs": ((0, 0, 0, 0), 64, 128),
+    "past_one_block_of_pairs": ((400, 300, 200, 100), 0, 128),
+    "one_expert_past_the_block": ((0, 500, 0, 0), 0, 64),
+    "trailing_pairs_held_elsewhere": ((120, 260, 7, 50), 300, 256),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_expert_kernels_match_the_loop_and_a_dense_product(monkeypatch,
+                                                           case):
+    """The Pallas path (interpret mode) against the chunk loop and
+    against a per-expert ``jnp`` product: forward and the gradients in
+    ``x``, ``coef``, ``Wg``, ``Wu``, ``Wd``. 2e-5: each side sums its
+    float32 products in another order."""
+    counts, elsewhere, chunk = KERNEL_CASES[case]
+    rows, d, f = 512, WIDE, 2 * WIDE
+    args = _pairs(counts, elsewhere, rows, d, f)
+    g = jax.random.normal(jax.random.PRNGKey(9), (rows, d), jnp.float32)
+
+    def value_and_grads(fn):
+        def loss(x, coef, wg, wu, wd):
+            return jnp.sum(fn(x, args[1], coef, args[3], wg, wu, wd) * g)
+        return jax.value_and_grad(loss, (0, 1, 2, 3, 4))(
+            args[0], args[2], *args[4:])
+
+    ffn = lambda *a: grouped.expert_ffn(*a, chunk=chunk)
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    assert grouped.grouped_supported(args[0], *args[4:], len(args[1]), chunk)
+    block = grouped._block_pairs(len(args[1]), len(counts), chunk)
+    # past one block the loop over the blocks runs a second trip
+    assert int(grouped._n_blocks(args[3], block)) == (
+        2 if case in ("past_one_block_of_pairs", "one_expert_past_the_block")
+        else 0 if case == "no_pairs" else 1)
+    got = value_and_grads(ffn)
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
+    assert not grouped.grouped_supported(args[0], *args[4:], len(args[1]),
+                                         chunk)
+    for want in (value_and_grads(ffn), value_and_grads(_dense_experts)):
+        assert abs(got[0] - want[0]) <= RTOL * max(abs(want[0]), 1e-30)
+        for a, b in zip(got[1], want[1]):
+            _close(a, b)
+    # pairs held elsewhere, and a sum of no pairs, weigh nothing
+    assert not np.asarray(got[1][1])[sum(counts):].any()
+
+
+def _widest(jaxpr):
+    """The longest leading dimension among the arrays of two or more
+    dimensions a program makes, sub-programs included."""
+    widest = 0
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            if len(shape) > 1:
+                widest = max(widest, shape[0])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            widest = max(widest, _widest(sub))
+    return widest
+
+
+def test_expert_kernels_size_nothing_by_the_worst_case(monkeypatch):
+    """``rows`` is as long as every row choosing only held experts (8
+    times the expected load here); the kernels' gathers, products and
+    scatter-adds are as long as a block and a tile an expert."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    rows, chunk, held = 2048, 256, 4
+    args = _pairs((250, 250, 250, 250), rows * 4 - 1000, rows, WIDE, WIDE)
+    assert len(args[1]) == 8 * held * chunk
+
+    def loss(x, coef, wg, wu, wd):
+        return jnp.sum(grouped.expert_ffn(x, args[1], coef, args[3], wg, wu,
+                                          wd, chunk=chunk))
+
+    program = jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3, 4)))(
+        args[0], args[2], *args[4:])
+    assert _widest(program.jaxpr) <= max(
+        rows, held * chunk + held * grouped.TILE)
+
+
+@pytest.mark.parametrize("why,d,f,interpret,backend", [
+    ("a width of 64", 64, 128, True, "xla_chunks"),
+    ("an expert width of 64", 128, 64, True, "xla_chunks"),
+    ("a CPU without interpret mode", 128, 128, False, "xla_chunks"),
+    ("whole lane tiles", 128, 256, True, "pallas")])
+def test_expert_ffn_chooses_its_backend_from_the_call(monkeypatch, why, d, f,
+                                                      interpret, backend):
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1" if interpret else "0")
+    args = _pairs((40, 30, 20, 10), 28, 64, d, f)
+    assert grouped.grouped_supported(args[0], *args[4:], len(args[1]),
+                                     128) == (backend == "pallas")
+    ran = _count("dl4j_moe_grouped_matmul_calls_total", backend=backend)
+    _close(grouped.expert_ffn(*args, chunk=128), _dense_experts(*args))
+    assert _count("dl4j_moe_grouped_matmul_calls_total",
+                  backend=backend) == ran + 1
+    # one dtype for the rows and the weights, and one the MXU takes
+    assert not grouped.grouped_supported(
+        args[0].astype(jnp.bfloat16), *args[4:], len(args[1]), 128)
 
 
 def test_expert_counts_ride_in_the_state(net, batch):
@@ -444,17 +593,34 @@ def test_token_embedding_standalone():
     _close(out[0], w[ids[0, :2]] @ np.asarray(tiny.params["layer_1"]["W"]))
 
 
-def test_every_op_of_the_step_is_placed_under_a_scope(net, batch):
+@pytest.mark.parametrize("experts", ["xla_chunks", "pallas"])
+def test_every_op_of_the_step_is_placed_under_a_scope(monkeypatch, net,
+                                                      batch, experts):
     """The step's dots lie under attn, route or experts inside a layer,
-    or in the head, and the op index places them all."""
+    or in the head, and the op index places them all: with the experts'
+    chunk loop, and with their kernels (interpret mode, a net of widths
+    the kernels take), whose calls must not land outside a scope."""
+    if experts == "pallas":
+        monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+        net = _untied(zoo.sdar_moe(seed=1, **{
+            **SMALL, "hidden": WIDE, "expert_width": WIDE}), 15)
+    ran = _count("dl4j_moe_grouped_matmul_calls_total", backend=experts)
     step = jax.jit(net._step_fn())
     args = net._step_args(net._batch_args(batch), jax.random.PRNGKey(0))
     index = opindex.parse(step.lower(*args).compile().as_text())
+    assert _count("dl4j_moe_grouped_matmul_calls_total",
+                  backend=experts) > ran
     seen = set()
     for entry in index.values():
         phase, layer, _ = opindex.place(entry)
-        if entry["opcode"] in ("fusion", "custom-call", "dot", "scatter",
-                               "gather", "sort", "while"):
+        # the interpreter's ``pl.when`` branches hold zero tiles that the
+        # CPU compiler broadcasts from a constant and gives no name; on a
+        # TPU a kernel is one custom call
+        constant = experts == "pallas" and not entry["op_name"] and all(
+            opcode == "broadcast" for opcode, _ in entry["inner"])
+        if not constant and entry["opcode"] in (
+                "fusion", "custom-call", "dot", "scatter", "gather", "sort",
+                "while"):
             assert phase != "unplaced", entry
         _, scope, _ = opindex.place(
             entry, scopes=("attn", "block_attention", "route", "experts"))
@@ -481,20 +647,29 @@ def test_trace_time_counters(monkeypatch):
                       direction="forward"),
         "bwd": _count("dl4j_block_attention_calls_total", backend="pallas",
                       direction="backward"),
-        "gmm": _count("dl4j_moe_grouped_matmul_calls_total")}
+        "loop": _count("dl4j_moe_grouped_matmul_calls_total",
+                       backend="xla_chunks"),
+        "kernels": _count("dl4j_moe_grouped_matmul_calls_total",
+                          backend="pallas")}
     q, k, v, g = _qkv(128, 2, 1, 128)
     jax.grad(lambda q: jnp.sum(att.block_diffusion_mha(
         q, k, v, seq_len=128, block_len=4) * g))(q)
     q, k, v, _ = _qkv(8, 2, 1, 16)
     att.block_diffusion_mha(q, k, v, seq_len=8, block_len=4)
     _expert_net(4, 0).output(np.zeros((1, 4, D), np.float32))
+    _expert_net(4, 0, d=WIDE, f=WIDE).output(
+        np.zeros((1, 4, WIDE), np.float32))
     assert _count("dl4j_block_attention_calls_total",
                   backend="xla") == before["xla"] + 1
     assert _count("dl4j_block_attention_calls_total", backend="pallas",
                   direction="forward") == before["fwd"] + 1
     assert _count("dl4j_block_attention_calls_total", backend="pallas",
                   direction="backward") == before["bwd"] + 1
-    assert _count("dl4j_moe_grouped_matmul_calls_total") == before["gmm"] + 1
+    # widths of 32 and 24 keep the chunk loop, whole lane tiles do not
+    assert _count("dl4j_moe_grouped_matmul_calls_total",
+                  backend="xla_chunks") == before["loop"] + 1
+    assert _count("dl4j_moe_grouped_matmul_calls_total",
+                  backend="pallas") == before["kernels"] + 1
 
 
 def test_routing_counts_are_scraped_not_read_by_the_fit():

@@ -180,3 +180,33 @@ def test_block_attention_kernels_compile_at_the_bench_shape(one_chip, x32):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     # live tiles only: 320 of the 1,024 tile pairs of 128 x 512
     assert len(att._bd_live_tiles(seq, block, 128, 512)) == 320
+
+
+def test_grouped_expert_kernels_compile_at_the_bench_shape(one_chip, x32):
+    """The three kernels of ops/grouped.py at the SDAR cell's shape:
+    8,192 rows of 2,048, 16 held experts of width 768, the worst case of
+    65,536 pairs and blocks of 16 x 768 (Mosaic accepts the transposed
+    products, the scalar-prefetch tables and three float32 gradient
+    blocks of one expert resident within the VMEM limit, the rows' DMAs).
+    Forward and both backward kernels, each once in the program, inside
+    the loop over the blocks of pairs."""
+    from deeplearning4j_tpu.ops import grouped
+
+    rows, d, f, held, pairs, chunk = 8192, 2048, 768, 16, 65536, 768
+    cd = jnp.bfloat16
+    shapes = [((rows, d), cd), ((pairs,), jnp.int32), ((pairs,), jnp.float32),
+              ((held,), jnp.int32), ((held, d, f), cd), ((held, d, f), cd),
+              ((held, f, d), cd)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    assert grouped.grouped_supported(*(args[i] for i in (0, 4, 5, 6)), pairs,
+                                     chunk) == (jax.default_backend() == "tpu")
+    assert grouped._vmem_request(d, f, 2) <= grouped._VMEM_CAP
+
+    def loss(x, rows, coef, counts, wg, wu, wd):
+        y = grouped._expert_ffn(x, rows, coef, counts, wg, wu, wd, chunk,
+                                True)
+        return jnp.sum(y * y)
+
+    text = jax.jit(jax.grad(loss, (0, 2, 4, 5, 6))).lower(*args).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
