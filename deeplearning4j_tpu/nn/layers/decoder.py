@@ -1,6 +1,6 @@
 """Runtime layers of a modern decoder (configs and the equations:
-nn/conf/layers_decoder.py; PERF.md section 4 has the model they were
-written for).
+nn/conf/layers_decoder.py; PERF.md section 4 has the two models they
+were written for, and that docstring says which decoder uses what).
 
 Precision under a mixed policy: parameters in the param dtype, every
 large product in the compute dtype with float32 accumulation, and in
@@ -10,10 +10,12 @@ must not turn on a bf16 rounding of the router's own making), the
 attention's softmax and the loss. The residual stream between layers is
 in the compute dtype, as in every other net of this package.
 
-Named scopes inside a block, under the layer's own: ``attn`` (norm,
+Named scopes inside a layer, under the layer's own: ``attn`` (norm,
 projections, head norms, rotation, output projection, and inside it
-``block_attention`` round the attention itself, whatever backend runs),
-``route`` (norm, router, top-k, the sort of the pairs, and what
+``block_attention`` or ``causal_attention`` round the attention itself,
+whatever backend runs), ``mamba`` (norm, both projections, and inside it
+``ssm_conv``, ``ssm_scan`` and ``ssm_norm`` round the three ops of
+ops/ssm.py), ``shared_expert`` (its two products), ``route`` (norm, router, top-k, the sort of the pairs, and what
 ``ops/grouped.py`` does to move rows in XLA: a gather a block of pairs
 before its kernels, or a gather and a scatter-add a chunk inside its
 loop) and ``experts`` (the grouped products and the gating between
@@ -35,6 +37,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import grouped
 from deeplearning4j_tpu.ops import initializers as init_mod
+from deeplearning4j_tpu.ops import ssm
 
 
 def _rms_norm(x, g, eps):
@@ -104,7 +107,7 @@ class _DecoderLayer(Layer):
             raise NotImplementedError(
                 f"{type(self).__name__} '{self.name}' has no streaming "
                 "path: rnn_time_step and truncated BPTT are not supported "
-                "on the decoder layers (ROADMAP Queue 2 item 10)")
+                "on the decoder layers (ROADMAP Queue 2 items 9 and 10)")
 
     def _init(self, key, shape, fan_in, fan_out):
         w_fn = init_mod.resolve(self.resolve("weight_init", "xavier"))
@@ -144,36 +147,67 @@ class RoutedExpertsLayer(_DecoderLayer):
                 f"{type(conf).__name__} '{conf.name}': experts "
                 f"{conf.first_expert}..{conf.first_expert + self.held - 1} "
                 f"are not among {conf.n_experts}")
+        for field, known in (("router", ("softmax", "sigmoid")),
+                             ("expert_form", ("gated_silu", "relu2"))):
+            if getattr(conf, field) not in known:
+                raise ValueError(
+                    f"{type(conf).__name__} '{conf.name}': {field} "
+                    f"{getattr(conf, field)!r} is neither "
+                    f"{known[0]!r} nor {known[1]!r}")
 
     def init_params(self, key):
         d, f = int(self.conf.n_out), int(self.conf.expert_width)
         n, held = int(self.conf.n_experts), self.held
         kr, kg, ku, kd = jax.random.split(key, 4)
-        return {
+        params = {
             "ln_g": jnp.ones((d,), self.param_dtype),
             "Wr": self._init(kr, (d, n), d, n),
-            "Wg": self._init(kg, (held, d, f), d, f),
             "Wu": self._init(ku, (held, d, f), d, f),
             "Wd": self._init(kd, (held, f, d), f, d),
         }
+        if self.conf.expert_form == "gated_silu":
+            params["Wg"] = self._init(kg, (held, d, f), d, f)
+        shared = int(self.conf.shared_width)
+        if shared:
+            ku, kd = jax.random.split(jax.random.fold_in(key, 1))
+            params["Ws_u"] = self._init(ku, (d, shared), d, shared)
+            params["Ws_d"] = self._init(kd, (shared, d), shared, d)
+        return params
 
     def init_state(self):
         # the total in two int32 limbs (low 30 bits, the rest): one would
         # wrap after 33k steps of the worst case
-        return {"expert_rows": jnp.zeros((self.held,), jnp.int32),
-                "expert_rows_total": jnp.zeros((2, self.held), jnp.int32)}
+        state = {"expert_rows": jnp.zeros((self.held,), jnp.int32),
+                 "expert_rows_total": jnp.zeros((2, self.held), jnp.int32)}
+        if self.conf.router == "sigmoid":
+            state["router_bias"] = jnp.zeros((int(self.conf.n_experts),),
+                                             jnp.float32)
+        return state
 
-    def _route(self, params, a):
+    def _choose(self, logits, state):
+        """The ``experts_per_token`` experts of every row and their
+        weights, float32 [R, k]."""
+        k = int(self.conf.experts_per_token)
+        if self.conf.router == "softmax":
+            top, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
+        score = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            score + jax.lax.stop_gradient(state["router_bias"]), k)
+        top = jnp.take_along_axis(score, chosen, axis=-1)
+        return chosen, top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+
+    def _route(self, params, state, a):
         """The normed rows [R, d] (float32) and the pairs held here,
         sorted by expert: rows, weights, and the count of each expert."""
         k = int(self.conf.experts_per_token)
         w = _rms_norm(a, params["ln_g"], self.conf.eps).reshape(
             -1, a.shape[-1])
-        r = jax.nn.softmax(jnp.dot(
+        chosen, coef = self._choose(jnp.dot(
             w, params["Wr"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST), axis=-1)
-        top, chosen = jax.lax.top_k(r, k)
-        coef = top / jnp.sum(top, axis=-1, keepdims=True)
+            precision=jax.lax.Precision.HIGHEST), state)
+        if self.conf.routed_scale != 1.0:
+            coef = coef * float(self.conf.routed_scale)
         local = chosen.astype(jnp.int32) - int(self.conf.first_expert)
         key = jnp.where((local >= 0) & (local < self.held), local,
                         self.held).reshape(-1)
@@ -187,46 +221,89 @@ class RoutedExpertsLayer(_DecoderLayer):
     def _experts(self, params, state, a):
         cd = a.dtype
         with jax.named_scope("route"):
-            w, rows, coef, counts = self._route(params, a)
+            w, rows, coef, counts = self._route(params, state, a)
         chunk = expert_chunk_rows(w.shape[0], self.conf.experts_per_token,
                                   self.conf.n_experts)
+        gate = params.get("Wg")
         with jax.named_scope("experts"):
             y = grouped.expert_ffn(
-                w.astype(cd), rows, coef, counts, params["Wg"].astype(cd),
+                w.astype(cd), rows, coef, counts,
+                None if gate is None else gate.astype(cd),
                 params["Wu"].astype(cd), params["Wd"].astype(cd),
                 chunk=chunk)
+        if "Ws_u" in params:
+            with jax.named_scope("shared_expert"):
+                up = jnp.dot(w.astype(cd), params["Ws_u"].astype(cd),
+                             preferred_element_type=jnp.float32)
+                held = jnp.square(jnp.maximum(up, 0.0)).astype(cd)
+                y = y + jnp.dot(held, params["Ws_d"].astype(cd),
+                                preferred_element_type=jnp.float32)
         with jax.named_scope("route"):
             out = a + y.reshape(a.shape).astype(cd)
             low = state["expert_rows_total"][0] + counts
             total = jnp.stack([
                 low & ((1 << _LIMB) - 1),
                 state["expert_rows_total"][1] + (low >> _LIMB)])
-        return out, {"expert_rows": counts, "expert_rows_total": total}
+        return out, {**state, "expert_rows": counts,
+                     "expert_rows_total": total}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         return self._experts(params, state, x.astype(self.compute_dtype))
 
 
-class MoeDecoderBlockLayer(RoutedExpertsLayer):
-    def __init__(self, conf, input_type, global_conf, policy):
-        super().__init__(conf, input_type, global_conf, policy)
+class _GroupedQueryHeads:
+    """The projections of a pre-norm grouped-query attention, for the
+    layers whose conf has ``n_heads``, ``n_kv_heads`` and ``head_dim``:
+    parameters ``attn_ln_g``, ``Wq``, ``Wk``, ``Wv``, ``Wo``."""
+
+    def _check_heads(self):
+        conf = self.conf
         if conf.n_heads % conf.n_kv_heads:
             raise ValueError(
-                f"MoeDecoderBlock '{conf.name}': {conf.n_heads} query heads "
-                f"cannot share {conf.n_kv_heads} key/value heads evenly")
+                f"{type(conf).__name__} '{conf.name}': {conf.n_heads} query "
+                f"heads cannot share {conf.n_kv_heads} key/value heads "
+                "evenly")
 
-    def init_params(self, key):
+    def _init_heads(self, kq, kk, kv, ko):
         d = int(self.conf.n_out)
         hq, hkv, dh = (int(self.conf.n_heads), int(self.conf.n_kv_heads),
                        int(self.conf.head_dim))
-        k_experts, kq, kk, kv, ko = jax.random.split(key, 5)
-        params = super().init_params(k_experts)
-        params.update({
+        return {
             "attn_ln_g": jnp.ones((d,), self.param_dtype),
             "Wq": self._init(kq, (d, hq * dh), d, hq * dh),
             "Wk": self._init(kk, (d, hkv * dh), d, hkv * dh),
             "Wv": self._init(kv, (d, hkv * dh), d, hkv * dh),
             "Wo": self._init(ko, (hq * dh, d), hq * dh, d),
+        }
+
+    def _heads(self, params, x):
+        """q [b, t, hq, dh], k and v [b, t, hkv, dh] of the normed rows,
+        in ``x``'s dtype."""
+        conf, cd = self.conf, x.dtype
+        b, t, _ = x.shape
+        hq, hkv, dh = int(conf.n_heads), int(conf.n_kv_heads), int(
+            conf.head_dim)
+        u = _rms_norm(x, params["attn_ln_g"], conf.eps).astype(cd)
+        return (_project(u, params["Wq"], cd).reshape(b, t, hq, dh),
+                _project(u, params["Wk"], cd).reshape(b, t, hkv, dh),
+                _project(u, params["Wv"], cd).reshape(b, t, hkv, dh))
+
+    def _merge_heads(self, params, x, o):
+        b, t, _ = x.shape
+        return x + _project(o.reshape(b, t, -1), params["Wo"], x.dtype)
+
+
+class MoeDecoderBlockLayer(_GroupedQueryHeads, RoutedExpertsLayer):
+    def __init__(self, conf, input_type, global_conf, policy):
+        super().__init__(conf, input_type, global_conf, policy)
+        self._check_heads()
+
+    def init_params(self, key):
+        dh = int(self.conf.head_dim)
+        k_experts, kq, kk, kv, ko = jax.random.split(key, 5)
+        params = super().init_params(k_experts)
+        params.update(self._init_heads(kq, kk, kv, ko))
+        params.update({
             "q_norm_g": jnp.ones((dh,), self.param_dtype),
             "k_norm_g": jnp.ones((dh,), self.param_dtype),
         })
@@ -234,17 +311,12 @@ class MoeDecoderBlockLayer(RoutedExpertsLayer):
 
     def _attention(self, params, x):
         conf, cd = self.conf, x.dtype
-        b, t, _ = x.shape
+        t = x.shape[1]
         if t % 2:
             raise ValueError(
                 f"MoeDecoderBlock '{self.name}' takes a noised and a clean "
                 f"copy of each sequence, an even number of rows; got {t}")
-        hq, hkv, dh = int(conf.n_heads), int(conf.n_kv_heads), int(
-            conf.head_dim)
-        u = _rms_norm(x, params["attn_ln_g"], conf.eps).astype(cd)
-        q = _project(u, params["Wq"], cd).reshape(b, t, hq, dh)
-        k = _project(u, params["Wk"], cd).reshape(b, t, hkv, dh)
-        v = _project(u, params["Wv"], cd).reshape(b, t, hkv, dh)
+        q, k, v = self._heads(params, x)
         q = _rotate(_rms_norm(q, params["q_norm_g"], conf.eps),
                     conf.rope_theta).astype(cd)
         k = _rotate(_rms_norm(k, params["k_norm_g"], conf.eps),
@@ -252,12 +324,101 @@ class MoeDecoderBlockLayer(RoutedExpertsLayer):
         with jax.named_scope("block_attention"):
             o = att.block_diffusion_mha(q, k, v, seq_len=t // 2,
                                         block_len=int(conf.block_len))
-        return x + _project(o.reshape(b, t, hq * dh), params["Wo"], cd)
+        return self._merge_heads(params, x, o)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         with jax.named_scope("attn"):
             a = self._attention(params, x.astype(self.compute_dtype))
         return self._experts(params, state, a)
+
+
+class CausalAttentionLayer(_GroupedQueryHeads, _DecoderLayer):
+    def __init__(self, conf, input_type, global_conf, policy):
+        super().__init__(conf, input_type, global_conf, policy)
+        self._check_heads()
+
+    def init_params(self, key):
+        return self._init_heads(*jax.random.split(key, 4))
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = x.astype(self.compute_dtype)
+        with jax.named_scope("attn"):
+            q, k, v = self._heads(params, x)
+            with jax.named_scope("causal_attention"):
+                o = att.causal_attention(q, k, v)
+            return self._merge_heads(params, x, o), state
+
+
+def _inverse_softplus(dt):
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2MixerLayer(_DecoderLayer):
+    def __init__(self, conf, input_type, global_conf, policy):
+        super().__init__(conf, input_type, global_conf, policy)
+        if conf.n_heads % conf.n_groups:
+            raise ValueError(
+                f"Mamba2Mixer '{conf.name}': {conf.n_heads} heads cannot "
+                f"share {conf.n_groups} groups evenly")
+        self.inner = int(conf.n_heads) * int(conf.head_dim)
+        self.bc = int(conf.n_groups) * int(conf.state_size)
+
+    def init_params(self, key):
+        conf = self.conf
+        d, heads, k = int(conf.n_out), int(conf.n_heads), int(
+            conf.conv_kernel)
+        wide = 2 * self.inner + 2 * self.bc + heads
+        conv = self.inner + 2 * self.bc
+        k_in, k_out, k_w, k_b, k_dt = jax.random.split(key, 5)
+        pd = self.param_dtype
+        # dt ~ logU[dt_min, dt_max], floored; the convolution as a
+        # depthwise Conv1d's default, uniform(+-1/sqrt(kernel))
+        dt = jnp.exp(jax.random.uniform(k_dt, (heads,), jnp.float32)
+                     * (math.log(conf.dt_max) - math.log(conf.dt_min))
+                     + math.log(conf.dt_min))
+        bound = 1.0 / math.sqrt(k)
+        return {
+            "ln_g": jnp.ones((d,), pd),
+            "W_in": self._init(k_in, (d, wide), d, wide),
+            "conv_w": jax.random.uniform(k_w, (conv, k), pd, -bound, bound),
+            "conv_b": jax.random.uniform(k_b, (conv,), pd, -bound, bound),
+            "dt_bias": _inverse_softplus(
+                jnp.maximum(dt, conf.dt_floor)).astype(pd),
+            "A_log": jnp.log(jnp.arange(1, heads + 1, dtype=pd)),
+            "D": jnp.ones((heads,), pd),
+            "norm_g": jnp.ones((self.inner,), pd),
+            "W_out": self._init(k_out, (self.inner, d), self.inner, d),
+        }
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        conf, cd = self.conf, self.compute_dtype
+        x = x.astype(cd)
+        b, t, _ = x.shape
+        heads, groups = int(conf.n_heads), int(conf.n_groups)
+        f32 = jnp.float32
+        with jax.named_scope("mamba"):
+            u = _rms_norm(x, params["ln_g"], conf.eps).astype(cd)
+            zxbcdt = _project(u, params["W_in"], cd)
+            z, xbc, dt = jnp.split(
+                zxbcdt, [self.inner, 2 * self.inner + 2 * self.bc], axis=-1)
+            with jax.named_scope("ssm_conv"):
+                xbc = ssm.causal_conv1d(xbc, params["conv_w"],
+                                        params["conv_b"])
+            xs, bm, cm = jnp.split(xbc, [self.inner, self.inner + self.bc],
+                                   axis=-1)
+            dt = jax.nn.softplus(dt.astype(f32)
+                                 + params["dt_bias"].astype(f32))
+            with jax.named_scope("ssm_scan"):
+                y = ssm.ssm_scan(
+                    xs.reshape(b, t, heads, -1), dt,
+                    -jnp.exp(params["A_log"].astype(f32)),
+                    bm.reshape(b, t, groups, -1),
+                    cm.reshape(b, t, groups, -1),
+                    params["D"].astype(f32), chunk=int(conf.chunk))
+            with jax.named_scope("ssm_norm"):
+                y = ssm.gated_group_norm(y.reshape(b, t, -1), z,
+                                         params["norm_g"], groups, conf.eps)
+            return x + _project(y.astype(cd), params["W_out"], cd), state
 
 
 class TokenOutputLayer(_DecoderLayer):
@@ -267,8 +428,8 @@ class TokenOutputLayer(_DecoderLayer):
 
     def _logits(self, params, x):
         cd = self.compute_dtype
-        noised = x[:, :x.shape[1] // 2]
-        return jnp.einsum("btf,fg->btg", noised.astype(cd),
+        rows = x if self.conf.causal else x[:, :x.shape[1] // 2]
+        return jnp.einsum("btf,fg->btg", rows.astype(cd),
                           params["W"].astype(cd),
                           preferred_element_type=jnp.float32)
 

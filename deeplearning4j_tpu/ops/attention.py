@@ -397,14 +397,19 @@ def block_diffusion_visible(i, j, seq_len: int, block_len: int):
                      (~noised_j) & (bj <= bi))
 
 
-def _count_block_attention(direction: str, backend: str) -> None:
+def _count_calls(metric: str, what: str, direction: str,
+                 backend: str) -> None:
     from deeplearning4j_tpu.observability.metrics import get_registry
 
     get_registry().counter(
-        "dl4j_block_attention_calls_total",
-        "Block-diffusion attention calls traced, by direction and backend",
+        metric, f"{what} calls traced, by direction and backend",
         ("direction", "backend")).labels(
             direction=direction, backend=backend).inc()
+
+
+def _count_block_attention(direction: str, backend: str) -> None:
+    _count_calls("dl4j_block_attention_calls_total",
+                 "Block-diffusion attention", direction, backend)
 
 
 @registry.register("block_diffusion_mha", backend="xla")
@@ -413,14 +418,19 @@ def block_diffusion_mha_xla(q, k, v, *, seq_len: int, block_len: int):
     Scores and softmax in float32, both products with float32
     accumulation; autodiff gives the backward."""
     _count_block_attention("forward", "xla")
+    rows = jnp.arange(q.shape[1], dtype=jnp.int32)
+    return _dense_masked_mha(q, k, v, block_diffusion_visible(
+        rows[:, None], rows[None, :], seq_len, block_len))
+
+
+def _dense_masked_mha(q, k, v, visible):
+    """Grouped-query attention under ``visible`` [T, T] over the dense
+    scores: q [b, T, Hq, dh], k and v [b, T, Hkv, dh] -> [b, T, Hq, dh]."""
     b, t, hq, dh = q.shape
     hkv = k.shape[2]
     qg = q.reshape(b, t, hkv, hq // hkv, dh)
     s = jnp.einsum("bikgd,bjkd->bkgij", qg, k,
                    preferred_element_type=jnp.float32) / math.sqrt(dh)
-    rows = jnp.arange(t, dtype=jnp.int32)
-    visible = block_diffusion_visible(rows[:, None], rows[None, :],
-                                      seq_len, block_len)
     p = jax.nn.softmax(jnp.where(visible, s, _NEG_INF), axis=-1)
     out = jnp.einsum("bkgij,bjkd->bikgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -443,7 +453,14 @@ def block_attention_supported(q, k, v, seq_len: int, block_len: int) -> bool:
         return False
     if block_len & (block_len - 1) or _BD_BQ % block_len:
         return False
-    if q.dtype not in (jnp.bfloat16, jnp.float32) or hq % k.shape[2]:
+    return _bd_operands_supported(q, k, v)
+
+
+def _bd_operands_supported(q, k, v) -> bool:
+    """What the tiled kernels ask whatever the mask rule: one dtype the
+    MXU takes, query heads in whole groups, and a TPU (or the tests'
+    interpret mode) to run them."""
+    if q.dtype not in (jnp.bfloat16, jnp.float32) or q.shape[2] % k.shape[2]:
         return False
     if k.dtype != q.dtype or v.dtype != q.dtype:
         return False
@@ -480,13 +497,27 @@ def _bd_live_tiles(seq_len: int, block_len: int, bq: int, bk: int):
     return np.asarray(pairs, np.int32)
 
 
+def _causal_live_tiles(rows: int, bq: int, bk: int):
+    """The live pairs of the causal rule over ``rows`` rows, "row i sees
+    key j <= i": in the kernels' terms every row is a clean row whose
+    block is itself (kind 2 with blocks of one position and no noised
+    half), so the rule is a table and the kernels are the same."""
+    import numpy as np
+
+    return np.asarray([(qi, ki, 2) for qi in range(rows // bq)
+                       for ki in range(rows // bk)
+                       if ki * bk <= qi * bq + bq - 1], np.int32)
+
+
 def _bd_tables(seq_len, block_len, bq, bk, key_major: bool):
     """Scalar-prefetch tables of the live pairs in the order a kernel
     walks them: ``qi, ki, kind, first, last`` where first/last mark the
-    run of steps that share the tile the kernel accumulates for."""
+    run of steps that share the tile the kernel accumulates for.
+    ``block_len`` None: the causal rule over ``seq_len`` rows."""
     import numpy as np
 
-    pairs = _bd_live_tiles(seq_len, block_len, bq, bk)
+    pairs = (_causal_live_tiles(seq_len, bq, bk) if block_len is None
+             else _bd_live_tiles(seq_len, block_len, bq, bk))
     owner = 1 if key_major else 0
     if key_major:
         pairs = pairs[np.lexsort((pairs[:, 0], pairs[:, 1]))]
@@ -725,6 +756,12 @@ def _bd_join(og, b):
         b, t, bh // b * g, dh)
 
 
+def _bd_shift(block_len) -> int:
+    """A position's block is the position shifted by this; the causal
+    rule (``block_len`` None) has blocks of one."""
+    return 0 if block_len is None else block_len.bit_length() - 1
+
+
 def _bd_forward(qg, kg, vg, seq_len, block_len):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -741,7 +778,7 @@ def _bd_forward(qg, kg, vg, seq_len, block_len):
                  pltpu.VMEM((g * bq, 1), jnp.float32),
                  pltpu.VMEM((g * bq, dh), jnp.float32)],
         scale=1.0 / math.sqrt(dh), seq_len=seq_len,
-        shift=block_len.bit_length() - 1)(*tables, qg, kg, vg)
+        shift=_bd_shift(block_len))(*tables, qg, kg, vg)
 
 
 def _bd_backward(qg, kg, vg, og, lse, dog, seq_len, block_len):
@@ -750,7 +787,7 @@ def _bd_backward(qg, kg, vg, og, lse, dog, seq_len, block_len):
     bh, g, t, dh = qg.shape
     bq, bk = _BD_BQ, _bd_key_tile(seq_len)
     static = dict(scale=1.0 / math.sqrt(dh), seq_len=seq_len,
-                  shift=block_len.bit_length() - 1)
+                  shift=_bd_shift(block_len))
     di = jnp.sum(og.astype(jnp.float32) * dog.astype(jnp.float32), axis=-1)
     rows, stats, keys = _bd_specs(g, bq, bk, dh)
     operands = (qg, kg, vg, dog, lse, di)
@@ -810,3 +847,68 @@ def block_diffusion_mha(q, k, v, *, seq_len: int, block_len: int):
     """Resolve the registered backend order and apply (layer-facing)."""
     return registry.get("block_diffusion_mha")(
         q, k, v, seq_len=seq_len, block_len=block_len)
+
+
+# ------------------------------------------- causal attention for training
+# The causal rule on the tiled kernels above: forward, dQ and dK/dV over
+# the live tiles on and under the diagonal, scores never in HBM. This is
+# the training path of a causal decoder layer (nn/layers/decoder.py
+# ``CausalAttentionLayer``); ``causal_mha`` further up stays the serving
+# path's, with its decode bit-identity contract, and is not touched.
+
+
+def _count_causal_attention(direction: str, backend: str) -> None:
+    _count_calls("dl4j_causal_attention_calls_total",
+                 "Causal training attention", direction, backend)
+
+
+@registry.register("causal_attention", backend="xla")
+def causal_attention_xla(q, k, v):
+    """q [b, T, Hq, dh], k and v [b, T, Hkv, dh] -> [b, T, Hq, dh]:
+    masked softmax over the dense scores in float32 (they reach
+    memory); autodiff gives the backward."""
+    _count_causal_attention("forward", "xla")
+    rows = jnp.arange(q.shape[1], dtype=jnp.int32)
+    return _dense_masked_mha(q, k, v, rows[:, None] >= rows[None, :])
+
+
+def causal_attention_supported(q, k, v) -> bool:
+    """Whether the tiled kernels cover this call: whole tiles of 128
+    rows, heads of 128, and what ``_bd_operands_supported`` asks."""
+    if q.shape[1] % 128 or q.shape[3] % 128:
+        return False
+    return _bd_operands_supported(q, k, v)
+
+
+@jax.custom_vjp
+def _causal_tiled(qg, kg, vg):
+    return _bd_forward(qg, kg, vg, qg.shape[2], None)[0]
+
+
+def _causal_tiled_fwd(qg, kg, vg):
+    _count_causal_attention("forward", "pallas")
+    og, lse = _bd_forward(qg, kg, vg, qg.shape[2], None)
+    return og, (qg, kg, vg, og, lse)
+
+
+def _causal_tiled_bwd(residuals, dog):
+    _count_causal_attention("backward", "pallas")
+    return _bd_backward(*residuals, dog, residuals[0].shape[2], None)
+
+
+_causal_tiled.defvjp(_causal_tiled_fwd, _causal_tiled_bwd)
+
+
+@registry.register("causal_attention", backend="pallas")
+def causal_attention_pallas(q, k, v):
+    """The tiled forward and backward; delegates to the xla backend for
+    calls ``causal_attention_supported`` refuses."""
+    if not causal_attention_supported(q, k, v):
+        return causal_attention_xla(q, k, v)
+    return _bd_join(_causal_tiled(*_bd_split(q, k, v)), q.shape[0])
+
+
+def causal_attention(q, k, v):
+    """Causal grouped-query attention of a training step: resolve the
+    registered backend order and apply (layer-facing)."""
+    return registry.get("causal_attention")(q, k, v)

@@ -1,6 +1,18 @@
-"""Grouped gated feed-forward over routed (row, expert) pairs: the
-matrix products of an expert layer that holds some of a model's experts
-(nn/layers/decoder.py).
+"""Grouped feed-forward over routed (row, expert) pairs: the matrix
+products of an expert layer that holds some of a model's experts
+(nn/layers/decoder.py ``RoutedExpertsLayer``).
+
+An expert has one of two forms, told apart by whether the call brings a
+gate matrix: ``down(silu(gate x) * up x)``, three matrices and nine
+products a training step (the block-diffusion decoder's,
+``zoo.sdar_moe``); or ``down(max(up x, 0)^2)``, two matrices and six
+products (the hybrid decoder's, ``zoo.nemotron_h``). The chunk loop runs
+both (``_hidden``, ``_hidden_and_slopes`` are all that differs); the
+kernels are written for the gated form alone, and the one configuration
+without a gate has the published width 1,856, 14.5 lane tiles, which
+they would refuse anyway (ROADMAP Queue 2 item 6 has both steps). A shared
+expert that every row takes is no business of this module: it is two
+plain products in the layer.
 
 The pairs arrive sorted by held expert: pair ``p`` is row ``rows[p]`` of
 ``x`` weighted by ``coef[p]``, expert ``e`` owns the ``counts[e]`` pairs
@@ -43,7 +55,8 @@ loading them cost the decoder job 2 s of set-up, past its bound): a
 later block of a biased router goes on from the weight gradients the
 blocks before it left, which were rounded to the weights' dtype.
 
-**The chunk loop** (the CPU, widths that are no multiple of 128): the
+**The chunk loop** (the CPU, widths that are no multiple of 128, an
+expert without a gate): the
 sorted pairs are walked in chunks of ``chunk`` pairs of one expert by a
 loop whose trip count is the number of chunks that hold a pair,
 ``sum(ceil(counts / chunk))``: a chunk gathers its rows, runs the three
@@ -122,18 +135,41 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
+# The two forms of an expert, told apart by what the call brings: with a
+# gate matrix ``down(silu(gate x) * up x)``, without one ``down(max(up
+# x, 0)^2)``. ``pre`` holds the float32 products of a chunk's rows with
+# the matrices that lead into the hidden width: (gate, up) or (up,).
+def _hidden(pre):
+    if len(pre) == 2:
+        gate, up = pre
+        return jax.nn.silu(gate) * up
+    return jnp.square(jnp.maximum(pre[0], 0.0))
+
+
+def _hidden_and_slopes(pre):
+    """The hidden row again and what a unit of its gradient is worth to
+    each of ``pre``."""
+    if len(pre) == 2:
+        gate, up = pre
+        sig = jax.nn.sigmoid(gate)
+        act = gate * sig
+        return act * up, (up * (sig + act * (1.0 - sig)), act)
+    held = jnp.maximum(pre[0], 0.0)
+    return held * held, (2.0 * held,)
+
+
 def _forward(x, rows, coef, counts, wg, wu, wd, chunk):
     table = _chunk_table(counts, rows.shape[0], chunk)
     rows, coef = _pad(rows, coef, chunk)
     cd = x.dtype
+    inward = [w for w in (wg, wu) if w is not None]
 
     def body(c, y):
         with jax.named_scope("route"):
             e, _, idx, w, _ = _chunk_inputs(c, table, rows, coef, chunk)
             xs = jnp.take(x, idx, axis=0)
-        gate = _dot(xs, wg[e], ((1,), (0,)))
-        up = _dot(xs, wu[e], ((1,), (0,)))
-        h = (jax.nn.silu(gate) * up).astype(cd)
+        pre = [_dot(xs, w_in[e], ((1,), (0,))) for w_in in inward]
+        h = _hidden(pre).astype(cd)
         out = _dot(h, wd[e], ((1,), (0,)))
         with jax.named_scope("route"):
             return y.at[idx].add(out * w[:, None])
@@ -148,38 +184,40 @@ def _backward(x, rows, coef, counts, wg, wu, wd, dy, chunk):
     rows, coef = _pad(rows, coef, chunk)
     cd = x.dtype
     dy = dy.astype(cd)
+    inward = [w for w in (wg, wu) if w is not None]
 
     def body(c, carry):
-        dx, dcoef, dwg, dwu, dwd = carry
+        dx, dcoef, dw_in, dwd = carry
         with jax.named_scope("route"):
             e, s, idx, w, live = _chunk_inputs(c, table, rows, coef, chunk)
             xs = jnp.take(x, idx, axis=0)
             dys = jnp.take(dy, idx, axis=0)
-        gate = _dot(xs, wg[e], ((1,), (0,)))
-        up = _dot(xs, wu[e], ((1,), (0,)))
-        sig = jax.nn.sigmoid(gate)
-        act = gate * sig
-        h = act * up
+        pre = [_dot(xs, w_in[e], ((1,), (0,))) for w_in in inward]
+        h, slopes = _hidden_and_slopes(pre)
         dh_pair = _dot(dys, wd[e], ((1,), (1,)))        # of one unit of coef
         dcoef = jax.lax.dynamic_update_slice(
             dcoef, jnp.where(live, jnp.sum(dh_pair * h, axis=1), 0.0), (s,))
         dh = dh_pair * w[:, None]
-        dgate = (dh * up * (sig + act * (1.0 - sig))).astype(cd)
-        dup = (dh * act).astype(cd)
+        dpre = [(dh * slope).astype(cd) for slope in slopes]
         dwd = dwd.at[e].add(_dot((h * w[:, None]).astype(cd), dys,
                                  ((0,), (0,))))
-        dwg = dwg.at[e].add(_dot(xs, dgate, ((0,), (0,))))
-        dwu = dwu.at[e].add(_dot(xs, dup, ((0,), (0,))))
-        dxs = (_dot(dgate, wg[e], ((1,), (1,)))
-               + _dot(dup, wu[e], ((1,), (1,))))
+        dw_in = [dw.at[e].add(_dot(xs, d, ((0,), (0,))))
+                 for dw, d in zip(dw_in, dpre)]
+        dxs = _dot(dpre[0], inward[0][e], ((1,), (1,)))
+        for d, w_in in zip(dpre[1:], inward[1:]):
+            dxs = dxs + _dot(d, w_in[e], ((1,), (1,)))
         with jax.named_scope("route"):
-            return dx.at[idx].add(dxs), dcoef, dwg, dwu, dwd
+            return dx.at[idx].add(dxs), dcoef, dw_in, dwd
 
-    zeros = [jnp.zeros(a.shape, jnp.float32) for a in (x, wg, wu, wd)]
-    dx, dcoef, dwg, dwu, dwd = jax.lax.fori_loop(
+    def zeros(a):
+        return jnp.zeros(a.shape, jnp.float32)
+
+    dx, dcoef, dw_in, dwd = jax.lax.fori_loop(
         0, table[3], body,
-        (zeros[0], jnp.zeros((n_pairs + chunk,), jnp.float32), *zeros[1:]))
-    return dx, dcoef[:n_pairs], dwg, dwu, dwd
+        (zeros(x), jnp.zeros((n_pairs + chunk,), jnp.float32),
+         [zeros(w) for w in inward], zeros(wd)))
+    # one gradient a matrix of the call, None for a gate that is not there
+    return dx, dcoef[:n_pairs], *([None] * (wg is None)), *dw_in, dwd
 
 
 # ------------------------------------------------------- the Pallas kernels
@@ -206,7 +244,10 @@ def grouped_supported(x, wg, wu, wd, n_pairs: int, chunk: int) -> bool:
     """Whether the kernels cover this call: widths in whole lane tiles,
     a dtype the MXU takes, one expert's matrices resident in VMEM, a
     block's rows listed in SMEM, and a TPU (or the tests' interpret
-    mode) to run them."""
+    mode) to run them. They are written for the gated form: an expert
+    without a gate (``wg`` None) takes the chunk loop."""
+    if wg is None:
+        return False
     n_experts, d, f = wg.shape
     if d % LANES or f % LANES:
         return False
@@ -606,23 +647,25 @@ def _expert_ffn_fwd(x, rows, coef, counts, wg, wu, wd, chunk, kernels):
 
 
 def _expert_ffn_bwd(chunk, kernels, residuals, dy):
-    x, rows, coef, counts, wg, wu, wd = residuals
-    dx, dcoef, dwg, dwu, dwd = _executor(kernels)[1](*residuals, dy, chunk)
+    x, rows, coef, counts, *ws = residuals
+    dx, dcoef, *dws = _executor(kernels)[1](*residuals, dy, chunk)
 
     def no_gradient(a):
         return np.zeros(a.shape, jax.dtypes.float0)
 
     return (dx.astype(x.dtype), no_gradient(rows), dcoef.astype(coef.dtype),
-            no_gradient(counts), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwd.astype(wd.dtype))
+            no_gradient(counts),
+            *(None if w is None else dw.astype(w.dtype)
+              for dw, w in zip(dws, ws)))
 
 
 _expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
 
 
 def expert_ffn(x, rows, coef, counts, wg, wu, wd, *, chunk: int = CHUNK):
-    """``y[r] = sum over held pairs (r, e) of coef * down_e(silu(gate_e
-    x[r]) * up_e x[r])`` as float32 ``[R, d]``.
+    """``y[r] = sum over held pairs (r, e) of coef * down_e(h_e(x[r]))``
+    as float32 ``[R, d]``, where ``h_e(x) = silu(gate_e x) * up_e x``, or
+    ``max(up_e x, 0)^2`` where ``wg`` is None.
 
     ``x`` [R, d] and the weights ``wg``, ``wu`` [E, d, f], ``wd``
     [E, f, d] in the compute dtype; ``rows`` int32 [P] and ``coef``

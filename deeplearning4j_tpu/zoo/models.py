@@ -14,6 +14,7 @@ MXU-native dtype policy.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax.numpy as jnp
@@ -302,6 +303,89 @@ def sdar_moe(seed: int = 42, n_layers: int = 48, n_experts: int = 128,
             # move 16 of 128 columns (IsFusibleUnalignedDUS, PR 31)
             first = np.asarray(p["Wr"])[:, :held]
             p["Wr"] = jnp.asarray(np.tile(first, (1, n_experts // held)))
+    return net
+
+
+NEMOTRON_H_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def nemotron_h(seed: int = 42, pattern: str = NEMOTRON_H_PATTERN,
+               n_experts: int = 128, experts_held: Optional[int] = None,
+               first_expert: int = 0, vocab_size: int = 131_072,
+               hidden: int = 2688, mamba_heads: int = 64,
+               mamba_head_dim: int = 64, n_groups: int = 8,
+               state_size: int = 128, conv_kernel: int = 4,
+               chunk: int = 128, n_heads: int = 32, n_kv_heads: int = 2,
+               head_dim: int = 128, expert_width: int = 1856,
+               shared_width: int = 3712, experts_per_token: int = 6,
+               routed_scale: float = 2.5, eps: float = 1e-5,
+               residual_depth: int = 52, learning_rate: float = 1e-5,
+               dtype: Optional[DtypePolicy] = None) -> MultiLayerNetwork:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (``model_type`` nemotron_h; the
+    defaults are its published config.json): a causal decoder of one
+    mixer a layer, each with a pre-norm and a residual, in the order of
+    ``pattern``: ``M`` a Mamba-2 mixer, ``*`` causal grouped-query
+    attention without rotation, ``E`` routed ``relu2`` experts under a
+    sigmoid router with a correction bias, renormalised and scaled,
+    beside a shared expert. Integer ids in, the next token as integer
+    labels out.
+
+    ``experts_held`` and ``first_expert`` give this chip's share of the
+    routed experts under expert parallelism (the router still scores all
+    ``n_experts``; the shared expert is whole on every chip);
+    ``vocab_size`` is the slice of the vocabulary held here.
+
+    Init, for every seed alike: matrices normal(0, 0.02) (the family's
+    ``initializer_range``), every mixer's output matrix divided by
+    ``sqrt(residual_depth)`` (``rescale_prenorm_residual`` at the
+    published depth, whatever ``pattern`` keeps of it), norm weights 1,
+    and the mixer's own ``A_log``, ``D``, ``dt_bias`` and convolution
+    (nn/layers/decoder.py ``Mamba2MixerLayer``). The embedding rows are
+    normal(0, 1), as ``sdar_moe``'s and for its reason: at 0.02 the
+    first mixer's output is several times its input, and rows that
+    share a history reach the first router looking alike."""
+    from deeplearning4j_tpu.nn.conf.layers_decoder import (
+        CausalAttention, Mamba2Mixer, RmsNorm, RoutedExperts,
+        TokenEmbedding, TokenOutput)
+    kinds = {
+        "M": lambda: Mamba2Mixer(
+            n_heads=mamba_heads, head_dim=mamba_head_dim, n_groups=n_groups,
+            state_size=state_size, conv_kernel=conv_kernel, chunk=chunk,
+            eps=eps),
+        "*": lambda: CausalAttention(
+            n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
+            eps=eps),
+        "E": lambda: RoutedExperts(
+            n_experts=n_experts, experts_per_token=experts_per_token,
+            expert_width=expert_width, experts_held=experts_held,
+            first_expert=first_expert, eps=eps, router="sigmoid",
+            routed_scale=routed_scale, expert_form="relu2",
+            shared_width=shared_width),
+    }
+    unknown = sorted(set(pattern) - set(kinds))
+    if unknown or not pattern:
+        raise ValueError(
+            f"nemotron_h: pattern {pattern!r} may hold 'M' (Mamba-2), '*' "
+            f"(attention) and 'E' (experts); it holds {unknown}")
+    b = (NeuralNetConfiguration.builder()
+         .seed(seed).updater(Adam(learning_rate)).dtype(dtype or BF16)
+         .weight_init({"type": "normal", "mean": 0.0, "std": 0.02})
+         .list()
+         .layer(TokenEmbedding(n_out=hidden, weight_init={
+             "type": "normal", "mean": 0.0, "std": 1.0})))
+    for kind in pattern:
+        b = b.layer(kinds[kind]())
+    conf = (b.layer(RmsNorm(eps=eps))
+            .layer(TokenOutput(n_out=vocab_size, activation="identity",
+                               causal=True))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    shrink = 1.0 / math.sqrt(residual_depth)
+    for p in net.params.values():
+        for leaf in ("W_out", "Wo", "Wd", "Ws_d"):
+            if leaf in p:
+                p[leaf] = p[leaf] * shrink
     return net
 
 

@@ -210,3 +210,42 @@ def test_grouped_expert_kernels_compile_at_the_bench_shape(one_chip, x32):
     text = jax.jit(jax.grad(loss, (0, 2, 4, 5, 6))).lower(*args).compile(
         ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_hybrid_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
+    """What the cell ``nemotron3_nano_30b_a3b-train-b1-l4096`` asks of
+    the chip's compilers at its own shape: the tiled attention kernels
+    under the causal tables (4,096 rows, 32 query heads on 2 key/value
+    heads of 128: 16 heads a group, 2,048 rows a tile against 512 keys,
+    within the 64 MB VMEM limit), and the chunked recurrence with its
+    backward (64 heads of 64, state 128, 8 groups, 32 chunks of 128),
+    whatever executor the registry gives it."""
+    from deeplearning4j_tpu.ops import attention as att
+    from deeplearning4j_tpu.ops import ssm
+
+    seq, cd = 4096, jnp.bfloat16
+    q, k, v = (jax.ShapeDtypeStruct((1, seq, h, 128), cd, sharding=one_chip)
+               for h in (32, 2, 2))
+
+    def attend(q, k, v):
+        out = att._bd_join(att._causal_tiled(*att._bd_split(q, k, v)), 1)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # live tiles only: 144 of the 256 tile pairs of 128 x 512
+    assert len(att._causal_live_tiles(seq, 128, 512)) == 144
+
+    shapes = [((1, seq, 64, 64), cd), ((1, seq, 64), jnp.float32),
+              ((64,), jnp.float32), ((1, seq, 8, 128), cd),
+              ((1, seq, 8, 128), cd), ((64,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+
+    def scan(*a):
+        return jnp.sum(ssm.ssm_scan(*a, chunk=128))
+
+    compiled = jax.jit(jax.grad(scan, tuple(range(6)))).lower(*args).compile()
+    # the backward holds one layer's decay matrices and chunk states, not
+    # a gigabyte: [64, 32, 128, 128] float32 is 134 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
