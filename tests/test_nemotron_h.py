@@ -131,15 +131,16 @@ def test_gradient_of_every_parameter_group(gradients, layer, name):
 
 
 # ---------------------------------------------------------- the recurrence
-def _scan_args(length, seed=0, dt_scale=1.0):
+def _scan_args(length, seed=0, dt_scale=1.0, sizes=(2, 4, 8, 2, 16),
+               dtype=jnp.float32):
     k = jax.random.split(jax.random.PRNGKey(seed), 6)
-    bs, heads, p, groups, n = 2, 4, 8, 2, 16
+    bs, heads, p, groups, n = sizes
     normal = lambda key, shape: jax.random.normal(key, shape, jnp.float32)
-    return (normal(k[0], (bs, length, heads, p)),
+    return (normal(k[0], (bs, length, heads, p)).astype(dtype),
             jax.nn.softplus(normal(k[1], (bs, length, heads))) * dt_scale,
             -jnp.exp(normal(k[2], (heads,))),
-            normal(k[3], (bs, length, groups, n)),
-            normal(k[4], (bs, length, groups, n)),
+            normal(k[3], (bs, length, groups, n)).astype(dtype),
+            normal(k[4], (bs, length, groups, n)).astype(dtype),
             normal(k[5], (heads,)))
 
 
@@ -150,29 +151,119 @@ def _one_position_at_a_time(x, dt, a, b, c, d):
         jnp.repeat(c[i], r, axis=1), d) for i in range(x.shape[0])])
 
 
+def _value_and_grads(fn, args, seed=7):
+    g = jax.random.normal(jax.random.PRNGKey(seed), args[0].shape,
+                          jnp.float32)
+    return jax.jit(jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * g),
+                                      tuple(range(6))))(*args)
+
+
+# the smallest shapes the kernels take: a pair of heads of 64 a group, two
+# groups, a state of one lane tile, chunks of 128
+KERNEL_SIZES = (1, 4, 64, 2, 128)
+EXECUTORS = {"xla": (8, (2, 4, 8, 2, 16)), "pallas": (128, KERNEL_SIZES)}
+
+
+@pytest.mark.parametrize("executor", sorted(EXECUTORS))
 @pytest.mark.parametrize("chunks", [1, 2, 5])
 @pytest.mark.parametrize("dt_scale", [1.0, 40.0], ids=["dt_1", "dt_40"])
-def test_the_chunked_recurrence_is_the_sequential_one(chunks, dt_scale):
-    """Forward and the gradients in x, dt, A, B, C (and D). At ``dt`` of
-    40 a chunk's decay ``exp(sum dt A)`` underflows to 0 in float32: the
-    decay matrix is made of masked differences, so it holds zeros and
-    the gradients stay finite."""
-    args = _scan_args(8 * chunks, dt_scale=dt_scale)
+def test_the_chunked_recurrence_is_the_sequential_one(monkeypatch, executor,
+                                                      chunks, dt_scale):
+    """Forward and the gradients in x, dt, A, B, C (and D), by the xla
+    executor at toy sizes and by the kernels (interpreted) at the
+    smallest shapes they take (``dt`` scaled so that a chunk of 128
+    decays as the chunk of 8 does). At ``dt`` of 40 a chunk's decay
+    ``exp(sum dt A)`` underflows to 0 in float32: the decay matrix is
+    made of masked differences, so it holds zeros and the gradients stay
+    finite."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    chunk, sizes = EXECUTORS[executor]
+    args = _scan_args(chunk * chunks, dt_scale=dt_scale * 8 / chunk,
+                      sizes=sizes)
+    assert ssm.ssm_scan_supported(*args, chunk) == (executor == "pallas")
     if dt_scale > 1:
-        assert float(jnp.exp(jnp.sum(args[1][:, :8] * args[2],
+        assert float(jnp.exp(jnp.sum(args[1][:, :chunk] * args[2],
                                      axis=1)).min()) == 0.0
-    g = jax.random.normal(jax.random.PRNGKey(7), args[0].shape, jnp.float32)
-
-    def value_and_grads(fn):
-        return jax.jit(jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * g),
-                                          tuple(range(6))))(*args)
-
-    got = value_and_grads(lambda *a: ssm.ssm_scan(*a, chunk=8))
-    want = value_and_grads(_one_position_at_a_time)
+    before = {d: _count("dl4j_ssm_scan_calls_total", direction=d,
+                        backend=executor) for d in ("forward", "backward")}
+    got = _value_and_grads(lambda *a: ssm.ssm_scan(*a, chunk=chunk), args)
+    want = _value_and_grads(_one_position_at_a_time, args)
+    for direction, was in before.items():
+        assert _count("dl4j_ssm_scan_calls_total", direction=direction,
+                      backend=executor) == was + 1
     _close(got[0], want[0], rtol=1e-4)
     for a, b in zip(got[1], want[1]):
         assert np.all(np.isfinite(np.asarray(a)))
         _close(a, b, rtol=2e-4)
+
+
+@pytest.mark.parametrize("dtype,rtol", [(jnp.float32, 2e-4),
+                                        (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sizes,dt_scale", [
+    ((2, 4, 64, 2, 128), 0.05),      # a pair of heads a lane tile
+    ((2, 4, 64, 2, 128), 2.5),       # a chunk's decay underflows to zeros
+    ((1, 4, 128, 2, 256), 0.05),     # a head a lane tile, a state of two
+], ids=["pair", "pair-dt_large", "head_128"])
+def test_the_kernels_are_the_chunked_form(monkeypatch, dtype, rtol, sizes,
+                                          dt_scale):
+    """The two kernels against ``_chunked`` and its autodiff, all six
+    gradients, over two groups and three chunks (the carried state and
+    its gradient cross two boundaries). With float32 operands the two
+    order the same sums differently; with bfloat16 the chunked form's
+    autodiff rounds the gradient of a rounded operand to bfloat16 and
+    the kernel keeps it float32, so they agree to bfloat16's step."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    args = _scan_args(3 * 128, dt_scale=dt_scale, sizes=sizes, dtype=dtype)
+    assert ssm.ssm_scan_supported(*args, 128)
+    if dt_scale > 1:
+        assert float(jnp.exp(jnp.sum(args[1][:, :128] * args[2],
+                                     axis=1)).min()) == 0.0
+    got = _value_and_grads(lambda *a: ssm.ssm_scan(*a, chunk=128), args)
+    want = _value_and_grads(lambda *a: ssm._chunked(*a, 128), args)
+    _close(got[0], want[0], rtol=rtol)
+    for a, b, like in zip(got[1], want[1], args):
+        assert a.dtype == like.dtype and a.shape == like.shape
+        assert np.all(np.isfinite(np.asarray(a, np.float32)))
+        _close(a, b, rtol=rtol)
+
+
+REFUSALS = {
+    "no_tpu_no_interpreter": dict(interpret="0"),
+    "chunk_of_64": dict(chunk=64),
+    "odd_heads_a_group": dict(sizes=(1, 6, 64, 2, 128)),
+    "head_width_32": dict(sizes=(1, 8, 32, 2, 128)),
+    "state_of_64": dict(sizes=(1, 4, 64, 2, 64)),
+    "a_group_of_2048_lanes": dict(sizes=(1, 32, 64, 1, 128)),
+    "float32_B_beside_bfloat16_x": dict(mixed=True),
+}
+
+
+@pytest.mark.parametrize("why", sorted(REFUSALS))
+def test_what_the_kernels_refuse_runs_on_xla(monkeypatch, why):
+    """Each refusal of ``ssm_scan_supported`` lands on the xla executor,
+    silently, and the counter says so."""
+    case = dict(interpret="1", chunk=128, sizes=KERNEL_SIZES, mixed=False)
+    case.update(REFUSALS[why])
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", case["interpret"])
+    args = list(_scan_args(128, sizes=case["sizes"]))
+    if case["mixed"]:
+        args[0] = args[0].astype(jnp.bfloat16)
+    chunk = case["chunk"]
+    assert not ssm.ssm_scan_supported(*args, chunk)
+
+    def counts():
+        return {(d, b): _count("dl4j_ssm_scan_calls_total", direction=d,
+                               backend=b)
+                for d in ("forward", "backward") for b in ("xla", "pallas")}
+
+    before = counts()
+    got = _value_and_grads(lambda *a: ssm.ssm_scan(*a, chunk=chunk), args)
+    assert counts() == {k: v + (k[1] == "xla") for k, v in before.items()}
+    want = _value_and_grads(lambda *a: ssm._chunked(*a, chunk), args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        _close(a, b)
 
 
 def test_a_sequence_that_is_no_whole_chunks_is_refused():

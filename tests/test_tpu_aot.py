@@ -217,9 +217,11 @@ def test_hybrid_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     the chip's compilers at its own shape: the tiled attention kernels
     under the causal tables (4,096 rows, 32 query heads on 2 key/value
     heads of 128: 16 heads a group, 2,048 rows a tile against 512 keys,
-    within the 64 MB VMEM limit), and the chunked recurrence with its
-    backward (64 heads of 64, state 128, 8 groups, 32 chunks of 128),
-    whatever executor the registry gives it."""
+    within the 64 MB VMEM limit), and the two kernels of the chunked
+    recurrence (64 heads of 64 in pairs, state 128, 8 groups, 32 chunks
+    of 128: a step is a group's chunk, its decay tiles never leave
+    VMEM). The registry would hand this CPU process the xla executor, so
+    the kernels' entry is compiled itself."""
     from deeplearning4j_tpu.ops import attention as att
     from deeplearning4j_tpu.ops import ssm
 
@@ -237,15 +239,30 @@ def test_hybrid_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     # live tiles only: 144 of the 256 tile pairs of 128 x 512
     assert len(att._causal_live_tiles(seq, 128, 512)) == 144
 
-    shapes = [((1, seq, 64, 64), cd), ((1, seq, 64), jnp.float32),
-              ((64,), jnp.float32), ((1, seq, 8, 128), cd),
-              ((1, seq, 8, 128), cd), ((64,), jnp.float32)]
-    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    def scan_args(rows, heads, p):
+        shapes = [((1, rows, heads, p), cd), ((1, rows, heads), jnp.float32),
+                  ((heads,), jnp.float32), ((1, rows, 8, 128), cd),
+                  ((1, rows, 8, 128), cd), ((heads,), jnp.float32)]
+        return [jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+                for s, t in shapes]
+
+    args = scan_args(seq, 64, 64)
+    assert ssm.ssm_scan_supported(*args, 128) is False      # no TPU here
 
     def scan(*a):
-        return jnp.sum(ssm.ssm_scan(*a, chunk=128))
+        return jnp.sum(ssm._tiled(*a, 128))
+
+    # a head a lane tile takes other lines of the kernels: Mosaic
+    # spreads no [1, 1] value over a tile
+    wide = jax.jit(jax.grad(scan, tuple(range(6)))).lower(
+        *scan_args(512, 32, 128)).compile()
+    assert wide.as_text().count('custom_call_target="tpu_custom_call"') == 2
 
     compiled = jax.jit(jax.grad(scan, tuple(range(6)))).lower(*args).compile()
-    # the backward holds one layer's decay matrices and chunk states, not
-    # a gigabyte: [64, 32, 128, 128] float32 is 134 MB
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+    # 135 MB as compiled: the states before every chunk that the forward
+    # saves (67 MB) and the float32 gradient this sum hands the backward
+    # (67 MB); the xla executor's decay matrices and their products held
+    # several hundred
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e8
