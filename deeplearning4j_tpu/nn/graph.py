@@ -21,6 +21,7 @@ import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.observability import opindex as _opindex
+from deeplearning4j_tpu.observability.trace import get_tracer
 from deeplearning4j_tpu.nn.conf.graph_conf import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.layers import BaseLayerConfig
 from deeplearning4j_tpu.nn.trainer import (
@@ -41,7 +42,6 @@ class ComputationGraph(Trainer):
     def init(self, seed: Optional[int] = None, *, structure_only: bool = False):
         gc = self.conf.global_conf
         seed = gc.seed if seed is None else seed
-        self._rng_key = jax.random.PRNGKey(seed)
 
         # resolve InputTypes through the DAG
         input_types: Dict[str, object] = {}
@@ -92,7 +92,7 @@ class ComputationGraph(Trainer):
             default_activation=gc.activation or "sigmoid")
         self._fusion_interior = _fusion.interior_vertices(self._fusion_plans)
 
-        self._init_trees(structure_only)
+        self._init_trees(seed, structure_only)
         return self
 
     # -------------------------------------------------- selective remat
@@ -503,15 +503,23 @@ class ComputationGraph(Trainer):
         self._require_init()
         feats = [jnp.asarray(f) for f in features]
         key = ("out", train, masks is not None)
-        if key not in self._apply_fns:
+
+        def build():
             def fn(params, state, inputs, fmasks):
                 acts, _, _, _ = self._walk(params, state, inputs, train=train,
                                            rng=None, fmasks=fmasks)
                 return tuple(acts[o] for o in self.conf.network_outputs)
             self._apply_fns[key] = jax.jit(fn)
+            return self._apply_fns[key]
+
         inputs, fmasks = self._prepare_inputs(
             feats, masks if masks is not None else None)
-        outs = self._apply_fns[key](self.params, self.state, inputs, fmasks)
+        shapes = tuple((f.shape, f.dtype) for f in feats)
+        if masks is not None:
+            shapes += tuple(None if m is None else jnp.shape(m)
+                            for m in masks)
+        outs = self._first_forward(key, shapes, build, self.params,
+                                   self.state, inputs, fmasks)
         return outs[0] if len(outs) == 1 else outs
 
     def feed_forward(self, *features, masks=None, train: bool = False):
@@ -519,8 +527,10 @@ class ComputationGraph(Trainer):
         self._require_init()
         feats = [jnp.asarray(f) for f in features]
         inputs, fmasks = self._prepare_inputs(feats, masks)
-        acts, _, _, _ = self._walk(self.params, self.state, inputs,
-                                   train=train, rng=None, fmasks=fmasks)
+        # an eager walk: every op of a new shape is a program of its own
+        with get_tracer().span("forward"):
+            acts, _, _, _ = self._walk(self.params, self.state, inputs,
+                                       train=train, rng=None, fmasks=fmasks)
         return acts
 
     def score(self, mds, train: bool = False):

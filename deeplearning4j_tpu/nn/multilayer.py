@@ -74,7 +74,6 @@ class MultiLayerNetwork(Trainer):
         — used by clone()/restore, which overwrite every leaf anyway."""
         gc = self.conf.global_conf
         seed = gc.seed if seed is None else seed
-        self._rng_key = jax.random.PRNGKey(seed)
 
         input_type = self.conf.input_type
         self.layers = []
@@ -100,7 +99,7 @@ class MultiLayerNetwork(Trainer):
             self.layers.append(layer)
             input_type = layer.output_type
         self._resolved_confs = resolved_confs
-        self._init_trees(structure_only)
+        self._init_trees(seed, structure_only)
         return self
 
     # -------------------------------------------------------------- forward
@@ -370,23 +369,26 @@ class MultiLayerNetwork(Trainer):
         self._rng_key, rng = jax.random.split(self._rng_key)
         return rng
 
+    def _apply(self, collect, train, x, mask):
+        x = jnp.asarray(x)
+        mask = None if mask is None else jnp.asarray(mask)
+        return self._first_forward(
+            (collect, train),
+            (x.shape, x.dtype, None if mask is None else mask.shape),
+            lambda: self._get_apply(collect, train),
+            self.params, self.state, x, self._inference_rng(train), mask)
+
     def output(self, x, train: bool = False, mask=None):
         """Forward pass -> final layer activations
         (MultiLayerNetwork.output :1512). ``mask`` is the per-timestep
         features mask for variable-length sequences."""
         self._require_init()
-        fn = self._get_apply(collect=False, train=train)
-        return fn(self.params, self.state, jnp.asarray(x),
-                  self._inference_rng(train),
-                  None if mask is None else jnp.asarray(mask))
+        return self._apply(False, train, x, mask)
 
     def feed_forward(self, x, train: bool = False, mask=None) -> List[jnp.ndarray]:
         """All layer activations (feedForward :675)."""
         self._require_init()
-        fn = self._get_apply(collect=True, train=train)
-        return fn(self.params, self.state, jnp.asarray(x),
-                  self._inference_rng(train),
-                  None if mask is None else jnp.asarray(mask))
+        return self._apply(True, train, x, mask)
 
     def score(self, ds: DataSet, train: bool = False):
         """Loss on one dataset (MultiLayerNetwork.score parity)."""

@@ -95,6 +95,7 @@ class Trainer:
         self._tbptt_step = None
         self._multi_steps = {}
         self._apply_fns = {}
+        self._forward_seen = set()  # (id(fn), input shapes) traced already
         self._mesh = None
         self._rng_key = None
         self._rnn_state = None
@@ -107,11 +108,12 @@ class Trainer:
         self._lr_scale = 1.0
 
     # ----------------------------------------------------- trees and caches
-    def _init_trees(self, structure_only: bool):
-        """Parameter, state and optimizer pytrees of ``self.layers`` from
-        ``self._rng_key`` — ShapeDtypeStructs (via jax.eval_shape) with
-        ``structure_only``, which clone()/restore use because they
-        overwrite every leaf anyway."""
+    def _init_trees(self, seed: int, structure_only: bool):
+        """``self._rng_key`` from ``seed``, and from it the parameter,
+        state and optimizer pytrees of ``self.layers`` —
+        ShapeDtypeStructs (via jax.eval_shape) with ``structure_only``,
+        which clone()/restore use because they overwrite every leaf
+        anyway."""
         def init_trees(key):
             params, state = {}, {}
             for layer in self.layers:
@@ -124,11 +126,16 @@ class Trainer:
                     state[layer.name] = s
             return params, state, self._fresh_opt_state(params)
 
-        if structure_only:
-            self.params, self.state, self.opt_state = jax.eval_shape(
-                init_trees, self._rng_key)
-        else:
-            self.params, self.state, self.opt_state = init_trees(self._rng_key)
+        # eager: a sub-second program for the key and for every distinct
+        # initializer call
+        with _get_tracer().program_span("net_init"):
+            self._rng_key = jax.random.PRNGKey(seed)
+            if structure_only:
+                self.params, self.state, self.opt_state = jax.eval_shape(
+                    init_trees, self._rng_key)
+            else:
+                self.params, self.state, self.opt_state = init_trees(
+                    self._rng_key)
         self.iteration = 0
         self._drop_compiled(forward_too=True)
 
@@ -151,6 +158,7 @@ class Trainer:
         self._multi_steps = {}
         if forward_too:
             self._apply_fns = {}
+            self._forward_seen = set()
             self._rnn_state = None
 
     def materialize_state(self):
@@ -167,6 +175,20 @@ class Trainer:
         """Fresh optimizer state from (concrete) params — used after a
         structure-only init when the updater state isn't being restored."""
         self.opt_state = self._fresh_opt_state(self.params)
+
+    def _first_forward(self, key, shapes, build, *args):
+        """``self._apply_fns[key](*args)``; ``build()`` makes and returns
+        that entry on first use. The first call with inputs of these
+        ``shapes`` traces, lowers and compiles the forward, so it runs
+        under the span ``forward``; a later one pays a set lookup."""
+        fn = self._apply_fns.get(key)
+        if fn is not None and (id(fn), shapes) in self._forward_seen:
+            return fn(*args)
+        with _get_tracer().program_span("forward"):
+            if fn is None:
+                fn = build()
+            self._forward_seen.add((id(fn), shapes))
+            return fn(*args)
 
     def _require_init(self):
         if self.params is None:
@@ -336,14 +358,16 @@ class Trainer:
         wiring; DL4J_TPU_AUTO_FLOPS=0 opts out."""
         if not _goodput.auto_flops_enabled():
             return
+        if self._train_step is None:
+            # the key below names the step, so build it first: the
+            # chunked path has not
+            self._train_step = self._build_train_step()
         key = (id(self._train_step), jax.tree_util.tree_map(np.shape, batch))
         if getattr(self, "_flops_key", None) == key:
             return
         self._flops_key = key
-        with _get_tracer().span("flops_derive"):
+        with _get_tracer().program_span("flops_derive"):
             try:
-                if self._train_step is None:
-                    self._train_step = self._build_train_step()
                 from deeplearning4j_tpu.utils.perf import (
                     xla_step_cost_lowered,
                 )
@@ -637,9 +661,11 @@ class Trainer:
             _opindex.register(jitted, args, args[6])
             (self.params, self.state, self.opt_state, self._rng_key,
              scores) = jitted(*args)
+            # inside the span: the first slice of each chunk length
+            # compiles (a dynamic_slice and a squeeze)
+            self.score_value = scores[-1]
         start = self.iteration
         self.iteration += len(batches)
-        self.score_value = scores[-1]
         self.last_batch_examples = batches[-1].num_examples
         _goodput.observe_steps(len(batches))  # one dispatch, k real steps
         # pre-stack arrays already have the per-step shape; slicing the

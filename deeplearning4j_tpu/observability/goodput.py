@@ -161,6 +161,9 @@ class RunReport:
     # no cache dir is configured
     xla_cache_hits: int = 0
     xla_cache_misses: int = 0
+    # where the run's program-making seconds went (metrics.stage_delta):
+    # {"trace", "lower", "cache_load", "compile"} -> seconds, every owner
+    xla_stage_seconds: Dict[str, float] = field(default_factory=dict)
     # cold-start attribution, annotated by the serving runtime:
     # process start -> first successful reply, and the warm-up ladder's
     # wall time (None outside serving / before the first reply)
@@ -205,6 +208,7 @@ class RunReport:
             "compile_seconds": self.compile_seconds,
             "xla_cache_hits": self.xla_cache_hits,
             "xla_cache_misses": self.xla_cache_misses,
+            "xla_stage_seconds": self.xla_stage_seconds,
             "cold_start_s": self.cold_start_s,
             "warmup_s": self.warmup_s,
             "device_memory_peak_bytes": self.device_memory_peak_bytes,
@@ -276,6 +280,7 @@ class EfficiencyLedger:
         # seam); start_run overwrites this with a live snapshot
         self._compile0 = {"count": 0, "seconds": 0.0,
                           "cache_hits": 0, "cache_misses": 0}
+        self._stage0: dict = {}     # metrics.stage_snapshot, the same way
         self._annotations: Dict[str, object] = {}
         self._dropped0 = 0
         self._closed = False
@@ -323,13 +328,16 @@ class EfficiencyLedger:
         with self._lock:
             self._annotations.update(fields)
 
-    def rebase_compile(self, snapshot: dict) -> None:
+    def rebase_compile(self, snapshot: dict, stages: dict) -> None:
         """Move the compile/cache baseline back to *snapshot* (an
         earlier ``metrics.compile_snapshot()``), so compiles that ran
         before ``start_run`` — e.g. the server's warm-up ladder — are
-        charged to this run's report."""
+        charged to this run's report; *stages* (a
+        ``metrics.stage_snapshot()`` of the same moment) does the same
+        for ``xla_stage_seconds``."""
         with self._lock:
             self._compile0 = dict(snapshot)
+            self._stage0 = stages
 
     # ---------------------------------------------------------------- views
     @property
@@ -378,6 +386,7 @@ class EfficiencyLedger:
         from deeplearning4j_tpu.observability import metrics as _m
         wall = time.perf_counter() - self._t0
         compile_run = _m.compile_delta(self._compile0)
+        stage_run = _m.stage_delta(self._stage0)["seconds"]
         live = self.live()
         with self._lock:
             attributed = self._attributed_s
@@ -417,6 +426,9 @@ class EfficiencyLedger:
             compile_seconds=compile_run["seconds"],
             xla_cache_hits=compile_run["cache_hits"],
             xla_cache_misses=compile_run["cache_misses"],
+            xla_stage_seconds={
+                stage: round(sum(owners.values()), 6)
+                for stage, owners in sorted(stage_run.items())},
             device_memory_peak_bytes=_m.memory_watermark_bytes(),
             padding=live["padding"],
             trace_dropped_spans=dropped,
@@ -448,7 +460,7 @@ class _NullLedger:
     def annotate(self, **fields):
         pass
 
-    def rebase_compile(self, snapshot):
+    def rebase_compile(self, snapshot, stages):
         pass
 
     def live(self):
@@ -478,6 +490,7 @@ def start_run(kind: str, net=None):
     from deeplearning4j_tpu.observability.trace import get_tracer
     ledger = EfficiencyLedger(kind)
     ledger._compile0 = _m.compile_snapshot()
+    ledger._stage0 = _m.stage_snapshot()
     _m.update_memory_watermark()
     tracer = get_tracer()
     ledger._tracer = tracer

@@ -29,6 +29,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from deeplearning4j_tpu.observability import trace as _trace
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricFamily", "MetricsRegistry",
     "Sample", "get_registry", "set_registry", "sample_key",
@@ -433,11 +435,89 @@ _MEM_PEAK: dict = {}
 RESERVED_SUFFIX = "+reserved"
 
 
+# The stage account: every second jax spends making a program, booked
+# to a stage, to the span that caused it and to the program. jax wraps
+# tracing, lowering and backend compilation in one context manager
+# (jax/_src/dispatch.py log_elapsed_time) that sends a scalar event with
+# the unix start time on entry and a duration event on exit, both on the
+# calling thread, with the program's name as fun_name.
+_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # "cache_load" instead where a /cache_hits event came inside it
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_STAGE_SECONDS: Dict[Tuple[str, str], float] = {}     # (stage, owner)
+_STAGE_PROGRAMS: Dict[Tuple[str, str], int] = {}      # (stage, owner)
+_PROGRAM_SECONDS: Dict[Tuple[str, str], dict] = {}    # (program, owner)
+_PROGRAM_SPANS: Dict[str, list] = {}    # span name -> [seconds, count]
+
+
+class _OpenStages(threading.local):
+    """The stage intervals open on this thread that will be booked:
+    ``stack`` holds ``[stage, program, start_unix, compile seconds
+    inside, cache hit]``, at most one trace or lowering (the outermost)
+    and one backend compile inside it; ``nested`` counts the tracings
+    and lowerings open inside the outermost one."""
+
+    def __init__(self):
+        self.stack = []
+        self.nested = 0
+
+
+_OPEN_STAGES = _OpenStages()
+
+
+def _on_jax_scalar(event: str, value, **kw):
+    stage = _STAGE_OF_EVENT.get(event)
+    if stage is None:
+        return
+    open_ = _OPEN_STAGES
+    if open_.stack and stage != "compile":
+        # an inner jit of a traced function reports a trace of its own,
+        # and a lowering rule traces: the outermost interval holds both
+        open_.nested += 1
+    else:
+        open_.stack.append([stage, kw.get("fun_name", ""), value, 0.0, False])
+
+
 def _on_jax_event_duration(event: str, duration: float, **kw):
-    if event.endswith("backend_compile_duration"):
+    stage = _STAGE_OF_EVENT.get(event)
+    if stage is None:
+        return
+    open_ = _OPEN_STAGES
+    stack = open_.stack
+    if stage == "compile":
         with _runtime_lock:
             _COMPILE["count"] += 1
             _COMPILE["seconds"] += duration
+    elif open_.nested:
+        open_.nested -= 1
+        return
+    if stack and stack[-1][0] == stage:
+        _, program, start, inside, hit = stack.pop()
+    else:   # the listener came on inside this interval
+        program, start, inside, hit = (
+            kw.get("fun_name", ""), time.time() - duration, 0.0, False)
+    if stack:
+        # a compile while something is traced (an eager op on concrete
+        # values): its seconds are the compile's, not the tracing's too
+        stack[0][3] += duration
+    if hit:
+        stage = "cache_load"
+    if program.endswith(")"):   # "jit(multi)" where tracing said "multi"
+        program = program[program.find("(") + 1:-1]
+    seconds = max(0.0, duration - inside)
+    owner = _trace.current_span()
+    key = (stage, owner or "none")
+    with _runtime_lock:
+        _STAGE_SECONDS[key] = _STAGE_SECONDS.get(key, 0.0) + seconds
+        _STAGE_PROGRAMS[key] = _STAGE_PROGRAMS.get(key, 0) + 1
+        by_stage = _PROGRAM_SECONDS.setdefault((program, key[1]), {})
+        by_stage[stage] = by_stage.get(stage, 0.0) + seconds
+    _trace.get_tracer().record_unix(
+        "xla_" + stage, start, start + duration, {"program": program},
+        parent=owner)
 
 
 def _on_jax_event(event: str, **kw):
@@ -447,9 +527,21 @@ def _on_jax_event(event: str, **kw):
     if event.endswith("/cache_hits"):
         with _runtime_lock:
             _CACHE["hits"] += 1
+        stack = _OPEN_STAGES.stack
+        if stack and stack[-1][0] == "compile":
+            stack[-1][4] = True
     elif event.endswith("/cache_misses"):
         with _runtime_lock:
             _CACHE["misses"] += 1
+
+
+def observe_program_span(name: str, seconds: float) -> None:
+    """A set-up span closed (``Tracer.program_span``): its seconds join
+    the account, beside the stage seconds booked to it as owner."""
+    with _runtime_lock:
+        ent = _PROGRAM_SPANS.setdefault(name, [0.0, 0])
+        ent[0] += seconds
+        ent[1] += 1
 
 
 def _ensure_compile_listener():
@@ -461,6 +553,7 @@ def _ensure_compile_listener():
         monitoring.register_event_duration_secs_listener(
             _on_jax_event_duration)
         monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_scalar_listener(_on_jax_scalar)
         _COMPILE_LISTENER_ON = True
     except Exception:
         pass
@@ -473,6 +566,8 @@ def _runtime_collector() -> List[MetricFamily]:
         cache_hits = _CACHE["hits"]
         cache_misses = _CACHE["misses"]
         steps = dict(_STEPS)
+        stage_seconds = dict(_STAGE_SECONDS)
+        stage_programs = dict(_STAGE_PROGRAMS)
     fams = [
         MetricFamily("dl4j_xla_compile_total", "counter",
                      "XLA backend compiles observed via jax.monitoring"
@@ -488,6 +583,16 @@ def _runtime_collector() -> List[MetricFamily]:
         MetricFamily("dl4j_xla_cache_misses_total", "counter",
                      "Fresh compiles written into the active persistent "
                      "compilation cache").add(cache_misses),
+        _stage_family("dl4j_xla_stage_seconds_total", stage_seconds,
+                      "Seconds jax spent making programs, by stage (trace, "
+                      "lower, cache_load: a backend compile the persistent "
+                      "cache held, compile: one it did not) and by owner: "
+                      "the innermost span open on the thread, or none. "
+                      "Each second is booked once: the outermost tracing "
+                      "or lowering holds what nests in it, less a compile"),
+        _stage_family("dl4j_xla_stage_programs_total", stage_programs,
+                      "Outermost intervals of each stage, by owner: for "
+                      "lower, cache_load and compile one a program"),
         MetricFamily("dl4j_fit_steps_total", "counter",
                      "Training steps dispatched by the fit loop"
                      ).add(steps["count"]),
@@ -556,6 +661,13 @@ def _runtime_collector() -> List[MetricFamily]:
         fams.append(peak_fam)
     fams.extend(_trace_drop_families())
     return fams
+
+
+def _stage_family(name: str, table: dict, help: str) -> MetricFamily:
+    fam = MetricFamily(name, "counter", help)
+    for (stage, owner), value in sorted(table.items()):
+        fam.add(value, {"stage": stage, "owner": owner})
+    return fam
 
 
 def _trace_drop_families() -> List[MetricFamily]:
@@ -761,6 +873,55 @@ def compile_delta(baseline: dict) -> dict:
     return {k: (round(now[k] - baseline.get(k, 0), 6)
                 if k == "seconds" else now[k] - baseline.get(k, 0))
             for k in now}
+
+
+def stage_snapshot() -> dict:
+    """The stage account since process start, and the baseline of
+    :func:`stage_delta`: ``seconds`` and ``programs`` as ``{stage:
+    {owner: value}}``, and ``spans`` as ``{name: {"seconds", "count"}}``
+    for the set-up spans (``Tracer.program_span``). Installs the
+    listener, as :func:`compile_snapshot` does."""
+    _ensure_compile_listener()
+    out = {"seconds": {}, "programs": {}}
+    with _runtime_lock:
+        for name, table in (("seconds", _STAGE_SECONDS),
+                            ("programs", _STAGE_PROGRAMS)):
+            for (stage, owner), value in table.items():
+                out[name].setdefault(stage, {})[owner] = value
+        out["spans"] = {name: {"seconds": ent[0], "count": ent[1]}
+                        for name, ent in _PROGRAM_SPANS.items()}
+    return out
+
+
+def largest_programs(n: int = 16) -> List[dict]:
+    """The ``n`` largest (program, owner) pairs of the stage account by
+    seconds, each ``{"program", "owner", "seconds", <stage>: seconds}``:
+    which program, without a label whose values nobody bounds."""
+    with _runtime_lock:
+        programs = [{"program": program, "owner": owner,
+                     "seconds": sum(by_stage.values()), **by_stage}
+                    for (program, owner), by_stage
+                    in _PROGRAM_SECONDS.items()]
+    programs.sort(key=lambda p: -p["seconds"])
+    return programs[:n]
+
+
+def stage_delta(baseline: dict) -> dict:
+    """Stage seconds, programs and set-up span totals since *baseline* (a
+    :func:`stage_snapshot`), in the snapshot's shape. A run's own share
+    of the process-cumulative account."""
+    now = stage_snapshot()
+    out = {}
+    for name in ("seconds", "programs"):
+        out[name] = {
+            stage: {owner: value - baseline.get(name, {}).get(stage, {})
+                    .get(owner, 0) for owner, value in owners.items()}
+            for stage, owners in now[name].items()}
+    before = baseline.get("spans", {})
+    out["spans"] = {
+        name: {k: ent[k] - before.get(name, {}).get(k, 0) for k in ent}
+        for name, ent in now["spans"].items()}
+    return out
 
 
 def process_start_unix() -> float:

@@ -18,11 +18,14 @@ entry. See OBSERVABILITY.md, "Which layer is a device op?".
 from __future__ import annotations
 
 import re
+import time
 import weakref
 from collections import Counter
 from typing import Optional
 
 import jax
+
+from deeplearning4j_tpu.observability.trace import get_tracer
 
 __all__ = ["scope", "scope_name", "register", "lookup", "parse", "place",
            "contains", "PHASES"]
@@ -85,8 +88,12 @@ def lookup(module_name: str) -> Optional[dict]:
     if jitted is None:
         return None
     if program.index is None:
-        program.index = parse(
-            jitted.lower(*program.specs).compile().as_text())
+        with get_tracer().program_span("opindex_lookup",
+                                       module=module_name) as sp:
+            text = jitted.lower(*program.specs).compile().as_text()
+            t0 = time.perf_counter()
+            program.index = parse(text)
+            sp.set(parse_s=round(time.perf_counter() - t0, 6))
     return program.index
 
 

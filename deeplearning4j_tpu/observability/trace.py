@@ -35,6 +35,14 @@ Span taxonomy (OBSERVABILITY.md has the full table):
 - distributed phases (parallel/stats.py): ``fit``, ``average``,
   ``checkpoint_barrier`` (the TrainingStatsCollector feeds the same
   tracer, so Spark-tier phases land in the same timeline)
+- set-up (``Tracer.program_span``): ``net_init``, ``forward``,
+  ``flops_derive``, ``opindex_lookup``; and under whichever span caused
+  them ``xla_trace``, ``xla_lower``, ``xla_cache_load``,
+  ``xla_compile``, one per program, recorded by the stage account of
+  observability/metrics.py with the program's name
+
+Every span carries ``parent``: the name of the span that was open on
+the same thread when it began (None at the top).
 """
 
 from __future__ import annotations
@@ -48,7 +56,7 @@ from typing import List, NamedTuple, Optional, Sequence
 
 __all__ = [
     "Span", "Tracer", "get_tracer", "set_tracer", "span", "trace_span",
-    "trace_timeline_component",
+    "trace_timeline_component", "current_span",
 ]
 
 
@@ -62,6 +70,7 @@ class Span(NamedTuple):
     tid: int
     thread: str
     attrs: Optional[dict]
+    parent: Optional[str] = None    # the span open on this thread at its start
 
     def to_dict(self) -> dict:
         d = {"name": self.name, "ts_us": round(self.ts_us, 3),
@@ -69,14 +78,35 @@ class Span(NamedTuple):
              "thread": self.thread}
         if self.attrs:
             d["attrs"] = self.attrs
+        if self.parent:
+            d["parent"] = self.parent
         return d
+
+
+class _Open(threading.local):
+    """The spans open on this thread (any tracer), innermost last."""
+
+    def __init__(self):
+        self.stack = []
+
+
+_OPEN = _Open()
+
+
+def current_span() -> Optional[str]:
+    """Name of the innermost span open on the calling thread, or None:
+    the parent of a span that begins now, and the owner the stage
+    account (observability/metrics.py) books a trace or a compile to."""
+    stack = _OPEN.stack
+    return stack[-1]._name if stack else None
 
 
 class _SpanCtx:
     """Hand-rolled context manager: ~2x cheaper than
     ``@contextmanager`` on the per-step hot path."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann", "_parent",
+                 "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, attrs):
         self._tracer = tracer
@@ -84,26 +114,59 @@ class _SpanCtx:
         self._attrs = attrs
         self._ann = None
 
+    def set(self, **attrs):
+        """Attributes known only inside the span (a part's own seconds)."""
+        self._attrs = {**(self._attrs or {}), **attrs}
+
     def __enter__(self):
         if self._tracer.annotate:
             import jax
             self._ann = jax.profiler.TraceAnnotation(self._name)
             self._ann.__enter__()
+        stack = self._stack = _OPEN.stack
+        self._parent = stack[-1]._name if stack else None
+        stack.append(self)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        stack = self._stack     # of the thread that opened it
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            # closed out of order: a generator that yielded inside the
+            # span and finished under a consumer's, or another thread
+            stack.remove(self)
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        self._tracer._record(self._name, self._t0, t1, self._attrs)
+        self._tracer._record(self._name, self._t0, t1, self._attrs,
+                             parent=self._parent)
         return False
+
+
+class _ProgramSpanCtx(_SpanCtx):
+    """A span round set-up work (``Tracer.program_span``): the stage
+    account keeps its seconds too, since the ring it is recorded on may
+    be gone by the time anyone asks what set-up cost."""
+
+    __slots__ = ()
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        from deeplearning4j_tpu.observability.metrics import (
+            observe_program_span)
+        observe_program_span(self._name, seconds)
+        return _SpanCtx.__exit__(self, *exc)
 
 
 class _NullCtx:
     """Returned by a disabled tracer — a shared no-op (no allocation)."""
 
     __slots__ = ()
+
+    def set(self, **attrs):
+        pass
 
     def __enter__(self):
         return self
@@ -152,15 +215,35 @@ class Tracer:
             return _NULL
         return _SpanCtx(self, name, attrs or None)
 
+    def program_span(self, name: str, **attrs):
+        """``span`` for set-up work that makes programs (``net_init``,
+        ``forward``, ``flops_derive``, ``opindex_lookup``): its seconds
+        are also added to the stage account's span totals
+        (``metrics.stage_snapshot()["spans"]``)."""
+        if not self.enabled:
+            return _NULL
+        return _ProgramSpanCtx(self, name, attrs or None)
+
     def record(self, name: str, t0: float, t1: float, attrs: dict = None,
-               tid: int = None, thread: str = None):
+               tid: int = None, thread: str = None, parent: str = None):
         """Record an explicitly-timed span (``perf_counter`` endpoints) —
         for spans whose start lives on another thread (e.g. a serving
-        ticket's ``queue_wait`` measured from its submit timestamp)."""
+        ticket's ``queue_wait`` measured from its submit timestamp).
+        ``parent`` names the span that caused it, where the caller knows."""
         if self.enabled:
-            self._record(name, t0, t1, attrs, tid, thread)
+            self._record(name, t0, t1, attrs, tid, thread, parent)
 
-    def _record(self, name, t0, t1, attrs, tid=None, thread=None):
+    def record_unix(self, name: str, start_unix: float, end_unix: float,
+                    attrs: dict = None, parent: str = None):
+        """``record`` for an interval timed on the unix clock (jax's
+        monitoring events), placed on this tracer's clock through
+        ``epoch_unix``."""
+        t0 = start_unix - self.epoch_unix() + self._epoch
+        self.record(name, t0, t0 + (end_unix - start_unix), attrs,
+                    parent=parent)
+
+    def _record(self, name, t0, t1, attrs, tid=None, thread=None,
+                parent=None):
         if tid is None:
             t = threading.current_thread()
             tid, thread = t.ident or 0, t.name
@@ -183,7 +266,7 @@ class Tracer:
                     self._dropped_by_name.get(evicted, 0) + 1
             span = Span(
                 name, (t0 - self._epoch) * 1e6, (t1 - t0) * 1e6,
-                tid, thread or "", attrs)
+                tid, thread or "", attrs, parent)
             self._ring.append(span)
             sinks = self._sinks
         for sink in sinks:
@@ -257,19 +340,31 @@ class Tracer:
         spans = self.spans()
         pid = os.getpid()
         events = []
-        threads = {}
+        # a lane is a thread ident AND a name: a thread that ends hands
+        # its ident to the next one started, which is another lane
+        lanes: dict = {}    # (ident, name) -> the export's tid
+        first: dict = {}    # ident -> the first name seen under it
+        spare = max((s.tid for s in spans), default=0)
         for s in spans:
-            threads.setdefault(s.tid, s.thread)
+            lane = lanes.get((s.tid, s.thread))
+            if lane is None:
+                lane = s.tid
+                if first.setdefault(s.tid, s.thread) != s.thread:
+                    lane = spare = spare + 1
+                lanes[(s.tid, s.thread)] = lane
             ev = {"ph": "X", "name": s.name, "cat": "dl4j_tpu",
-                  "pid": pid, "tid": s.tid,
+                  "pid": pid, "tid": lane,
                   "ts": round(s.ts_us, 3), "dur": round(s.dur_us, 3)}
-            if s.attrs:
-                ev["args"] = s.attrs
+            args = dict(s.attrs) if s.attrs else {}
+            if s.parent:
+                args["parent"] = s.parent
+            if args:
+                ev["args"] = args
             events.append(ev)
         events.sort(key=lambda e: e["ts"])
-        meta = [{"ph": "M", "name": "thread_name", "pid": pid, "tid": tid,
+        meta = [{"ph": "M", "name": "thread_name", "pid": pid, "tid": lane,
                  "args": {"name": name or f"thread-{tid}"}}
-                for tid, name in sorted(threads.items())]
+                for (tid, name), lane in sorted(lanes.items())]
         out = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
         dropped = self.dropped_spans()
         if self.dropped or dropped:

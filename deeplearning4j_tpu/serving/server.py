@@ -574,6 +574,7 @@ class ModelServer:
         # charges the warm-up ladder's compiles (and cache hits/misses)
         # to this run — that delta is exactly what a warm cache zeroes
         compile0 = _obs_metrics.compile_snapshot()
+        stages0 = _obs_metrics.stage_snapshot()
         if self.warmup:
             shapes = self._infer_row_shapes()
             self._validate_aot_manifest(shapes)
@@ -823,7 +824,7 @@ class ModelServer:
         self._attach_slo_collector()
         self._attach_sched_collector()
         self._ledger = _goodput.start_run("serving", net=self.net)
-        self._ledger.rebase_compile(compile0)
+        self._ledger.rebase_compile(compile0, stages0)
         if self.warmup_s is not None:
             self._ledger.annotate(warmup_s=self.warmup_s)
         from deeplearning4j_tpu.observability import distributed as _dist

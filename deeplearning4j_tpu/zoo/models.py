@@ -36,6 +36,7 @@ from deeplearning4j_tpu.nn.conf.vertices import ElementWiseVertex
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.nn.updater import Adam, Nesterovs
+from deeplearning4j_tpu.observability.trace import get_tracer
 
 BF16 = DtypePolicy(param_dtype="float32", compute_dtype="bfloat16")
 F32 = DtypePolicy(param_dtype="float32", compute_dtype="float32")
@@ -382,10 +383,11 @@ def nemotron_h(seed: int = 42, pattern: str = NEMOTRON_H_PATTERN,
             .build())
     net = MultiLayerNetwork(conf).init()
     shrink = 1.0 / math.sqrt(residual_depth)
-    for p in net.params.values():
-        for leaf in ("W_out", "Wo", "Wd", "Ws_d"):
-            if leaf in p:
-                p[leaf] = p[leaf] * shrink
+    with get_tracer().program_span("net_init"):     # eager, as init is
+        for p in net.params.values():
+            for leaf in ("W_out", "Wo", "Wd", "Ws_d"):
+                if leaf in p:
+                    p[leaf] = p[leaf] * shrink
     return net
 
 

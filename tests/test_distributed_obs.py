@@ -501,7 +501,7 @@ def test_predict_trace_id_echo_and_span_stamping(fresh_identity,
         while time.time() < deadline:
             stamped = {s.name: s.attrs.get("trace_ids")
                        for s in tr.spans()
-                       if s.attrs.get("trace_ids")}
+                       if s.attrs and s.attrs.get("trace_ids")}
             if "device_compute" in stamped:
                 break
             time.sleep(0.01)
