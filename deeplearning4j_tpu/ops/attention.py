@@ -32,8 +32,10 @@ Three backends behind the ``causal_mha`` registry seam:
   down (``block_diffusion_mha``) are the measured ones: on the v5e, in
   the cell ``sdar_30b_a3b-train-b1-l4096`` (2 x 4,096 rows, 32 query
   heads on 4 key/value heads of 128, tiles of 8 x 128 rows by 512
-  keys), the forward runs at 26% and the tiled backward at 43% of the
-  MXU's roofline for the visible pairs (PERF.md, Findings PR 31). The
+  keys), the forward runs at 46% and the tiled backward at 40% of the
+  MXU's roofline for the visible pairs, which is 2.4 us a grid step for
+  1.4 of products and, backward, 85% of the MXU's peak for the seven
+  products the two kernels execute (PERF.md, Findings PR 36). The
   causal path was not moved onto that kernel body: the body carries a
   window of key blocks per row and could carry "keys up to my own", but
   the causal path's contract is the decode bit-identity above, pinned
@@ -384,6 +386,17 @@ def extend_cache(k_cache, v_cache, k_new, v_new, pos):
 #   tables: a tile pair with no visible entry costs no grid step and no
 #   DMA. Of the 4 L^2 pairs L^2 + L * block_len are visible; at L = 4096
 #   and tiles of 128 x 512 the live tiles hold 1.25 times that.
+#
+#   Which way a kernel's score tile lies is chosen by what it reduces:
+#   the forward and the dK/dV kernel hold it as [keys, G * bq rows], the
+#   dQ kernel as [G * bq rows, keys]. Whatever a kernel knows per ROW
+#   (the forward's running max and sum, the backward's saved log-sum-exp
+#   and ``di``) then lies along the lanes in the first two, dense, and
+#   the forward's two reductions over a tile's keys go down the sublanes
+#   on the VPU; dK and dV are sums over rows, which that orientation
+#   hands to the MXU's contraction. dQ is a sum over keys, the
+#   contraction of [rows, keys] by the key tile, and needs no reduction
+#   of the scores at all, so it keeps its statistics as columns.
 
 
 def block_diffusion_visible(i, j, seq_len: int, block_len: int):
@@ -543,18 +556,9 @@ def _bd_bounds(kind, q_lo, k_lo, seq_len, shift, q_iota, k_iota):
     return kb, lower, upper
 
 
-def _col_to_rows(col, g, bq):
-    """[g * bq, 1] -> [g, bq] with elementwise ops and a sublane
-    reduction only (no relayout): row r of head h lands in lane r."""
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1))
-    return jnp.concatenate(
-        [jnp.sum(jnp.where(eye, col[h * bq:(h + 1) * bq], 0.0), axis=0,
-                 keepdims=True) for h in range(g)], axis=0)
-
-
 def _rows_to_col(rows, g, bq):
-    """[g, bq] -> [g * bq, 1], the inverse of ``_col_to_rows``."""
+    """[g, bq] -> [g * bq, 1] with elementwise ops and a lane reduction
+    only (no relayout): lane r of head h lands in row h * bq + r."""
     eye = (jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
            == jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1))
     return jnp.concatenate(
@@ -589,10 +593,23 @@ def _bd_scores(qi, ki, kind, q_ref, k_ref, scale, seq_len, shift):
 def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
                    q_ref, k_ref, v_ref, o_ref, lse_ref,
                    m_scr, l_scr, acc_scr, *, scale, seq_len, shift):
+    """Online softmax with the scores as [keys, rows] (``k . q^T``, as
+    ``_bd_dkv_kernel`` makes them): a row's running max, sum and
+    rescaling factor are [1, g * bq] along the lanes, 8 dense vregs for
+    8 heads, and the max and the sum over a tile's keys run down the
+    sublanes, elementwise across vregs, on the VPU. As [rows, keys] both
+    are reductions across the 128 lanes of every vreg of the tile and
+    the statistics [g * bq, 1] columns, one lane of a vreg in use: on a
+    v5e that is half of such a kernel's time (PERF.md, Findings PR 36).
+    The accumulator follows as [dh, g * bq] (``v^T . p^T``) and is turned
+    once a query tile, head by head; the log-sum-exp leaves by splitting
+    the lanes. The dQ kernel keeps [rows, keys]: it reduces nothing over
+    the keys and its products want the rows streamed."""
     import jax.experimental.pallas as pl
 
     s_id = pl.program_id(1)
     g, bq, dh = q_ref.shape[1:]
+    bk = k_ref.shape[1]
 
     @pl.when(first_ref[s_id] == 1)
     def _():
@@ -600,26 +617,39 @@ def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    s = _bd_scores(qi_ref[s_id], ki_ref[s_id], kind_ref[s_id], q_ref, k_ref,
-                   scale, seq_len, shift)
-    m_prev, l_prev = m_scr[:], l_scr[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
+    kb, lower, upper = _bd_bounds(
+        kind_ref[s_id], qi_ref[s_id] * bq, ki_ref[s_id] * bk, seq_len, shift,
+        lanes & (bq - 1),
+        jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
+    st = jax.lax.dot_general(
+        k_ref[0], q_ref[0].reshape(g * bq, dh),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale          # [bk, g*bq]
+    st = jnp.where((kb <= upper) & (kb >= lower), st, _MASK_VALUE)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    pt = jnp.exp(st - m_new)
+    l_scr[:] = alpha * l_scr[:] + jnp.sum(pt, axis=0, keepdims=True)
     m_scr[:] = m_new
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        v_ref[0], pt.astype(v_ref.dtype),
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [dh, g*bq]
 
     @pl.when(last_ref[s_id] == 1)
     def _():
         l = l_scr[:]
-        o_ref[0] = (acc_scr[:] / l).reshape(g, bq, dh).astype(o_ref.dtype)
-        lse_ref[0] = _col_to_rows(m_scr[:] + jnp.log(l), g, bq)
+        out = acc_scr[:] / l
+        lse = m_scr[:] + jnp.log(l)
+        for h in range(g):
+            o_ref[0, h] = out[:, h * bq:(h + 1) * bq].T.astype(o_ref.dtype)
+            lse_ref[0, h:h + 1, :] = lse[:, h * bq:(h + 1) * bq]
 
 
+# dQ: scores as [rows, keys] (``_bd_scores``), the row statistics read
+# once a query tile into [g * bq, 1] columns; nothing is reduced over keys.
 def _bd_dq_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
                   q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
                   lse_scr, di_scr, acc_scr, *, scale, seq_len, shift):
@@ -774,9 +804,9 @@ def _bd_forward(qg, kg, vg, seq_len, block_len):
         in_specs=[rows, keys, keys], out_specs=[rows, stats],
         out_shape=[jax.ShapeDtypeStruct(qg.shape, qg.dtype),
                    jax.ShapeDtypeStruct((bh, g, t), jnp.float32)],
-        scratch=[pltpu.VMEM((g * bq, 1), jnp.float32),
-                 pltpu.VMEM((g * bq, 1), jnp.float32),
-                 pltpu.VMEM((g * bq, dh), jnp.float32)],
+        scratch=[pltpu.VMEM((1, g * bq), jnp.float32),
+                 pltpu.VMEM((1, g * bq), jnp.float32),
+                 pltpu.VMEM((dh, g * bq), jnp.float32)],
         scale=1.0 / math.sqrt(dh), seq_len=seq_len,
         shift=_bd_shift(block_len))(*tables, qg, kg, vg)
 

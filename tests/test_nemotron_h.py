@@ -475,14 +475,20 @@ def _dense_causal(q, k, v):
     return jnp.einsum("bhij,bjhd->bihd", p, v)
 
 
-@pytest.mark.parametrize("backend,rows,dh", [("pallas", 512, 128),
-                                             ("xla", 24, 16)])
+@pytest.mark.parametrize("backend,rows,dh,heads,kv_heads", [
+    ("pallas", 512, 128, 4, 2), ("xla", 24, 16, 4, 2),
+    # the cell's group, 16 query heads a key/value head (32 on 2), and a
+    # query tile that meets two key tiles, so the running max and sum are
+    # rescaled
+    ("pallas", 1024, 128, 16, 1)],
+    ids=["pallas-512-128", "xla-24-16", "pallas-1024-128-16on1"])
 def test_causal_attention_matches_dense_masked_softmax(monkeypatch, backend,
-                                                       rows, dh):
+                                                       rows, dh, heads,
+                                                       kv_heads):
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     k = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(k[0], (1, rows, 4, dh), jnp.float32)
-    kk, v = (jax.random.normal(k[i], (1, rows, 2, dh), jnp.float32)
+    q = jax.random.normal(k[0], (1, rows, heads, dh), jnp.float32)
+    kk, v = (jax.random.normal(k[i], (1, rows, kv_heads, dh), jnp.float32)
              for i in (1, 2))
     g = jax.random.normal(k[3], q.shape, jnp.float32)
     assert att.causal_attention_supported(q, kk, v) == (backend == "pallas")

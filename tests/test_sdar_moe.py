@@ -167,28 +167,42 @@ def _dense_attention(q, k, v, seq, block):
     return np.einsum("bhij,bjhd->bihd", p / p.sum(-1, keepdims=True), v)
 
 
-def _qkv(seq, heads, kv_heads, dh, seed=0):
+def _qkv(seq, heads, kv_heads, dh, seed=0, dtype=jnp.float32):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     shape = lambda h: (1, 2 * seq, h, dh)
-    return [jax.random.normal(k, shape(h), jnp.float32)
+    return [jax.random.normal(k, shape(h), jnp.float32).astype(dtype)
             for k, h in zip(ks, (heads, kv_heads, kv_heads, heads))]
 
 
-@pytest.mark.parametrize("backend,seq,block", [
-    ("xla", 20, 4), ("xla", 12, 3), ("pallas", 256, 4), ("pallas", 128, 8)])
+# heads, key/value heads, operand dtype, tolerance. 8 on 1 is the SDAR
+# cell's group (32 on 4); bf16: the products' operands, p among them,
+# and the outputs are rounded to 8 bits
+F32_4ON2 = (4, 2, jnp.float32, 2e-5)
+
+
+@pytest.mark.parametrize("backend,seq,block,heads,kv_heads,dtype,rtol", [
+    ("xla", 20, 4, *F32_4ON2), ("xla", 12, 3, *F32_4ON2),
+    ("pallas", 256, 4, *F32_4ON2), ("pallas", 128, 8, *F32_4ON2),
+    ("pallas", 256, 4, 8, 1, jnp.float32, 2e-5),
+    ("pallas", 128, 4, 8, 1, jnp.bfloat16, 2e-2)],
+    ids=["xla-20-4", "xla-12-3", "pallas-256-4", "pallas-128-8",
+         "pallas-256-4-8on1", "pallas-128-4-8on1-bf16"])
 def test_attention_matches_dense_masked_softmax(monkeypatch, backend, seq,
-                                                block):
+                                                block, heads, kv_heads,
+                                                dtype, rtol):
     """Forward and the gradients of q, k, v. The Pallas kernels run in
     interpret mode; 2e-5: online softmax over tiles sums in another
     order."""
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     dh = 128 if backend == "pallas" else 16
-    q, k, v, g = _qkv(seq, 4, 2, dh)
+    q, k, v, g = _qkv(seq, heads, kv_heads, dh, dtype=dtype)
     fn = registry.get("block_diffusion_mha", backend)
     if backend == "pallas":
         assert att.block_attention_supported(q, k, v, seq, block)
     out = fn(q, k, v, seq_len=seq, block_len=block)
-    _close(out, _dense_attention(q, k, v, seq, block))
+    assert out.dtype == dtype
+    _close(out, _dense_attention(q, k, v, seq, block), rtol)
+    g = g.astype(jnp.float32)
     got = jax.grad(lambda *a: jnp.sum(fn(*a, seq_len=seq, block_len=block)
                                       * g), (0, 1, 2))(q, k, v)
 
@@ -200,8 +214,46 @@ def test_attention_matches_dense_masked_softmax(monkeypatch, backend, seq,
         return jnp.sum(jnp.einsum("bhij,bjhd->bihd",
                                   jax.nn.softmax(s, -1), vv) * g)
 
-    for a, b in zip(got, jax.grad(dense, (0, 1, 2))(q, k, v)):
-        _close(a, b)
+    want = jax.grad(dense, (0, 1, 2))(*(a.astype(jnp.float32)
+                                        for a in (q, k, v)))
+    for a, b in zip(got, want):
+        assert a.dtype == dtype
+        _close(a, b, rtol)
+
+
+@pytest.mark.parametrize("seq,block,group", [(512, 4, 2), (1024, None, 4)],
+                         ids=["block_diffusion", "causal"])
+def test_forward_saves_the_log_sum_exp_of_the_masked_scores(monkeypatch, seq,
+                                                            block, group):
+    """The forward's second output feeds both backward kernels: for each
+    row the log-sum-exp of its visible scaled scores, float32, laid
+    [b * Hkv, G, rows]. Block-diffusion at L = 512 has key tiles of 512,
+    so a noised row sees 4 of the 512 keys of its kind-0 tile and the
+    running max starts from a tile that is masked but for those; causal
+    at 1,024 rows carries max and sum over two key tiles."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    rows = 2 * seq if block else seq
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = 3.0 * jax.random.normal(ks[0], (1, rows, 2 * group, 128), jnp.float32)
+    k, v = (jax.random.normal(key, (1, rows, 2, 128), jnp.float32)
+            for key in ks[1:])
+    if block:
+        assert att._bd_key_tile(seq) == 512
+        assert (0, 0, 0) in {tuple(int(x) for x in row) for row in
+                             att._bd_live_tiles(seq, block, 128, 512)}
+        visible = _table(seq, block)
+    else:
+        visible = np.tri(rows, dtype=bool)
+    lse = jax.jit(lambda *a: att._bd_forward(*att._bd_split(*a), seq,
+                                             block)[1])(q, k, v)
+    assert lse.shape == (2, group, rows) and lse.dtype == jnp.float32
+    q64, k64 = (np.asarray(a, np.float64) for a in (q, k))
+    s = np.einsum("ikgd,jkd->kgij", q64[0].reshape(rows, 2, group, 128),
+                  k64[0]) / np.sqrt(128)
+    s = np.where(visible, s, -np.inf)
+    top = s.max(-1, keepdims=True)
+    want = (top + np.log(np.exp(s - top).sum(-1, keepdims=True)))[..., 0]
+    np.testing.assert_allclose(np.asarray(lse), want, rtol=0, atol=2e-5)
 
 
 def test_unsupported_shapes_fall_back_to_xla(monkeypatch):
