@@ -3,7 +3,7 @@ in, RMS norm, rotary positions, grouped-query heads with per-head
 query/key norm, routed experts of which this chip holds a share, a
 state-space mixer, and a head with integer labels and per-token weights.
 
-Two decoders are built of them (PERF.md section 4 has both models):
+Three decoders are built of them (PERF.md section 4 has the models):
 
 - a block-diffusion decoder (``zoo.sdar_moe``): ``TokenEmbedding``,
   ``MoeDecoderBlock`` (attention under the block-diffusion mask, then a
@@ -17,6 +17,36 @@ Two decoders are built of them (PERF.md section 4 has both models):
   ``RoutedExperts`` (a sigmoid router with a correction bias over
   ``relu2`` experts without a gate, and a shared expert every row
   takes); ``RmsNorm``, ``TokenOutput`` over every row.
+- a causal latent-attention decoder (``zoo.glm4_moe_lite``):
+  ``TokenEmbedding``, ``LatentDenseBlock`` for the leading layers (latent
+  attention, then a dense gated silu MLP), ``LatentMoeBlock`` for the
+  rest (latent attention, then a sigmoid router with a correction bias
+  over gated silu experts and a shared gated silu expert), and
+  ``MtpTokenOutput``: the final norm, the head, and a multi-token-
+  prediction module that reads the embedding's matrix and the head's
+  own (``RmsNorm`` and ``TokenOutput(causal=True)`` without the module).
+
+Which decoder uses what:
+
+    ======================  ==========  ==========  ==============
+                            sdar_moe    nemotron_h  glm4_moe_lite
+    ======================  ==========  ==========  ==============
+    TokenEmbedding          x           x           x (two users)
+    RmsNorm                 x           x           without module
+    TokenOutput             causal=F    causal=T    without module
+    MoeDecoderBlock         x
+    CausalAttention                     x
+    Mamba2Mixer                         x
+    RoutedExperts           (in block)  x           (in blocks)
+      router                softmax     sigmoid     sigmoid
+      expert_form           gated_silu  relu2       gated_silu
+      shared expert         none        relu2       gated_silu
+    LatentDenseBlock                                x
+    LatentMoeBlock                                  x
+    MtpTokenOutput                                  with module
+    ops/attention.py        block_diff  causal      causal
+    ops/grouped.py          kernels     chunk loop  kernels, 2 slices
+    ======================  ==========  ==========  ==============
 
 Layout as the recurrent family: ``[batch, time, features]``, but the
 first layer takes ``[batch, time]`` integer ids (``InputType.recurrent(
@@ -91,9 +121,10 @@ class RoutedExperts(_WidthPreserving):
     ``expert_form``: ``"gated_silu"``, ``h = silu(gate w) * up w``, three
     matrices an expert (``Wg``, ``Wu``, ``Wd``); or ``"relu2"``, ``h =
     max(up w, 0)^2``, two (``Wu``, ``Wd``).
-    ``shared_width`` > 0 adds a shared ``relu2`` expert of that width,
-    ``down_s(max(up_s w, 0)^2)`` (``Ws_u``, ``Ws_d``), that every row
-    takes, whichever experts it chose and wherever they are held."""
+    ``shared_width`` > 0 adds a shared expert of that width and of the
+    routed experts' form (``Ws_u``, ``Ws_d``, and ``Ws_g`` where gated)
+    that every row takes, whichever experts it chose and wherever they
+    are held."""
 
     layer_type = "routed_experts"
     n_experts: int = 8
@@ -155,6 +186,60 @@ class CausalAttention(_WidthPreserving):
         return CausalAttentionLayer(self, input_type, global_conf, policy)
 
 
+@dataclass(frozen=True)
+class _LatentAttention:
+    """Fields of a latent attention (DeepSeek-V2's multi-head latent
+    attention, arXiv 2405.04434 section 2.1), for ``u = RMSNorm(x)``:
+
+        c_q  = RMSNorm(W_dq u)  [q_rank]      q_h = W_uq,h c_q = [q_nope,h | q_rope,h]
+        [c_kv | k_rope] = W_dkv u             c_kv <- RMSNorm(c_kv)  [kv_rank]
+        [k_nope,h | v_h] = W_ukv,h c_kv
+        q_h = [q_nope,h | R_p q_rope,h]       k_h = [k_nope,h | R_p k_rope]
+        x + W_o concat_h(softmax_{j <= i}(q_h,i . k_h,j / sqrt(nope_dim + rope_dim)) v_h,j)
+
+    ``R_p`` rotates the ``rope_dim`` columns alone (column ``i`` with ``i
+    + rope_dim / 2``) by the row's position, 0 for the first row; one
+    ``k_rope`` serves all heads. ``v_dim`` must equal ``nope_dim +
+    rope_dim`` (ops/attention.py takes one head size)."""
+
+    n_heads: int = 4
+    q_rank: int = 24
+    kv_rank: int = 16
+    nope_dim: int = 24
+    rope_dim: int = 8
+    v_dim: int = 32
+    rope_theta: float = 1e6
+
+
+@register_layer
+@dataclass(frozen=True)
+class LatentMoeBlock(_LatentAttention, RoutedExperts):
+    """One decoder layer: pre-norm latent attention with a residual,
+    then the routed experts above."""
+
+    layer_type = "latent_moe_block"
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import LatentMoeBlockLayer
+        return LatentMoeBlockLayer(self, input_type, global_conf, policy)
+
+
+@register_layer
+@dataclass(frozen=True)
+class LatentDenseBlock(_LatentAttention, _WidthPreserving):
+    """One decoder layer: pre-norm latent attention with a residual,
+    then a pre-norm dense MLP with one, ``a + W_d (silu(W_g w) * W_u
+    w)``, ``w = RMSNorm(a)``, of ``mlp_width``."""
+
+    layer_type = "latent_dense_block"
+    mlp_width: int = 128
+    eps: float = 1e-5
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import LatentDenseBlockLayer
+        return LatentDenseBlockLayer(self, input_type, global_conf, policy)
+
+
 @register_layer
 @dataclass(frozen=True)
 class Mamba2Mixer(_WidthPreserving):
@@ -206,3 +291,42 @@ class TokenOutput(BaseRecurrentConfig):
     def make_layer(self, input_type, global_conf, policy):
         from deeplearning4j_tpu.nn.layers.decoder import TokenOutputLayer
         return TokenOutputLayer(self, input_type, global_conf, policy)
+
+
+@register_layer
+@dataclass(frozen=True)
+class MtpTokenOutput(LatentMoeBlock):
+    """The head of a decoder trained to predict two tokens a row
+    (DeepSeek-V3's multi-token prediction, arXiv 2412.19437 section
+    2.2, one module): for ``h`` the last block's output and integer
+    labels ``[b, 2, t]``, row ``i`` holding tokens ``i + 1`` and ``i +
+    2``,
+
+        logits  = W RMSNorm_f(h)                              L_main = nll(logits, labels[:, 0])
+        h'      = W_eh [RMSNorm_e(Emb(labels[:, 0])) ; RMSNorm_h(h)]
+        g       = one LatentMoeBlock (this conf's fields) on h', positions 0..t-1
+        logits' = W RMSNorm_s(g)                              L_mtp  = nll(logits', labels[:, 1])
+        loss    = L_main + mtp_weight * L_mtp
+
+    ``Emb`` is the matrix ``W`` of the layer named ``embedding``, the
+    net's ``TokenEmbedding``: one stored leaf with two users, whose
+    gradient is the sum of both (so is the head's ``W``, used twice
+    here). ``net.output`` answers ``logits`` alone; the two losses of
+    the last step ride in the layer's state (``mtp_loss``). A labels
+    mask, where given, is ``[b, 2, t]`` too. ``n_out`` is the hidden
+    width, as for every block; the logits are ``vocab_size`` wide."""
+
+    layer_type = "mtp_token_output"
+    vocab_size: int = 256
+    mtp_weight: float = 0.3
+    embedding: str = "layer_0"
+
+    def get_output_type(self, input_type):
+        from deeplearning4j_tpu.nn.conf.inputs import InputType
+        return InputType.recurrent(
+            self.vocab_size, None if input_type is None
+            else input_type.timesteps)
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import MtpTokenOutputLayer
+        return MtpTokenOutputLayer(self, input_type, global_conf, policy)

@@ -1,5 +1,5 @@
 """Runtime layers of a modern decoder (configs and the equations:
-nn/conf/layers_decoder.py; PERF.md section 4 has the two models they
+nn/conf/layers_decoder.py; PERF.md section 4 has the three models they
 were written for, and that docstring says which decoder uses what).
 
 Precision under a mixed policy: parameters in the param dtype, every
@@ -13,9 +13,17 @@ in the compute dtype, as in every other net of this package.
 Named scopes inside a layer, under the layer's own: ``attn`` (norm,
 projections, head norms, rotation, output projection, and inside it
 ``block_attention`` or ``causal_attention`` round the attention itself,
-whatever backend runs), ``mamba`` (norm, both projections, and inside it
-``ssm_conv``, ``ssm_scan`` and ``ssm_norm`` round the three ops of
-ops/ssm.py), ``shared_expert`` (its two products), ``route`` (norm, router, top-k, the sort of the pairs, and what
+whatever backend runs; a latent attention has two more inside it,
+``mla_down`` round both compressions and their norms and ``mla_up``
+round both expansions, the rotation and the broadcast of the rotated
+key slice), ``dense_mlp`` (a dense layer's norm and three products),
+``head`` and ``mtp`` (in ``MtpTokenOutput``: the model's final norm,
+head and loss; and all the prediction module does, its own ``attn``,
+``route``, ``experts`` and ``shared_expert`` inside it), ``mamba``
+(norm, both projections, and inside it ``ssm_conv``, ``ssm_scan`` and
+``ssm_norm`` round the three ops of ops/ssm.py), ``shared_expert`` (its
+two or three products), ``route`` (norm, router, top-k, the sort of the
+pairs, and what
 ``ops/grouped.py`` does to move rows in XLA: a gather a block of pairs
 before its kernels, or a gather and a scatter-add a chunk inside its
 loop) and ``experts`` (the grouped products and the gating between
@@ -51,17 +59,33 @@ def _project(x, w, cd):
     return jnp.einsum("btf,fg->btg", x.astype(cd), w.astype(cd))
 
 
-def _rotate(x, theta):
-    """Rotary positions over the whole head (rotate-half), float32.
-    ``x`` [b, 2L, h, dh]: both halves of the rows sit at 0..L-1."""
-    t, dh = x.shape[1], x.shape[3]
-    pos = (jnp.arange(t, dtype=jnp.int32) % (t // 2)).astype(jnp.float32)
+def _rotate(x, theta, pos):
+    """Rotary positions over the whole of ``x`` [b, t, h, dh]
+    (rotate-half: column ``i`` pairs with ``i + dh/2``), float32; row
+    ``i`` sits at ``pos[i]`` (int32 [t])."""
+    dh = x.shape[3]
+    pos = pos.astype(jnp.float32)
     inv = float(theta) ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angle = pos[:, None] * inv[None, :]                      # [t, dh/2]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[None, :, None, :]
     x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
+def _feed_forward(w, gate, up, down):
+    """One expert every row takes, in the two forms of ops/grouped.py:
+    ``down(silu(gate w) * up w)``, or ``down(max(up w, 0)^2)`` where
+    ``gate`` is None. ``w`` [R, d] in the compute dtype; float32 [R, d]."""
+    cd = w.dtype
+    held = jnp.dot(w, up.astype(cd), preferred_element_type=jnp.float32)
+    if gate is None:
+        held = jnp.square(jnp.maximum(held, 0.0))
+    else:
+        held = jax.nn.silu(jnp.dot(
+            w, gate.astype(cd), preferred_element_type=jnp.float32)) * held
+    return jnp.dot(held.astype(cd), down.astype(cd),
+                   preferred_element_type=jnp.float32)
 
 
 def expert_chunk_rows(rows: int, experts_per_token: int,
@@ -172,6 +196,9 @@ class RoutedExpertsLayer(_DecoderLayer):
             ku, kd = jax.random.split(jax.random.fold_in(key, 1))
             params["Ws_u"] = self._init(ku, (d, shared), d, shared)
             params["Ws_d"] = self._init(kd, (shared, d), shared, d)
+            if self.conf.expert_form == "gated_silu":
+                params["Ws_g"] = self._init(jax.random.fold_in(key, 2),
+                                            (d, shared), d, shared)
         return params
 
     def init_state(self):
@@ -233,11 +260,9 @@ class RoutedExpertsLayer(_DecoderLayer):
                 chunk=chunk)
         if "Ws_u" in params:
             with jax.named_scope("shared_expert"):
-                up = jnp.dot(w.astype(cd), params["Ws_u"].astype(cd),
-                             preferred_element_type=jnp.float32)
-                held = jnp.square(jnp.maximum(up, 0.0)).astype(cd)
-                y = y + jnp.dot(held, params["Ws_d"].astype(cd),
-                                preferred_element_type=jnp.float32)
+                y = y + _feed_forward(
+                    w.astype(cd), params.get("Ws_g"), params["Ws_u"],
+                    params["Ws_d"])
         with jax.named_scope("route"):
             out = a + y.reshape(a.shape).astype(cd)
             low = state["expert_rows_total"][0] + counts
@@ -317,10 +342,12 @@ class MoeDecoderBlockLayer(_GroupedQueryHeads, RoutedExpertsLayer):
                 f"MoeDecoderBlock '{self.name}' takes a noised and a clean "
                 f"copy of each sequence, an even number of rows; got {t}")
         q, k, v = self._heads(params, x)
+        # both halves of the rows sit at 0..L-1
+        pos = jnp.arange(t, dtype=jnp.int32) % (t // 2)
         q = _rotate(_rms_norm(q, params["q_norm_g"], conf.eps),
-                    conf.rope_theta).astype(cd)
+                    conf.rope_theta, pos).astype(cd)
         k = _rotate(_rms_norm(k, params["k_norm_g"], conf.eps),
-                    conf.rope_theta).astype(cd)
+                    conf.rope_theta, pos).astype(cd)
         with jax.named_scope("block_attention"):
             o = att.block_diffusion_mha(q, k, v, seq_len=t // 2,
                                         block_len=int(conf.block_len))
@@ -347,6 +374,131 @@ class CausalAttentionLayer(_GroupedQueryHeads, _DecoderLayer):
             with jax.named_scope("causal_attention"):
                 o = att.causal_attention(q, k, v)
             return self._merge_heads(params, x, o), state
+
+
+class _LatentHeads:
+    """A pre-norm latent attention (multi-head, queries and keys/values
+    each expanded from a compressed row), for the layers whose conf has
+    ``n_heads``, ``q_rank``, ``kv_rank``, ``nope_dim``, ``rope_dim``,
+    ``v_dim`` and ``rope_theta``: parameters ``attn_ln_g``, ``W_dq``,
+    ``q_ln_g``, ``W_uq``, ``W_dkv``, ``kv_ln_g``, ``W_ukv``, ``Wo``
+    (nn/conf/layers_decoder.py ``LatentMoeBlock`` has the equations)."""
+
+    def _check_latent(self):
+        conf = self.conf
+        if conf.v_dim != conf.nope_dim + conf.rope_dim:
+            raise ValueError(
+                f"{type(conf).__name__} '{conf.name}': values of "
+                f"{conf.v_dim} beside queries and keys of {conf.nope_dim} + "
+                f"{conf.rope_dim}: ops/attention.py takes one head size")
+        if conf.rope_dim % 2:
+            raise ValueError(
+                f"{type(conf).__name__} '{conf.name}': the rotation pairs "
+                f"columns, and {conf.rope_dim} is odd")
+
+    def _init_latent(self, key):
+        conf = self.conf
+        d, h = int(conf.n_out), int(conf.n_heads)
+        rq, rkv = int(conf.q_rank), int(conf.kv_rank)
+        dn, dr, dv = int(conf.nope_dim), int(conf.rope_dim), int(conf.v_dim)
+        k_dq, k_uq, k_dkv, k_ukv, k_o = jax.random.split(key, 5)
+        pd = self.param_dtype
+        return {
+            "attn_ln_g": jnp.ones((d,), pd),
+            "W_dq": self._init(k_dq, (d, rq), d, rq),
+            "q_ln_g": jnp.ones((rq,), pd),
+            "W_uq": self._init(k_uq, (rq, h * (dn + dr)), rq, h * (dn + dr)),
+            "W_dkv": self._init(k_dkv, (d, rkv + dr), d, rkv + dr),
+            "kv_ln_g": jnp.ones((rkv,), pd),
+            "W_ukv": self._init(k_ukv, (rkv, h * (dn + dv)), rkv,
+                                h * (dn + dv)),
+            "Wo": self._init(k_o, (h * dv, d), h * dv, d),
+        }
+
+    def _latent_attention(self, params, x):
+        """``x + W_o attention(RMSNorm(x))`` of ``x`` [b, t, d], rows at
+        positions 0..t-1, under the scope ``attn``."""
+        conf, cd = self.conf, x.dtype
+        b, t, _ = x.shape
+        h, rkv = int(conf.n_heads), int(conf.kv_rank)
+        dn, dr = int(conf.nope_dim), int(conf.rope_dim)
+        _count_latent_layer()
+        with jax.named_scope("attn"):
+            u = _rms_norm(x, params["attn_ln_g"], conf.eps).astype(cd)
+            with jax.named_scope("mla_down"):
+                c_q = _rms_norm(_project(u, params["W_dq"], cd),
+                                params["q_ln_g"], conf.eps).astype(cd)
+                c_kv, k_rope = jnp.split(_project(u, params["W_dkv"], cd),
+                                         [rkv], axis=-1)
+                c_kv = _rms_norm(c_kv, params["kv_ln_g"],
+                                 conf.eps).astype(cd)
+            with jax.named_scope("mla_up"):
+                pos = jnp.arange(t, dtype=jnp.int32)
+                q = _project(c_q, params["W_uq"], cd).reshape(
+                    b, t, h, dn + dr)
+                q = jnp.concatenate([q[..., :dn], _rotate(
+                    q[..., dn:], conf.rope_theta, pos).astype(cd)], axis=-1)
+                kv = _project(c_kv, params["W_ukv"], cd).reshape(b, t, h, -1)
+                # one rotated key slice for all heads
+                k_rope = _rotate(k_rope[:, :, None, :], conf.rope_theta,
+                                 pos).astype(cd)
+                k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+                    k_rope, (b, t, h, dr))], axis=-1)
+                v = kv[..., dn:]
+            with jax.named_scope("causal_attention"):
+                o = att.causal_attention(q, k, v)
+            return x + _project(o.reshape(b, t, -1), params["Wo"], cd)
+
+
+def _count_latent_layer() -> None:
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_mla_layers_traced_total",
+        "Latent-attention layers traced (forward walks of a net)").inc()
+
+
+class LatentMoeBlockLayer(_LatentHeads, RoutedExpertsLayer):
+    def __init__(self, conf, input_type, global_conf, policy):
+        super().__init__(conf, input_type, global_conf, policy)
+        self._check_latent()
+
+    def init_params(self, key):
+        k_experts, k_attn = jax.random.split(key)
+        params = super().init_params(k_experts)
+        params.update(self._init_latent(k_attn))
+        return params
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return self._experts(params, state, self._latent_attention(
+            params, x.astype(self.compute_dtype)))
+
+
+class LatentDenseBlockLayer(_LatentHeads, _DecoderLayer):
+    def __init__(self, conf, input_type, global_conf, policy):
+        super().__init__(conf, input_type, global_conf, policy)
+        self._check_latent()
+
+    def init_params(self, key):
+        d, f = int(self.conf.n_out), int(self.conf.mlp_width)
+        k_attn, kg, ku, kd = jax.random.split(key, 4)
+        params = self._init_latent(k_attn)
+        params.update({
+            "ln_g": jnp.ones((d,), self.param_dtype),
+            "Wg": self._init(kg, (d, f), d, f),
+            "Wu": self._init(ku, (d, f), d, f),
+            "Wd": self._init(kd, (f, d), f, d),
+        })
+        return params
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        cd = self.compute_dtype
+        a = self._latent_attention(params, x.astype(cd))
+        with jax.named_scope("dense_mlp"):
+            w = _rms_norm(a, params["ln_g"], self.conf.eps).astype(cd)
+            y = _feed_forward(w.reshape(-1, w.shape[-1]), params["Wg"],
+                              params["Wu"], params["Wd"])
+            return a + y.reshape(a.shape).astype(cd), state
 
 
 def _inverse_softplus(dt):
@@ -442,9 +594,92 @@ class TokenOutputLayer(_DecoderLayer):
             raise TypeError(
                 f"TokenOutput '{self.name}' takes integer labels [b, t], "
                 f"got {labels.dtype}{tuple(labels.shape)}")
-        nll = (jax.nn.logsumexp(logits, axis=-1)
-               - jnp.take_along_axis(logits, labels[..., None],
-                                     axis=-1)[..., 0])
-        if mask is not None:
-            nll = nll * mask.astype(jnp.float32)
-        return jnp.sum(nll) / math.prod(labels.shape)
+        return _token_nll(logits, labels, mask)
+
+
+def _token_nll(logits, labels, mask):
+    """The cross-entropy of ``TokenOutput``: summed, over the number of
+    labels."""
+    nll = (jax.nn.logsumexp(logits, axis=-1)
+           - jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0])
+    if mask is not None:
+        nll = nll * mask.astype(jnp.float32)
+    return jnp.sum(nll) / math.prod(labels.shape)
+
+
+class MtpTokenOutputLayer(LatentMoeBlockLayer):
+    """The model's final norm and head, and one multi-token-prediction
+    module that shares the head and the embedding (nn/conf/
+    layers_decoder.py ``MtpTokenOutput``). The block this class inherits
+    is the module's own expert layer; its routing counts ride in this
+    layer's state as any expert layer's do, and beside them
+    ``mtp_loss``, float32 [2]: the last step's two losses, unweighted."""
+
+    loss_uses_state = True
+    loss_returns_state = True
+
+    @property
+    def shares(self):
+        """Leaves of other layers that this one reads: a net hands them
+        over under the local name, and their gradient is summed into the
+        owner's (nn/multilayer.py ``_layer_params``)."""
+        return {"Emb": (self.conf.embedding, "W")}
+
+    def init_params(self, key):
+        d, vocab = int(self.conf.n_out), int(self.conf.vocab_size)
+        k_block, k_head, k_eh = jax.random.split(key, 3)
+        params = super().init_params(k_block)
+        params.update({
+            "W": self._init(k_head, (d, vocab), d, vocab),
+            "W_eh": self._init(k_eh, (2 * d, d), 2 * d, d),
+        })
+        # a buffer each: the step donates every leaf
+        for norm in ("norm_g", "enorm_g", "hnorm_g", "mtp_norm_g"):
+            params[norm] = jnp.ones((d,), self.param_dtype)
+        return params
+
+    def init_state(self):
+        return {**super().init_state(),
+                "mtp_loss": jnp.zeros((2,), jnp.float32)}
+
+    def _logits(self, params, norm, x):
+        cd = self.compute_dtype
+        rows = _rms_norm(x, params[norm], self.conf.eps).astype(cd)
+        return jnp.einsum("btf,fg->btg", rows, params["W"].astype(cd),
+                          preferred_element_type=jnp.float32)
+
+    def module(self, params, state, h, next_ids):
+        """The module's expert layer on ``W_eh [RMSNorm(Emb(next_ids)) ;
+        RMSNorm(h)]``: (its input, its output, the layer's new state)."""
+        conf, cd = self.conf, self.compute_dtype
+        e = jnp.take(params["Emb"], next_ids, axis=0)
+        both = jnp.concatenate([
+            _rms_norm(e, params["enorm_g"], conf.eps),
+            _rms_norm(h, params["hnorm_g"], conf.eps)], axis=-1)
+        given = _project(both, params["W_eh"], cd)
+        g, new_state = super().apply(params, state, given)
+        return given, g, new_state
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        with jax.named_scope("head"):
+            return self.activation_fn(self._logits(params, "norm_g", x)), state
+
+    def loss(self, params, x, labels, *, train=False, rng=None, mask=None,
+             state=None):
+        if (not jnp.issubdtype(labels.dtype, jnp.integer)
+                or labels.ndim != 3 or labels.shape[1] != 2):
+            raise TypeError(
+                f"MtpTokenOutput '{self.name}' takes integer labels "
+                f"[b, 2, t], the next token and the one after it; got "
+                f"{labels.dtype}{tuple(labels.shape)}")
+        first, second = labels[:, 0], labels[:, 1]
+        masks = (None, None) if mask is None else (mask[:, 0], mask[:, 1])
+        with jax.named_scope("head"):
+            main = _token_nll(self._logits(params, "norm_g", x), first,
+                              masks[0])
+        with jax.named_scope("mtp"):
+            _, g, new_state = self.module(params, state, x, first)
+            ahead = _token_nll(self._logits(params, "mtp_norm_g", g), second,
+                               masks[1])
+        new_state["mtp_loss"] = jnp.stack([main, ahead])
+        return main + float(self.conf.mtp_weight) * ahead, new_state

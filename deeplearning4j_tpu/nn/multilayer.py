@@ -103,6 +103,20 @@ class MultiLayerNetwork(Trainer):
         return self
 
     # -------------------------------------------------------------- forward
+    @staticmethod
+    def _layer_params(layer, params) -> dict:
+        """What ``layer`` is handed: its own leaves and, under their local
+        names, the leaves of other layers it declares in ``shares``
+        (``{local: (owner layer, leaf)}``). A shared leaf is stored,
+        updated, counted and saved once, with its owner; autodiff sums
+        its users' gradients there."""
+        own = params.get(layer.name, {})
+        shares = getattr(layer, "shares", None)
+        if not shares:
+            return own
+        return {**own, **{local: params[owner][leaf]
+                          for local, (owner, leaf) in shares.items()}}
+
     def _remat_spans(self, n: int) -> dict:
         """start index -> end index for maximal contiguous runs of layers
         whose names match the DL4J_TPU_REMAT prefixes (the chain-network
@@ -139,7 +153,7 @@ class MultiLayerNetwork(Trainer):
                 rng, lr = jax.random.split(rng)
             rngs.append(lr)
         sub = self.layers[i:end]
-        p_sub = {ly.name: params.get(ly.name, {}) for ly in sub}
+        p_sub = {ly.name: self._layer_params(ly, params) for ly in sub}
         s_sub = {ly.name: state.get(ly.name, {}) for ly in sub}
 
         def run_span(p_sub, s_sub, x, fmask, rngs):
@@ -182,7 +196,7 @@ class MultiLayerNetwork(Trainer):
             lrng = None
             if rng is not None:
                 rng, lrng = jax.random.split(rng)
-            p = params.get(layer.name, {})
+            p = self._layer_params(layer, params)
             s = state.get(layer.name, {})
             with _opindex.scope(layer.name):
                 if self.preprocessors[i] is not None:
@@ -205,7 +219,7 @@ class MultiLayerNetwork(Trainer):
         h, new_state = self._forward(params, state, x, train=train, rng=rng_fwd,
                                      fmask=fmask, to_layer=len(self.layers) - 1)
         out_layer = self.layers[-1]
-        p_out = params.get(out_layer.name, {})
+        p_out = self._layer_params(out_layer, params)
         # the output layer's own scope holds its matmul and data loss;
         # "loss" is what no layer owns: regularization and the sum
         with _opindex.scope(out_layer.name):
@@ -215,7 +229,9 @@ class MultiLayerNetwork(Trainer):
                 s_out = state.get(out_layer.name, {})
                 data_loss = out_layer.loss(p_out, h, labels, train=train,
                                            rng=lrng, mask=lmask, state=s_out)
-                if train and hasattr(out_layer, "update_centers"):
+                if getattr(out_layer, "loss_returns_state", False):
+                    data_loss, new_state[out_layer.name] = data_loss
+                elif train and hasattr(out_layer, "update_centers"):
                     new_state[out_layer.name] = out_layer.update_centers(
                         s_out, jax.lax.stop_gradient(h), labels, mask=lmask)
             else:

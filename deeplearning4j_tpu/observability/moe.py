@@ -11,7 +11,12 @@ the score gauge too:
 
 - ``dl4j_moe_pairs_total{layer}``: (row, expert) pairs computed here;
 - ``dl4j_moe_expert_rows{layer, expert}``: rows of the last step, the
-  expert numbered as the router numbers it.
+  expert numbered as the router numbers it;
+- ``dl4j_mtp_loss{layer, part}``: where the output layer holds a
+  multi-token-prediction module (``MtpTokenOutput``, whose own expert
+  layer is counted above like any other), the last step's two losses
+  apart, ``part`` ``main`` and ``mtp``, unweighted (``mtp_loss`` in that
+  layer's state; ``mtp_losses(net)`` reads it).
 """
 
 from __future__ import annotations
@@ -36,6 +41,15 @@ def expert_rows(net) -> dict:
     return {name: (np.asarray(s["expert_rows"]),
                    rows_total(np.asarray(s["expert_rows_total"])))
             for name, s in jax.device_get(state).items()}
+
+
+def mtp_losses(net):
+    """``(L_main, L_mtp)`` of the last step as floats, or None for a net
+    without a multi-token-prediction module."""
+    for s in (net.state or {}).values():
+        if "mtp_loss" in s:
+            return tuple(float(v) for v in np.asarray(s["mtp_loss"]))
+    return None
 
 
 def install(net) -> None:
@@ -63,6 +77,17 @@ def install(net) -> None:
             for i, n in enumerate(last):
                 rows.add(float(n), {"layer": name,
                                     "expert": str(first[name] + i)})
-        return [pairs, rows]
+        families = [pairs, rows]
+        for name, s in live.state.items():
+            if "mtp_loss" in s:
+                parts = MetricFamily(
+                    "dl4j_mtp_loss", "gauge",
+                    "The last step's next-token loss (main) and the "
+                    "prediction module's (mtp), unweighted")
+                for part, value in zip(("main", "mtp"),
+                                       np.asarray(s["mtp_loss"])):
+                    parts.add(float(value), {"layer": name, "part": part})
+                families.append(parts)
+        return families
 
     net._moe_collector = get_registry().register_collector(collect)

@@ -5,14 +5,15 @@ products of an expert layer that holds some of a model's experts
 An expert has one of two forms, told apart by whether the call brings a
 gate matrix: ``down(silu(gate x) * up x)``, three matrices and nine
 products a training step (the block-diffusion decoder's,
-``zoo.sdar_moe``); or ``down(max(up x, 0)^2)``, two matrices and six
+``zoo.sdar_moe``, and the latent-attention decoder's,
+``zoo.glm4_moe_lite``); or ``down(max(up x, 0)^2)``, two matrices and six
 products (the hybrid decoder's, ``zoo.nemotron_h``). The chunk loop runs
 both (``_hidden``, ``_hidden_and_slopes`` are all that differs); the
 kernels are written for the gated form alone, and the one configuration
 without a gate has the published width 1,856, 14.5 lane tiles, which
 they would refuse anyway (ROADMAP Queue 2 item 6 has both steps). A shared
 expert that every row takes is no business of this module: it is two
-plain products in the layer.
+or three plain products in the layer.
 
 The pairs arrive sorted by held expert: pair ``p`` is row ``rows[p]`` of
 ``x`` weighted by ``coef[p]``, expert ``e`` owns the ``counts[e]`` pairs
@@ -24,7 +25,9 @@ whatever the counts and nothing is padded into the result. Which one
 runs is decided by ``grouped_supported`` from what the call can see.
 
 **Pallas kernels** (a TPU, or the tests' interpret mode; ``d`` and ``f``
-multiples of 128; an expert's matrices small enough to stay in VMEM).
+multiples of 128; an expert's matrices small enough to stay in VMEM, or
+cut into equal slices of the hidden width that are, run one after the
+other inside a block: ``_width_slices``).
 The sorted pairs are walked in blocks of ``experts * chunk`` pairs (the
 share's expected load and half as much again: one block a layer unless
 the router is biased), ``ceil(sum(counts) / block)`` of them. A block
@@ -240,12 +243,38 @@ def _vmem_request(d: int, f: int, itemsize: int) -> int:
     return weights + rows + 12 * TILE * f * 4
 
 
+def _width_slices(d: int, f: int, itemsize: int) -> int:
+    """In how many equal slices of whole lane tiles the kernels take an
+    expert's hidden width ``f``: the fewest whose three matrices stay in
+    VMEM, 0 where none does. A gated expert is a sum over slices of its
+    hidden width, ``y = sum_s (silu(x Wg[:, s]) * x Wu[:, s]) Wd[s, :]``,
+    and so are all its gradients but the slices' own, so a width past
+    the cap runs the same kernels a slice and adds."""
+    if f % LANES:
+        return 0
+    tiles = f // LANES
+    return next((n for n in range(1, tiles + 1) if tiles % n == 0
+                 and _vmem_request(d, f // n, itemsize) <= _VMEM_CAP), 0)
+
+
+def _sliced(wg, wu, wd):
+    """The three matrices of every slice ``_width_slices`` asks for
+    (themselves where it asks for one)."""
+    _, d, f = wg.shape
+    n = _width_slices(d, f, wg.dtype.itemsize)
+    if n == 1:
+        return [(wg, wu, wd)]
+    cuts = [slice(i * f // n, (i + 1) * f // n) for i in range(n)]
+    return [(wg[:, :, c], wu[:, :, c], wd[:, c, :]) for c in cuts]
+
+
 def grouped_supported(x, wg, wu, wd, n_pairs: int, chunk: int) -> bool:
     """Whether the kernels cover this call: widths in whole lane tiles,
-    a dtype the MXU takes, one expert's matrices resident in VMEM, a
-    block's rows listed in SMEM, and a TPU (or the tests' interpret
-    mode) to run them. They are written for the gated form: an expert
-    without a gate (``wg`` None) takes the chunk loop."""
+    a dtype the MXU takes, one expert's matrices (or an equal slice of
+    them, ``_width_slices``) resident in VMEM, a block's rows listed in
+    SMEM, and a TPU (or the tests' interpret mode) to run them. They are
+    written for the gated form: an expert without a gate (``wg`` None)
+    takes the chunk loop."""
     if wg is None:
         return False
     n_experts, d, f = wg.shape
@@ -255,7 +284,7 @@ def grouped_supported(x, wg, wu, wd, n_pairs: int, chunk: int) -> bool:
         return False
     if any(w.dtype != x.dtype for w in (wg, wu, wd)):
         return False
-    if _vmem_request(d, f, x.dtype.itemsize) > _VMEM_CAP:
+    if not _width_slices(d, f, x.dtype.itemsize):
         return False
     block = _block_pairs(n_pairs, n_experts, chunk)
     if block + n_experts * TILE > _TABLE_CAP:
@@ -570,6 +599,8 @@ def _kernel_calls(n_tiles: int, n_rows: int, n_experts: int, d: int, f: int,
 
 
 def _calls_for(x, wg, block: int):
+    """The kernels for one slice of the hidden width (``wg``: a slice's
+    gate matrices)."""
     return _kernel_calls(block // TILE + wg.shape[0], x.shape[0], *wg.shape,
                          x.dtype.name, _interpret())
 
@@ -585,13 +616,16 @@ def _n_blocks(counts, block: int):
 
 def _kernel_forward(x, rows, coef, counts, wg, wu, wd, chunk):
     block = _block_pairs(rows.shape[0], counts.shape[0], chunk)
-    ffn, _, _ = _calls_for(x, wg, block)
+    slices = _sliced(wg, wu, wd)
+    ffn, _, _ = _calls_for(x, slices[0][0], block)
 
     def add_block(b, y):
         with jax.named_scope("route"):
             tables, idx, w, _ = _layout(b, counts, rows, coef, block=block)
             xs = jnp.take(x, idx, axis=0)
-        return ffn(*tables, idx, xs, w, wg, wu, wd, y)
+        for ws in slices:
+            y = ffn(*tables, idx, xs, w, *ws, y)
+        return y
 
     y = jax.lax.fori_loop(0, _n_blocks(counts, block), add_block, _sums(x))
     return y.reshape(x.shape)
@@ -600,7 +634,8 @@ def _kernel_forward(x, rows, coef, counts, wg, wu, wd, chunk):
 def _kernel_backward(x, rows, coef, counts, wg, wu, wd, dy, chunk):
     n_pairs = rows.shape[0]
     block = _block_pairs(n_pairs, counts.shape[0], chunk)
-    _, backward_rows, backward_weights = _calls_for(x, wg, block)
+    slices = _sliced(wg, wu, wd)
+    _, backward_rows, backward_weights = _calls_for(x, slices[0][0], block)
     dy = dy.astype(x.dtype)
 
     def add_block(b, carry):
@@ -610,13 +645,18 @@ def _kernel_backward(x, rows, coef, counts, wg, wu, wd, dy, chunk):
                                             block=block)
             xs = jnp.take(x, idx, axis=0)
             dys = jnp.take(dy, idx, axis=0)
-        dx, dcoef_b, dgate, dup, hw = backward_rows(
-            *tables, idx, xs, dys, w, wg, wu, wd, dx)
-        dws = list(backward_weights(*tables, b[None], xs, dys, dgate, dup,
-                                    hw, *dws))
+        dcoef_b = []
+        for i, ws in enumerate(slices):
+            dx, dcoef_s, dgate, dup, hw = backward_rows(
+                *tables, idx, xs, dys, w, *ws, dx)
+            dws[i] = list(backward_weights(*tables, b[None], xs, dys, dgate,
+                                           dup, hw, *dws[i]))
+            dcoef_b.append(dcoef_s[:, 0])
         with jax.named_scope("route"):
+            # a pair's weight is worth the sum of what its slices say
             dcoef = jax.lax.dynamic_update_slice(
-                dcoef, _gather_back(dcoef_b[:, 0], moves, block=block),
+                dcoef, _gather_back(functools.reduce(jnp.add, dcoef_b),
+                                    moves, block=block),
                 (b * block,))
         return dx, dcoef, dws
 
@@ -624,10 +664,13 @@ def _kernel_backward(x, rows, coef, counts, wg, wu, wd, dy, chunk):
     dx, dcoef, dws = jax.lax.fori_loop(
         0, _n_blocks(counts, block), add_block,
         (_sums(x), jnp.zeros((n_pairs + block,), jnp.float32),
-         [jnp.zeros_like(w) for w in (wg, wu, wd)]))
+         [[jnp.zeros_like(w) for w in ws] for ws in slices]))
     dx = dx.reshape(x.shape)
     live = jnp.arange(n_pairs, dtype=jnp.int32) < jnp.sum(counts)
-    return (dx, jnp.where(live, dcoef[:n_pairs], 0.0), *dws)
+    if len(dws) > 1:
+        dws = [[jnp.concatenate(of_slices, axis=axis) for of_slices, axis
+                in zip(zip(*dws), (2, 2, 1))]]
+    return (dx, jnp.where(live, dcoef[:n_pairs], 0.0), *dws[0])
 
 
 # ------------------------------------------------------------ the one entry

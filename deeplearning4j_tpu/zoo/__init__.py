@@ -7,6 +7,7 @@ from deeplearning4j_tpu.zoo.models import (
     F32,
     VGG16_MEAN_RGB,
     char_rnn,
+    glm4_moe_lite,
     gpt_mini,
     gpt_mini_draft,
     gpt_mini_tp_rules,
@@ -20,6 +21,7 @@ from deeplearning4j_tpu.zoo.models import (
     vgg16_preprocess,
 )
 
-__all__ = ["BF16", "F32", "VGG16_MEAN_RGB", "char_rnn", "gpt_mini",
+__all__ = ["BF16", "F32", "VGG16_MEAN_RGB", "char_rnn", "glm4_moe_lite",
+           "gpt_mini",
            "gpt_mini_draft", "gpt_mini_tp_rules", "lenet", "mnist_mlp",
            "nemotron_h", "resnet18", "resnet50", "sdar_moe", "vgg16", "vgg16_preprocess"]

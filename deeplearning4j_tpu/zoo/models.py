@@ -391,6 +391,82 @@ def nemotron_h(seed: int = 42, pattern: str = NEMOTRON_H_PATTERN,
     return net
 
 
+def glm4_moe_lite(seed: int = 42, n_layers: int = 47, first_dense: int = 1,
+                  n_experts: int = 64, experts_held: Optional[int] = None,
+                  first_expert: int = 0, vocab_size: int = 154_880,
+                  hidden: int = 2048, n_heads: int = 20, q_rank: int = 768,
+                  kv_rank: int = 512, nope_dim: int = 192,
+                  rope_dim: int = 64, v_dim: int = 256,
+                  mlp_width: int = 10_240, expert_width: int = 1536,
+                  shared_width: int = 1536, experts_per_token: int = 4,
+                  routed_scale: float = 1.8, rope_theta: float = 1e6,
+                  eps: float = 1e-5, mtp_modules: int = 1,
+                  mtp_weight: float = 0.3, learning_rate: float = 1e-5,
+                  dtype: Optional[DtypePolicy] = None) -> MultiLayerNetwork:
+    """GLM-4.7-Flash (``model_type`` glm4_moe_lite; the defaults are its
+    published config.json): a causal decoder of ``n_layers`` layers of
+    latent attention (queries and keys/values expanded from compressed
+    rows, rotary positions over ``rope_dim`` columns of a head), the
+    first ``first_dense`` followed by a dense gated silu MLP and the
+    others by routed gated silu experts under a sigmoid router with a
+    correction bias, renormalised and scaled, beside a shared expert;
+    and ``mtp_modules`` (0 or 1) multi-token-prediction module, one more
+    expert layer that predicts the token after the next through the
+    model's own embedding and head. Integer ids in; integer labels
+    ``[b, 2, t]`` out (the next token and the one after; ``[b, t]``
+    without the module).
+
+    ``experts_held`` and ``first_expert`` give this chip's share of the
+    routed experts under expert parallelism (the router still scores all
+    ``n_experts``; attention, the dense layers and the shared expert are
+    whole on every chip); ``vocab_size`` is the slice of the vocabulary
+    held here.
+
+    Init, for every seed alike: matrices normal(0, 0.02) (the family's
+    ``initializer_range``), norm weights 1, router bias 0, and the
+    embedding rows normal(0, 1) as the other two decoders' (every block
+    norms its input, so the scale only sets how much of the stream the
+    first layers replace; PERF.md Findings PR 37 has the pairs a layer
+    is given at both scales)."""
+    from deeplearning4j_tpu.nn.conf.layers_decoder import (
+        LatentDenseBlock, LatentMoeBlock, MtpTokenOutput, RmsNorm,
+        TokenEmbedding, TokenOutput)
+    if mtp_modules not in (0, 1):
+        raise ValueError(
+            f"glm4_moe_lite: {mtp_modules} prediction modules; the output "
+            "layer holds one or none")
+    if not 0 <= first_dense <= n_layers:
+        raise ValueError(
+            f"glm4_moe_lite: {first_dense} dense layers among {n_layers}")
+    attention = dict(n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank,
+                     nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+                     rope_theta=rope_theta, eps=eps)
+    experts = dict(attention, n_experts=n_experts,
+                   experts_per_token=experts_per_token,
+                   expert_width=expert_width, experts_held=experts_held,
+                   first_expert=first_expert, router="sigmoid",
+                   routed_scale=routed_scale, expert_form="gated_silu",
+                   shared_width=shared_width)
+    b = (NeuralNetConfiguration.builder()
+         .seed(seed).updater(Adam(learning_rate)).dtype(dtype or BF16)
+         .weight_init({"type": "normal", "mean": 0.0, "std": 0.02})
+         .list()
+         .layer(TokenEmbedding(n_out=hidden, weight_init={
+             "type": "normal", "mean": 0.0, "std": 1.0})))
+    for i in range(n_layers):
+        b = b.layer(LatentDenseBlock(mlp_width=mlp_width, **attention)
+                    if i < first_dense else LatentMoeBlock(**experts))
+    if mtp_modules:
+        b = b.layer(MtpTokenOutput(
+            vocab_size=vocab_size, mtp_weight=mtp_weight,
+            embedding="layer_0", activation="identity", **experts))
+    else:
+        b = b.layer(RmsNorm(eps=eps)).layer(TokenOutput(
+            n_out=vocab_size, activation="identity", causal=True))
+    return MultiLayerNetwork(
+        b.set_input_type(InputType.recurrent(vocab_size)).build()).init()
+
+
 def gpt_mini_draft(vocab_size: int = 80, width: int = 128,
                    n_layers: int = 2, n_heads: int = 2, max_len: int = 256,
                    max_cache_len: Optional[int] = None, seed: int = 43,

@@ -144,7 +144,10 @@ def test_a_compile_inside_a_trace_is_booked_once(tracer):
 
 def test_stages_go_to_the_span_that_caused_them(tracer):
     base = obs.stage_snapshot()
-    net = MultiLayerNetwork(_conf()).init()
+    # a width no other test's net has: a worker that has built a 6 -> 5
+    # -> 3 net before (tests/test_opindex.py does) holds its init's
+    # programs, and net_init would compile nothing
+    net = MultiLayerNetwork(_conf(hidden=11)).init()
     net.output(_batch(4).features)
     net.fit(_batch(16).features, _batch(16).labels, epochs=1, batch_size=4,
             multi_step=2)

@@ -266,3 +266,48 @@ def test_hybrid_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     # (67 MB); the xla executor's decay matrices and their products held
     # several hundred
     assert compiled.memory_analysis().temp_size_in_bytes < 2e8
+
+
+def test_latent_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
+    """What the cell ``glm4_7_flash-train-b1-l4096`` asks of the chip's
+    compilers at its own shape: the tiled attention kernels under the
+    causal tables with 20 query heads on 20 key/value heads of 256 (a
+    group of 1: 128 rows a tile against 512 keys), and the grouped
+    experts' kernels at a hidden width of 1,536, whose three matrices
+    pass the VMEM cap whole and run as two slices of 768 inside one
+    block (the forward and both backward kernels twice each)."""
+    from deeplearning4j_tpu.nn.layers.decoder import expert_chunk_rows
+    from deeplearning4j_tpu.ops import attention as att
+    from deeplearning4j_tpu.ops import grouped
+
+    seq, cd = 4096, jnp.bfloat16
+    q, k, v = (jax.ShapeDtypeStruct((1, seq, 20, 256), cd, sharding=one_chip)
+               for _ in range(3))
+
+    def attend(q, k, v):
+        out = att._bd_join(att._causal_tiled(*att._bd_split(q, k, v)), 1)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+    rows, d, f, held, experts, top_k = seq, 2048, 1536, 8, 64, 4
+    pairs, chunk = rows * top_k, expert_chunk_rows(rows, top_k, experts)
+    assert chunk == 384                 # 256 pairs an expert expected
+    shapes = [((rows, d), cd), ((pairs,), jnp.int32), ((pairs,), jnp.float32),
+              ((held,), jnp.int32), ((held, d, f), cd), ((held, d, f), cd),
+              ((held, f, d), cd)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    assert grouped._vmem_request(d, f, 2) > grouped._VMEM_CAP
+    assert grouped._width_slices(d, f, 2) == 2
+    assert grouped._vmem_request(d, f // 2, 2) <= grouped._VMEM_CAP
+
+    def loss(x, rows, coef, counts, wg, wu, wd):
+        y = grouped._expert_ffn(x, rows, coef, counts, wg, wu, wd, chunk,
+                                True)
+        return jnp.sum(y * y)
+
+    text = jax.jit(jax.grad(loss, (0, 2, 4, 5, 6))).lower(*args).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
