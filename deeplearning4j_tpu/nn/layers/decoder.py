@@ -382,7 +382,15 @@ class _LatentHeads:
     ``n_heads``, ``q_rank``, ``kv_rank``, ``nope_dim``, ``rope_dim``,
     ``v_dim`` and ``rope_theta``: parameters ``attn_ln_g``, ``W_dq``,
     ``q_ln_g``, ``W_uq``, ``W_dkv``, ``kv_ln_g``, ``W_ukv``, ``Wo``
-    (nn/conf/layers_decoder.py ``LatentMoeBlock`` has the equations)."""
+    (nn/conf/layers_decoder.py ``LatentMoeBlock`` has the equations).
+
+    Every head has keys and values of its own, so ``causal_attention``
+    sees a group of 1. This layer passes no tile: ops/attention.py reads
+    the group from the operands (``_bd_query_tile``) and gives a group
+    of 1 a query tile of 512 positions where the grouped-query layers'
+    8 or 16 heads a key/value head get 128, about 1,024 rows a grid
+    step either way, because a grid step's fixed cost is the same
+    whatever it holds (PERF.md, Findings PR 38)."""
 
     def _check_latent(self):
         conf = self.conf

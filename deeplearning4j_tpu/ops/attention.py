@@ -380,7 +380,13 @@ def extend_cache(k_cache, v_cache, k_new, v_new, pos):
 #   (dK/dV kernel and dQ kernel, from the forward's saved row statistics)
 #   whose scores never leave VMEM. One grid step takes the G = Hq/Hkv
 #   query heads of a group against one key/value tile, so a key tile is
-#   read once for 8 heads and the MXU streams G * bq rows per tile. The
+#   read once for 8 heads and the MXU streams G * bq rows per tile.
+#   The query tile ``bq`` follows the group (``_bd_query_tile``): a
+#   grid step has a fixed cost of about a microsecond whatever it
+#   holds, so the tile is the one that brings G * bq to about 1,024
+#   rows: 128 positions at groups of 8 and 16, 512 at a group of 1,
+#   where 128 rows against 512 keys were 0.34 us of products in a step
+#   of 1.21 (PERF.md, Findings PR 38). The
 #   grid is the list of LIVE (query tile, key tile) pairs, made on the
 #   host from the rule and handed to the kernels as scalar-prefetch
 #   tables: a tile pair with no visible entry costs no grid step and no
@@ -450,23 +456,39 @@ def _dense_masked_mha(q, k, v, visible):
     return out.reshape(b, t, hq, dh).astype(q.dtype)
 
 
-_BD_BQ = 128            # query positions a tile (times G heads = rows)
+_BD_STEP_ROWS = 1024    # rows (G heads x query positions) a grid step aims at
 
 
 def _bd_key_tile(seq_len: int) -> int:
     return next(bk for bk in (512, 256, 128) if seq_len % bk == 0)
 
 
+def _bd_query_tile(group: int, seq_len: int) -> int:
+    """Query positions a tile, times ``group`` heads = the rows one grid
+    step streams through the MXU: the largest power of two that keeps a
+    step within ``_BD_STEP_ROWS`` rows, no less than 128 positions (the
+    lane tile of the row statistics) and no more than the key tile (at a
+    group of 1 a tile of 1,024 against keys of 512 holds 1.25 times the
+    visible entries where 512 holds 1.125, and read 0.7% slower in the
+    step on the v5e). The key tile divides ``seq_len``, so this one does
+    and no tile straddles the two halves of a block-diffusion sequence.
+    512 at a group of 1, 256 at 4, 128 from 8 on."""
+    want = min(max(_BD_STEP_ROWS // group, 128), _bd_key_tile(seq_len))
+    return next(bq for bq in (512, 256, 128) if bq <= want)
+
+
 def block_attention_supported(q, k, v, seq_len: int, block_len: int) -> bool:
     """Whether the kernels cover this call: tiles may not straddle the
-    two halves, blocks are a power of two (the rule is shifts and
-    compares in the kernel), heads of 128."""
+    two halves or a block, blocks are a power of two (the rule is shifts
+    and compares in the kernel), heads of 128."""
     b, t, hq, dh = q.shape
     if t != 2 * seq_len or seq_len % 128 or dh % 128:
         return False
-    if block_len & (block_len - 1) or _BD_BQ % block_len:
+    if not _bd_operands_supported(q, k, v):
         return False
-    return _bd_operands_supported(q, k, v)
+    if block_len & (block_len - 1):
+        return False
+    return _bd_query_tile(hq // k.shape[2], seq_len) % block_len == 0
 
 
 def _bd_operands_supported(q, k, v) -> bool:
@@ -792,11 +814,24 @@ def _bd_shift(block_len) -> int:
     return 0 if block_len is None else block_len.bit_length() - 1
 
 
+def _count_tiled_attention(direction: str, group: int, bq: int) -> None:
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_tiled_attention_calls_total",
+        "Tiled attention kernel calls traced (a forward kernel, or a dQ "
+        "and a dK/dV kernel), by direction, by the query heads a key/value "
+        "head and by the query positions a tile that group was given",
+        ("direction", "group", "query_tile")).labels(
+            direction=direction, group=str(group), query_tile=str(bq)).inc()
+
+
 def _bd_forward(qg, kg, vg, seq_len, block_len):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, g, t, dh = qg.shape
-    bq, bk = _BD_BQ, _bd_key_tile(seq_len)
+    bq, bk = _bd_query_tile(g, seq_len), _bd_key_tile(seq_len)
+    _count_tiled_attention("forward", g, bq)
     tables = _bd_tables(seq_len, block_len, bq, bk, key_major=False)
     rows, stats, keys = _bd_specs(g, bq, bk, dh)
     return _bd_call(
@@ -815,7 +850,8 @@ def _bd_backward(qg, kg, vg, og, lse, dog, seq_len, block_len):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, g, t, dh = qg.shape
-    bq, bk = _BD_BQ, _bd_key_tile(seq_len)
+    bq, bk = _bd_query_tile(g, seq_len), _bd_key_tile(seq_len)
+    _count_tiled_attention("backward", g, bq)
     static = dict(scale=1.0 / math.sqrt(dh), seq_len=seq_len,
                   shift=_bd_shift(block_len))
     di = jnp.sum(og.astype(jnp.float32) * dog.astype(jnp.float32), axis=-1)

@@ -451,7 +451,8 @@ def test_routed_experts_refuse_an_unknown_form():
 
 # ------------------------------------------------------- causal attention
 @pytest.mark.parametrize("rows,bq,bk", [(512, 128, 128), (512, 128, 256),
-                                        (1024, 128, 512), (384, 128, 128)])
+                                        (1024, 128, 512), (384, 128, 128),
+                                        (2048, 512, 512), (1024, 256, 512)])
 def test_causal_live_tiles_are_those_with_a_visible_entry(rows, bq, bk):
     i = np.arange(rows)
     visible = i[:, None] >= i[None, :]
@@ -464,6 +465,47 @@ def test_causal_live_tiles_are_those_with_a_visible_entry(rows, bq, bk):
         2, 128, 0, rows, 0, jnp.arange(bq)[:, None], jnp.arange(bk)[None, :])
     np.testing.assert_array_equal(
         np.asarray((kb <= upper) & (kb >= lower)), visible[128:128 + bq, :bk])
+
+
+@pytest.mark.parametrize("why,heads,kv_heads,dh,seq,block,want", [
+    ("sdar_30b_a3b-train-b1-l4096", 32, 4, 128, 4096, 4, 128),
+    ("nemotron3_nano_30b_a3b-train-b1-l4096", 32, 2, 128, 4096, None, 128),
+    ("glm4_7_flash-train-b1-l4096", 20, 20, 256, 4096, None, 512),
+    ("a group of 4", 8, 2, 128, 4096, None, 256),
+    ("a group of 2 stops at the key tile", 4, 2, 128, 4096, None, 512),
+    ("384 rows hold no tile of 256 or 512", 2, 2, 128, 384, None, 128),
+    ("halves of 768 rows hold tiles of 256", 2, 2, 128, 768, 4, 256)])
+def test_query_tile_follows_the_group(why, heads, kv_heads, dh, seq, block,
+                                      want):
+    """The tiled kernels' query tile is a function of the group and the
+    sequence (no option selects it): about 1,024 rows a grid step, so
+    the two accepted decoder cells keep the tile of 128 they had and a
+    group of 1 gets 512. Traced at the cells' own shapes, nothing runs;
+    the counter says which tile each direction was given."""
+    group = heads // kv_heads
+    assert att._bd_query_tile(group, seq) == want, why
+    rows = seq if block is None else 2 * seq
+    q, k, v = (jax.ShapeDtypeStruct((1, rows, h, dh), jnp.bfloat16)
+               for h in (heads, kv_heads, kv_heads))
+
+    def attend(q, k, v):
+        qg, kg, vg = att._bd_split(q, k, v)
+        og = (att._causal_tiled(qg, kg, vg) if block is None
+              else att._bd_attention(qg, kg, vg, seq, block))
+        return jnp.sum(att._bd_join(og, 1).astype(jnp.float32))
+
+    def counts():
+        return {(d, tile): _count(
+            "dl4j_tiled_attention_calls_total", direction=d,
+            group=str(group), query_tile=str(tile))
+            for d in ("forward", "backward") for tile in (128, 256, 512)}
+
+    before = counts()
+    jax.eval_shape(jax.grad(attend, (0, 1, 2)), q, k, v)
+    after = counts()
+    assert {key: after[key] - before[key] for key in after} == {
+        (d, tile): float(tile == want)
+        for d in ("forward", "backward") for tile in (128, 256, 512)}, why
 
 
 def _dense_causal(q, k, v):
@@ -480,8 +522,13 @@ def _dense_causal(q, k, v):
     # the cell's group, 16 query heads a key/value head (32 on 2), and a
     # query tile that meets two key tiles, so the running max and sum are
     # rescaled
-    ("pallas", 1024, 128, 16, 1)],
-    ids=["pallas-512-128", "xla-24-16", "pallas-1024-128-16on1"])
+    ("pallas", 1024, 128, 16, 1),
+    # the latent decoder cell's group of 1 and heads of 256: a query tile
+    # of 512 that meets two key tiles and a diagonal tile; and a group of
+    # 4, a tile of 256 (two query tiles a key tile)
+    ("pallas", 1024, 256, 2, 2), ("pallas", 512, 128, 4, 1)],
+    ids=["pallas-512-128", "xla-24-16", "pallas-1024-128-16on1",
+         "pallas-1024-256-1on1", "pallas-512-128-4on1"])
 def test_causal_attention_matches_dense_masked_softmax(monkeypatch, backend,
                                                        rows, dh, heads,
                                                        kv_heads):

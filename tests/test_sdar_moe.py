@@ -146,7 +146,8 @@ def test_mask_rule_matches_a_brute_force_table(seq, block):
 
 
 @pytest.mark.parametrize("seq,bq,bk", [(256, 128, 128), (256, 128, 256),
-                                       (512, 128, 512), (384, 128, 128)])
+                                       (512, 128, 512), (384, 128, 128),
+                                       (512, 512, 512), (512, 256, 512)])
 def test_live_tiles_are_those_with_a_visible_entry(seq, bq, bk):
     table = _table(seq, 4)
     want = {(qi, ki) for qi in range(2 * seq // bq)
@@ -184,9 +185,12 @@ F32_4ON2 = (4, 2, jnp.float32, 2e-5)
     ("xla", 20, 4, *F32_4ON2), ("xla", 12, 3, *F32_4ON2),
     ("pallas", 256, 4, *F32_4ON2), ("pallas", 128, 8, *F32_4ON2),
     ("pallas", 256, 4, 8, 1, jnp.float32, 2e-5),
-    ("pallas", 128, 4, 8, 1, jnp.bfloat16, 2e-2)],
+    ("pallas", 128, 4, 8, 1, jnp.bfloat16, 2e-2),
+    # a group of 1: the query tile is 512 positions, a whole half of the
+    # rows, against key tiles of 512 of all three kinds
+    ("pallas", 512, 4, 2, 2, jnp.float32, 2e-5)],
     ids=["xla-20-4", "xla-12-3", "pallas-256-4", "pallas-128-8",
-         "pallas-256-4-8on1", "pallas-128-4-8on1-bf16"])
+         "pallas-256-4-8on1", "pallas-128-4-8on1-bf16", "pallas-512-4-1on1"])
 def test_attention_matches_dense_masked_softmax(monkeypatch, backend, seq,
                                                 block, heads, kv_heads,
                                                 dtype, rtol):

@@ -272,10 +272,12 @@ def test_latent_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     """What the cell ``glm4_7_flash-train-b1-l4096`` asks of the chip's
     compilers at its own shape: the tiled attention kernels under the
     causal tables with 20 query heads on 20 key/value heads of 256 (a
-    group of 1: 128 rows a tile against 512 keys), and the grouped
-    experts' kernels at a hidden width of 1,536, whose three matrices
-    pass the VMEM cap whole and run as two slices of 768 inside one
-    block (the forward and both backward kernels twice each)."""
+    group of 1, which the rule gives a query tile of 512: 512 rows a
+    grid step against 512 keys, 36 live tile pairs a head, within the
+    64 MB VMEM limit), and the grouped experts' kernels at a hidden
+    width of 1,536, whose three matrices pass the VMEM cap whole and run
+    as two slices of 768 inside one block (the forward and both backward
+    kernels twice each)."""
     from deeplearning4j_tpu.nn.layers.decoder import expert_chunk_rows
     from deeplearning4j_tpu.ops import attention as att
     from deeplearning4j_tpu.ops import grouped
@@ -288,6 +290,10 @@ def test_latent_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
         out = att._bd_join(att._causal_tiled(*att._bd_split(q, k, v)), 1)
         return jnp.sum(out.astype(jnp.float32))
 
+    assert att._bd_query_tile(1, seq) == att._bd_key_tile(seq) == 512
+    # live tiles only: 36 of the 64 tile pairs of 512 x 512; Mosaic takes
+    # them with ``vmem_limit_bytes=_BD_VMEM_LIMIT`` or raises here
+    assert len(att._causal_live_tiles(seq, 512, 512)) == 36
     text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
