@@ -1,9 +1,10 @@
 """Configs of a modern decoder on the net's own path: integer token ids
 in, RMS norm, rotary positions, grouped-query heads with per-head
 query/key norm, routed experts of which this chip holds a share, a
-state-space mixer, and a head with integer labels and per-token weights.
+state-space mixer, a gated short convolution, and a head with integer
+labels and per-token weights.
 
-Three decoders are built of them (PERF.md section 4 has the models):
+Four decoders are built of them (PERF.md section 4 has the models):
 
 - a block-diffusion decoder (``zoo.sdar_moe``): ``TokenEmbedding``,
   ``MoeDecoderBlock`` (attention under the block-diffusion mask, then a
@@ -25,28 +26,44 @@ Three decoders are built of them (PERF.md section 4 has the models):
   ``MtpTokenOutput``: the final norm, the head, and a multi-token-
   prediction module that reads the embedding's matrix and the head's
   own (``RmsNorm`` and ``TokenOutput(causal=True)`` without the module).
+- a causal short-convolution / attention decoder (``zoo.lfm2_moe``):
+  ``TokenEmbedding``, then one operator and one feed-forward a layer in
+  the order of a pattern string: the operator a gated short convolution
+  (``ShortConv*Block``) or causal grouped-query attention with head
+  norms and rotation (``Causal*Block``), the feed-forward a dense gated
+  silu MLP in the leading layers (``*DenseBlock``) and sigmoid-routed
+  gated silu experts without a shared one in the rest (``*MoeBlock``);
+  ``RmsNorm`` and ``TokenOutput(causal=True, tied_to=<the embedding>)``,
+  a head that reads the embedding's own matrix.
 
 Which decoder uses what:
 
-    ======================  ==========  ==========  ==============
-                            sdar_moe    nemotron_h  glm4_moe_lite
-    ======================  ==========  ==========  ==============
-    TokenEmbedding          x           x           x (two users)
-    RmsNorm                 x           x           without module
-    TokenOutput             causal=F    causal=T    without module
+    ======================  ==========  ==========  ==============  ==========
+                            sdar_moe    nemotron_h  glm4_moe_lite   lfm2_moe
+    ======================  ==========  ==========  ==============  ==========
+    TokenEmbedding          x           x           x (two users)   x (tied)
+    RmsNorm                 x           x           without module  x
+    TokenOutput             causal=F    causal=T    without module  tied_to
     MoeDecoderBlock         x
     CausalAttention                     x
     Mamba2Mixer                         x
-    RoutedExperts           (in block)  x           (in blocks)
-      router                softmax     sigmoid     sigmoid
-      expert_form           gated_silu  relu2       gated_silu
-      shared expert         none        relu2       gated_silu
+    RoutedExperts           (in block)  x           (in blocks)     (in blocks)
+      router                softmax     sigmoid     sigmoid         sigmoid
+      router_eps            1e-20       1e-20       1e-20           1e-6
+      expert_form           gated_silu  relu2       gated_silu      gated_silu
+      shared expert         none        relu2       gated_silu      none
     LatentDenseBlock                                x
     LatentMoeBlock                                  x
     MtpTokenOutput                                  with module
-    ops/attention.py        block_diff  causal      causal
-    ops/grouped.py          kernels     chunk loop  kernels, 2 slices
-    ======================  ==========  ==========  ==============
+    ShortConvDenseBlock                                             x
+    ShortConvMoeBlock                                               x
+    CausalDenseBlock                                                (pattern)
+    CausalMoeBlock                                                  x
+    ops/attention.py        block_diff  causal      causal          causal, 64
+    ops/grouped.py          kernels     chunk loop  kernels, 2 sl.  kernels, 2 sl.
+    ops/ssm.py                          x
+    ops/shortconv.py                                                x
+    ======================  ==========  ==========  ==============  ==========
 
 Layout as the recurrent family: ``[batch, time, features]``, but the
 first layer takes ``[batch, time]`` integer ids (``InputType.recurrent(
@@ -116,8 +133,9 @@ class RoutedExperts(_WidthPreserving):
     (the hybrid decoder's) scores ``s = sigmoid(W_r w)``, chooses the
     largest of ``s + bias`` (``router_bias`` in the layer's state: a
     buffer no gradient reaches, zero at init) and weighs a chosen expert
-    by ``s`` without the bias, renormalised. Either way the weights are
-    multiplied by ``routed_scale``.
+    by ``s`` without the bias, renormalised: ``s_e / (sum of the chosen
+    + router_eps)``. Either way the weights are multiplied by
+    ``routed_scale``.
     ``expert_form``: ``"gated_silu"``, ``h = silu(gate w) * up w``, three
     matrices an expert (``Wg``, ``Wu``, ``Wd``); or ``"relu2"``, ``h =
     max(up w, 0)^2``, two (``Wu``, ``Wd``).
@@ -137,26 +155,41 @@ class RoutedExperts(_WidthPreserving):
     routed_scale: float = 1.0
     expert_form: str = "gated_silu"
     shared_width: int = 0
+    router_eps: float = 1e-20   # in the sigmoid router's denominator
 
     def make_layer(self, input_type, global_conf, policy):
         from deeplearning4j_tpu.nn.layers.decoder import RoutedExpertsLayer
         return RoutedExpertsLayer(self, input_type, global_conf, policy)
 
 
-@register_layer
 @dataclass(frozen=True)
-class MoeDecoderBlock(RoutedExperts):
-    """One decoder layer: pre-norm grouped-query attention under the
-    block-diffusion mask with a residual (``n_heads`` query heads reading
-    ``n_kv_heads`` key/value heads of ``head_dim``, RMS norm on every
-    query and key head, rotary positions over the whole head), then the
-    routed experts above."""
+class _RotatedHeads:
+    """Fields of a causal grouped-query attention with head norms and
+    rotation, for ``u = RMSNorm(x)`` and positions ``p = 0..t-1``:
 
-    layer_type = "moe_decoder_block"
+        q_h = R_p RMSNorm_q(W_q,h u)   k_g = R_p RMSNorm_k(W_k,g u)   v_g = W_v,g u
+        x + W_o concat_h(softmax_{j <= i}(q_h,i . k_g(h),j / sqrt(head_dim)) v_g(h),j)
+
+    ``n_heads`` query heads read ``n_kv_heads`` key/value heads of
+    ``head_dim``; the head norms are over the head's columns, and
+    ``R_p`` rotates the whole head (column ``i`` with ``i + head_dim /
+    2``). ``MoeDecoderBlock`` has these heads under the block-diffusion
+    mask (both halves of its rows at ``0..t/2-1``)."""
+
     n_heads: int = 4
     n_kv_heads: int = 2
     head_dim: int = 32
     rope_theta: float = 1e6
+
+
+@register_layer
+@dataclass(frozen=True)
+class MoeDecoderBlock(_RotatedHeads, RoutedExperts):
+    """One decoder layer: pre-norm grouped-query attention with head
+    norms and rotation under the block-diffusion mask with a residual,
+    then the routed experts above."""
+
+    layer_type = "moe_decoder_block"
     block_len: int = 4
 
     def make_layer(self, input_type, global_conf, policy):
@@ -224,20 +257,99 @@ class LatentMoeBlock(_LatentAttention, RoutedExperts):
         return LatentMoeBlockLayer(self, input_type, global_conf, policy)
 
 
-@register_layer
 @dataclass(frozen=True)
-class LatentDenseBlock(_LatentAttention, _WidthPreserving):
-    """One decoder layer: pre-norm latent attention with a residual,
-    then a pre-norm dense MLP with one, ``a + W_d (silu(W_g w) * W_u
-    w)``, ``w = RMSNorm(a)``, of ``mlp_width``."""
+class _DenseMlp:
+    """Fields of the feed-forward of a leading dense layer: ``a + W_d
+    (silu(W_g w) * W_u w)``, ``w = RMSNorm(a)``, of ``mlp_width``."""
 
-    layer_type = "latent_dense_block"
     mlp_width: int = 128
     eps: float = 1e-5
+
+
+@register_layer
+@dataclass(frozen=True)
+class LatentDenseBlock(_LatentAttention, _DenseMlp, _WidthPreserving):
+    """One decoder layer: pre-norm latent attention with a residual,
+    then a pre-norm dense gated silu MLP with one."""
+
+    layer_type = "latent_dense_block"
 
     def make_layer(self, input_type, global_conf, policy):
         from deeplearning4j_tpu.nn.layers.decoder import LatentDenseBlockLayer
         return LatentDenseBlockLayer(self, input_type, global_conf, policy)
+
+
+@register_layer
+@dataclass(frozen=True)
+class CausalMoeBlock(_RotatedHeads, RoutedExperts):
+    """One decoder layer: pre-norm causal grouped-query attention with
+    head norms and rotation and a residual, then the routed experts
+    above."""
+
+    layer_type = "causal_moe_block"
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import CausalMoeBlockLayer
+        return CausalMoeBlockLayer(self, input_type, global_conf, policy)
+
+
+@register_layer
+@dataclass(frozen=True)
+class CausalDenseBlock(_RotatedHeads, _DenseMlp, _WidthPreserving):
+    """One decoder layer: the attention of ``CausalMoeBlock``, then a
+    pre-norm dense gated silu MLP with a residual."""
+
+    layer_type = "causal_dense_block"
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import CausalDenseBlockLayer
+        return CausalDenseBlockLayer(self, input_type, global_conf, policy)
+
+
+@dataclass(frozen=True)
+class _ShortConv:
+    """Fields of a gated short-convolution operator (the LFM2 family's
+    ``conv`` layers), for ``u = RMSNorm(x)`` of width ``d``:
+
+        [B | C | x~] = W_in u                     [3 d], in this order
+        g_t = B_t * x~_t
+        s_t,c = sum_{k < K} w_c,k g_{t-K+1+k, c}   (zeros before position 0)
+        x + W_out (C_t * s_t)
+
+    a depthwise causal filter of ``conv_kernel`` positions, no bias, no
+    activation (ops/shortconv.py computes ``C * conv(B * x~)`` in one
+    pass over ``W_in``'s output as it lies)."""
+
+    conv_kernel: int = 3
+
+
+@register_layer
+@dataclass(frozen=True)
+class ShortConvMoeBlock(_ShortConv, RoutedExperts):
+    """One decoder layer: the pre-norm short-convolution operator with a
+    residual, then the routed experts above."""
+
+    layer_type = "short_conv_moe_block"
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            ShortConvMoeBlockLayer)
+        return ShortConvMoeBlockLayer(self, input_type, global_conf, policy)
+
+
+@register_layer
+@dataclass(frozen=True)
+class ShortConvDenseBlock(_ShortConv, _DenseMlp, _WidthPreserving):
+    """One decoder layer: the pre-norm short-convolution operator with a
+    residual, then a pre-norm dense gated silu MLP with one."""
+
+    layer_type = "short_conv_dense_block"
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            ShortConvDenseBlockLayer)
+        return ShortConvDenseBlockLayer(self, input_type, global_conf,
+                                        policy)
 
 
 @register_layer
@@ -283,10 +395,15 @@ class TokenOutput(BaseRecurrentConfig):
     decoder): the head reads the first half of the rows, the noised
     copy, logits ``[b, t/2, n_out]`` and labels ``[b, t/2]``. ``causal``
     True (the hybrid decoder): every row, logits ``[b, t, n_out]`` and
-    labels ``[b, t]``, the next token."""
+    labels ``[b, t]``, the next token. ``tied_to`` names the net's
+    ``TokenEmbedding``: the head then has no matrix of its own and its
+    logits are ``rows E^T`` of that layer's ``W`` ``[n_out, width]``,
+    one stored leaf whose gradient is the gather's and the product's,
+    summed."""
 
     layer_type = "token_output"
     causal: bool = False
+    tied_to: Optional[str] = None
 
     def make_layer(self, input_type, global_conf, policy):
         from deeplearning4j_tpu.nn.layers.decoder import TokenOutputLayer

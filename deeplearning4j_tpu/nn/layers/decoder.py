@@ -1,5 +1,5 @@
 """Runtime layers of a modern decoder (configs and the equations:
-nn/conf/layers_decoder.py; PERF.md section 4 has the three models they
+nn/conf/layers_decoder.py; PERF.md section 4 has the four models they
 were written for, and that docstring says which decoder uses what).
 
 Precision under a mixed policy: parameters in the param dtype, every
@@ -21,7 +21,9 @@ key slice), ``dense_mlp`` (a dense layer's norm and three products),
 head and loss; and all the prediction module does, its own ``attn``,
 ``route``, ``experts`` and ``shared_expert`` inside it), ``mamba``
 (norm, both projections, and inside it ``ssm_conv``, ``ssm_scan`` and
-``ssm_norm`` round the three ops of ops/ssm.py), ``shared_expert`` (its
+``ssm_norm`` round the three ops of ops/ssm.py), ``conv_op`` (a
+short-convolution operator's norm and both projections, and inside it
+``short_conv`` round the op of ops/shortconv.py alone), ``shared_expert`` (its
 two or three products), ``route`` (norm, router, top-k, the sort of the
 pairs, and what
 ``ops/grouped.py`` does to move rows in XLA: a gather a block of pairs
@@ -45,7 +47,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import grouped
 from deeplearning4j_tpu.ops import initializers as init_mod
-from deeplearning4j_tpu.ops import ssm
+from deeplearning4j_tpu.ops import shortconv, ssm
 
 
 def _rms_norm(x, g, eps):
@@ -222,7 +224,8 @@ class RoutedExpertsLayer(_DecoderLayer):
         _, chosen = jax.lax.top_k(
             score + jax.lax.stop_gradient(state["router_bias"]), k)
         top = jnp.take_along_axis(score, chosen, axis=-1)
-        return chosen, top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        return chosen, top / (jnp.sum(top, axis=-1, keepdims=True)
+                              + float(self.conf.router_eps))
 
     def _route(self, params, state, a):
         """The normed rows [R, d] (float32) and the pairs held here,
@@ -318,45 +321,182 @@ class _GroupedQueryHeads:
         return x + _project(o.reshape(b, t, -1), params["Wo"], x.dtype)
 
 
-class MoeDecoderBlockLayer(_GroupedQueryHeads, RoutedExpertsLayer):
+class _RotatedHeads(_GroupedQueryHeads):
+    """``_GroupedQueryHeads`` with an RMS norm on every query and key
+    head and rotary positions over the whole head, for the layers whose
+    conf has ``rope_theta`` besides: parameters ``q_norm_g``,
+    ``k_norm_g`` too."""
+
+    def _init_rotated_heads(self, kq, kk, kv, ko):
+        dh = int(self.conf.head_dim)
+        return {**self._init_heads(kq, kk, kv, ko),
+                "q_norm_g": jnp.ones((dh,), self.param_dtype),
+                "k_norm_g": jnp.ones((dh,), self.param_dtype)}
+
+    def _rotated_heads(self, params, x, pos):
+        """``_heads`` with q and k normed and rotated, row ``i`` at
+        ``pos[i]``."""
+        conf, cd = self.conf, x.dtype
+        q, k, v = self._heads(params, x)
+        q = _rotate(_rms_norm(q, params["q_norm_g"], conf.eps),
+                    conf.rope_theta, pos).astype(cd)
+        k = _rotate(_rms_norm(k, params["k_norm_g"], conf.eps),
+                    conf.rope_theta, pos).astype(cd)
+        return q, k, v
+
+    def _causal_attention(self, params, x):
+        """``x + W_o attention(RMSNorm(x))`` of ``x`` [b, t, d] under the
+        causal rule, rows at positions 0..t-1, under the scope
+        ``attn``."""
+        with jax.named_scope("attn"):
+            q, k, v = self._rotated_heads(
+                params, x, jnp.arange(x.shape[1], dtype=jnp.int32))
+            with jax.named_scope("causal_attention"):
+                o = att.causal_attention(q, k, v)
+            return self._merge_heads(params, x, o)
+
+
+class _RotatedHeadsMoeBlock(_RotatedHeads, RoutedExpertsLayer):
+    """The parameters of ``_RotatedHeads`` beside the routed experts';
+    the mask rule is the subclass's."""
+
     def __init__(self, conf, input_type, global_conf, policy):
         super().__init__(conf, input_type, global_conf, policy)
         self._check_heads()
 
     def init_params(self, key):
-        dh = int(self.conf.head_dim)
         k_experts, kq, kk, kv, ko = jax.random.split(key, 5)
         params = super().init_params(k_experts)
-        params.update(self._init_heads(kq, kk, kv, ko))
-        params.update({
-            "q_norm_g": jnp.ones((dh,), self.param_dtype),
-            "k_norm_g": jnp.ones((dh,), self.param_dtype),
-        })
+        params.update(self._init_rotated_heads(kq, kk, kv, ko))
         return params
 
+
+class MoeDecoderBlockLayer(_RotatedHeadsMoeBlock):
     def _attention(self, params, x):
-        conf, cd = self.conf, x.dtype
         t = x.shape[1]
         if t % 2:
             raise ValueError(
                 f"MoeDecoderBlock '{self.name}' takes a noised and a clean "
                 f"copy of each sequence, an even number of rows; got {t}")
-        q, k, v = self._heads(params, x)
         # both halves of the rows sit at 0..L-1
-        pos = jnp.arange(t, dtype=jnp.int32) % (t // 2)
-        q = _rotate(_rms_norm(q, params["q_norm_g"], conf.eps),
-                    conf.rope_theta, pos).astype(cd)
-        k = _rotate(_rms_norm(k, params["k_norm_g"], conf.eps),
-                    conf.rope_theta, pos).astype(cd)
+        q, k, v = self._rotated_heads(
+            params, x, jnp.arange(t, dtype=jnp.int32) % (t // 2))
         with jax.named_scope("block_attention"):
             o = att.block_diffusion_mha(q, k, v, seq_len=t // 2,
-                                        block_len=int(conf.block_len))
+                                        block_len=int(self.conf.block_len))
         return self._merge_heads(params, x, o)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         with jax.named_scope("attn"):
             a = self._attention(params, x.astype(self.compute_dtype))
         return self._experts(params, state, a)
+
+
+class _DenseMlp:
+    """The feed-forward of a leading dense layer, for the layers whose
+    conf has ``mlp_width``: parameters ``ln_g``, ``Wg``, ``Wu``, ``Wd``;
+    under the scope ``dense_mlp``."""
+
+    def _init_mlp(self, kg, ku, kd):
+        d, f = int(self.conf.n_out), int(self.conf.mlp_width)
+        return {"ln_g": jnp.ones((d,), self.param_dtype),
+                "Wg": self._init(kg, (d, f), d, f),
+                "Wu": self._init(ku, (d, f), d, f),
+                "Wd": self._init(kd, (f, d), f, d)}
+
+    def _mlp(self, params, a):
+        cd = a.dtype
+        with jax.named_scope("dense_mlp"):
+            w = _rms_norm(a, params["ln_g"], self.conf.eps).astype(cd)
+            y = _feed_forward(w.reshape(-1, w.shape[-1]), params["Wg"],
+                              params["Wu"], params["Wd"])
+            return a + y.reshape(a.shape).astype(cd)
+
+
+class CausalMoeBlockLayer(_RotatedHeadsMoeBlock):
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return self._experts(params, state, self._causal_attention(
+            params, x.astype(self.compute_dtype)))
+
+
+class CausalDenseBlockLayer(_RotatedHeads, _DenseMlp, _DecoderLayer):
+    def __init__(self, conf, input_type, global_conf, policy):
+        super().__init__(conf, input_type, global_conf, policy)
+        self._check_heads()
+
+    def init_params(self, key):
+        kq, kk, kv, ko, kg, ku, kd = jax.random.split(key, 7)
+        return {**self._init_rotated_heads(kq, kk, kv, ko),
+                **self._init_mlp(kg, ku, kd)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return self._mlp(params, self._causal_attention(
+            params, x.astype(self.compute_dtype))), state
+
+
+class _ShortConvOperator:
+    """A pre-norm gated short-convolution operator (nn/conf/
+    layers_decoder.py ``_ShortConv`` has the equations), for the layers
+    whose conf has ``conv_kernel``: parameters ``op_ln_g``, ``W_in``
+    ``[d, 3 d]`` (``B``, ``C``, ``x~`` in this order), ``conv_w`` ``[d,
+    K]`` and ``W_out``."""
+
+    def _init_operator(self, key):
+        d, k = int(self.conf.n_out), int(self.conf.conv_kernel)
+        k_in, k_w, k_out = jax.random.split(key, 3)
+        # the filter as a depthwise Conv1d's default, as the state-space
+        # mixer's: uniform(+-1/sqrt(kernel))
+        bound = 1.0 / math.sqrt(k)
+        return {
+            "op_ln_g": jnp.ones((d,), self.param_dtype),
+            "W_in": self._init(k_in, (d, 3 * d), d, 3 * d),
+            "conv_w": jax.random.uniform(k_w, (d, k), self.param_dtype,
+                                         -bound, bound),
+            "W_out": self._init(k_out, (d, d), d, d),
+        }
+
+    def _operator(self, params, x):
+        """``x + W_out (C * conv(B * x~))`` of ``x`` [b, t, d], under the
+        scope ``conv_op``."""
+        cd = x.dtype
+        _count_short_conv_layer()
+        with jax.named_scope("conv_op"):
+            u = _rms_norm(x, params["op_ln_g"], self.conf.eps).astype(cd)
+            bcx = _project(u, params["W_in"], cd)
+            with jax.named_scope("short_conv"):
+                y = shortconv.gated_short_conv(bcx, params["conv_w"])
+            return x + _project(y, params["W_out"], cd)
+
+
+def _count_short_conv_layer() -> None:
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_short_conv_layers_traced_total",
+        "Short-convolution operator layers traced (forward walks of a "
+        "net)").inc()
+
+
+class ShortConvMoeBlockLayer(_ShortConvOperator, RoutedExpertsLayer):
+    def init_params(self, key):
+        k_experts, k_op = jax.random.split(key)
+        params = super().init_params(k_experts)
+        params.update(self._init_operator(k_op))
+        return params
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return self._experts(params, state, self._operator(
+            params, x.astype(self.compute_dtype)))
+
+
+class ShortConvDenseBlockLayer(_ShortConvOperator, _DenseMlp, _DecoderLayer):
+    def init_params(self, key):
+        k_op, kg, ku, kd = jax.random.split(key, 4)
+        return {**self._init_operator(k_op), **self._init_mlp(kg, ku, kd)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return self._mlp(params, self._operator(
+            params, x.astype(self.compute_dtype))), state
 
 
 class CausalAttentionLayer(_GroupedQueryHeads, _DecoderLayer):
@@ -482,31 +622,18 @@ class LatentMoeBlockLayer(_LatentHeads, RoutedExpertsLayer):
             params, x.astype(self.compute_dtype)))
 
 
-class LatentDenseBlockLayer(_LatentHeads, _DecoderLayer):
+class LatentDenseBlockLayer(_LatentHeads, _DenseMlp, _DecoderLayer):
     def __init__(self, conf, input_type, global_conf, policy):
         super().__init__(conf, input_type, global_conf, policy)
         self._check_latent()
 
     def init_params(self, key):
-        d, f = int(self.conf.n_out), int(self.conf.mlp_width)
         k_attn, kg, ku, kd = jax.random.split(key, 4)
-        params = self._init_latent(k_attn)
-        params.update({
-            "ln_g": jnp.ones((d,), self.param_dtype),
-            "Wg": self._init(kg, (d, f), d, f),
-            "Wu": self._init(ku, (d, f), d, f),
-            "Wd": self._init(kd, (f, d), f, d),
-        })
-        return params
+        return {**self._init_latent(k_attn), **self._init_mlp(kg, ku, kd)}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        cd = self.compute_dtype
-        a = self._latent_attention(params, x.astype(cd))
-        with jax.named_scope("dense_mlp"):
-            w = _rms_norm(a, params["ln_g"], self.conf.eps).astype(cd)
-            y = _feed_forward(w.reshape(-1, w.shape[-1]), params["Wg"],
-                              params["Wu"], params["Wd"])
-            return a + y.reshape(a.shape).astype(cd), state
+        return self._mlp(params, self._latent_attention(
+            params, x.astype(self.compute_dtype))), state
 
 
 def _inverse_softplus(dt):
@@ -582,13 +709,33 @@ class Mamba2MixerLayer(_DecoderLayer):
 
 
 class TokenOutputLayer(_DecoderLayer):
+    @property
+    def shares(self):
+        """A tied head reads the embedding's matrix under the local name
+        ``Emb`` and stores none (nn/multilayer.py ``_layer_params``):
+        autodiff sums the product's gradient into the gather's."""
+        tied = self.conf.tied_to
+        return {"Emb": (tied, "W")} if tied else {}
+
     def init_params(self, key):
+        if self.conf.tied_to:
+            return {}
         n_in, n_out = int(self.conf.n_in), int(self.conf.n_out)
         return {"W": self._init(key, (n_in, n_out), n_in, n_out)}
 
     def _logits(self, params, x):
         cd = self.compute_dtype
         rows = x if self.conf.causal else x[:, :x.shape[1] // 2]
+        if self.conf.tied_to:
+            emb = params["Emb"]
+            if emb.shape != (int(self.conf.n_out), int(self.conf.n_in)):
+                raise ValueError(
+                    f"TokenOutput '{self.name}' is tied to "
+                    f"'{self.conf.tied_to}', whose matrix is "
+                    f"{tuple(emb.shape)}; logits of {self.conf.n_out} over "
+                    f"rows of {self.conf.n_in} need its transpose")
+            return jnp.einsum("btf,gf->btg", rows.astype(cd), emb.astype(cd),
+                              preferred_element_type=jnp.float32)
         return jnp.einsum("btf,fg->btg", rows.astype(cd),
                           params["W"].astype(cd),
                           preferred_element_type=jnp.float32)

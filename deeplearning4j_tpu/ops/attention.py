@@ -940,8 +940,12 @@ def causal_attention_xla(q, k, v):
 
 def causal_attention_supported(q, k, v) -> bool:
     """Whether the tiled kernels cover this call: whole tiles of 128
-    rows, heads of 128, and what ``_bd_operands_supported`` asks."""
-    if q.shape[1] % 128 or q.shape[3] % 128:
+    rows, heads of whole lane tiles or of 64 (the head is the blocks'
+    whole last dimension, so q, k, v and the output stay 64 wide in
+    memory; a product then fills half of the MXU's contraction for the
+    scores and half of its columns for the values), and what
+    ``_bd_operands_supported`` asks."""
+    if q.shape[1] % 128 or (q.shape[3] % 128 and q.shape[3] != 64):
         return False
     return _bd_operands_supported(q, k, v)
 

@@ -12,6 +12,7 @@ from deeplearning4j_tpu.zoo.models import (
     gpt_mini_draft,
     gpt_mini_tp_rules,
     lenet,
+    lfm2_moe,
     mnist_mlp,
     nemotron_h,
     resnet18,
@@ -23,5 +24,5 @@ from deeplearning4j_tpu.zoo.models import (
 
 __all__ = ["BF16", "F32", "VGG16_MEAN_RGB", "char_rnn", "glm4_moe_lite",
            "gpt_mini",
-           "gpt_mini_draft", "gpt_mini_tp_rules", "lenet", "mnist_mlp",
+           "gpt_mini_draft", "gpt_mini_tp_rules", "lenet", "lfm2_moe", "mnist_mlp",
            "nemotron_h", "resnet18", "resnet50", "sdar_moe", "vgg16", "vgg16_preprocess"]

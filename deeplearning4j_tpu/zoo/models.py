@@ -467,6 +467,85 @@ def glm4_moe_lite(seed: int = 42, n_layers: int = 47, first_dense: int = 1,
         b.set_input_type(InputType.recurrent(vocab_size)).build()).init()
 
 
+LFM2_MOE_PATTERN = "cc" + "accc" * 9 + "ac"
+
+
+def lfm2_moe(seed: int = 42, pattern: str = LFM2_MOE_PATTERN,
+             n_dense: int = 2, n_experts: int = 64,
+             experts_held: Optional[int] = None, first_expert: int = 0,
+             vocab_size: int = 65_536, hidden: int = 2048,
+             conv_kernel: int = 3, n_heads: int = 32, n_kv_heads: int = 8,
+             head_dim: int = 64, mlp_width: int = 11_776,
+             expert_width: int = 1536, experts_per_token: int = 4,
+             routed_scale: float = 1.0, router_eps: float = 1e-6,
+             rope_theta: float = 1e6, eps: float = 1e-5,
+             learning_rate: float = 1e-5,
+             dtype: Optional[DtypePolicy] = None) -> MultiLayerNetwork:
+    """LFM2-24B-A2B (``model_type`` lfm2_moe; the defaults are its
+    published config.json): a causal decoder of one operator and one
+    feed-forward a layer, each pre-normed with a residual. The operator
+    follows ``pattern``: ``c`` a gated short convolution (``C *
+    conv_K(B * x~)`` between two projections, a depthwise causal filter
+    of ``conv_kernel`` positions), ``a`` causal grouped-query attention
+    with an RMS norm on every query and key head and rotary positions
+    over the whole head. The feed-forward follows the layer's index:
+    layers ``0..n_dense-1`` a dense gated silu MLP of ``mlp_width``, the
+    rest routed gated silu experts under a sigmoid router with a
+    correction bias, renormalised (``router_eps`` in the denominator), no
+    shared expert. The head is the embedding's own matrix
+    (``TokenOutput(tied_to=...)``): one stored leaf. Integer ids in, the
+    next token as integer labels out.
+
+    ``experts_held`` and ``first_expert`` give this chip's share of the
+    routed experts under expert parallelism (the router still scores all
+    ``n_experts``; operators and dense layers are whole on every chip);
+    ``vocab_size`` is the slice of the vocabulary held here.
+
+    Init, for every seed alike: matrices normal(0, 0.02) (the family's
+    ``initializer_range``), the embedding among them: tied, it is the
+    head too, and rows of normal(0, 1) would start the logits at a
+    spread of 45 (PERF.md, Findings PR 39, has the pairs a layer is
+    given at both scales); norm weights 1, router bias 0, the filter
+    uniform(+-1/sqrt(conv_kernel)) as the state-space mixer's."""
+    from deeplearning4j_tpu.nn.conf.layers_decoder import (
+        CausalDenseBlock, CausalMoeBlock, RmsNorm, ShortConvDenseBlock,
+        ShortConvMoeBlock, TokenEmbedding, TokenOutput)
+    unknown = sorted(set(pattern) - set("ca"))
+    if unknown or not pattern:
+        raise ValueError(
+            f"lfm2_moe: pattern {pattern!r} may hold 'c' (short "
+            f"convolution) and 'a' (attention); it holds {unknown}")
+    if not 0 <= n_dense <= len(pattern):
+        raise ValueError(
+            f"lfm2_moe: {n_dense} dense layers among {len(pattern)}")
+    operators = {
+        "c": dict(conv_kernel=conv_kernel),
+        "a": dict(n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
+                  rope_theta=rope_theta)}
+    blocks = {("c", True): ShortConvDenseBlock, ("a", True): CausalDenseBlock,
+              ("c", False): ShortConvMoeBlock, ("a", False): CausalMoeBlock}
+    dense = dict(mlp_width=mlp_width, eps=eps)
+    experts = dict(n_experts=n_experts, experts_per_token=experts_per_token,
+                   expert_width=expert_width, experts_held=experts_held,
+                   first_expert=first_expert, eps=eps, router="sigmoid",
+                   routed_scale=routed_scale, router_eps=router_eps,
+                   expert_form="gated_silu")
+    b = (NeuralNetConfiguration.builder()
+         .seed(seed).updater(Adam(learning_rate)).dtype(dtype or BF16)
+         .weight_init({"type": "normal", "mean": 0.0, "std": 0.02})
+         .list()
+         .layer(TokenEmbedding(n_out=hidden)))
+    for i, kind in enumerate(pattern):
+        b = b.layer(blocks[kind, i < n_dense](
+            **operators[kind], **(dense if i < n_dense else experts)))
+    conf = (b.layer(RmsNorm(eps=eps))
+            .layer(TokenOutput(n_out=vocab_size, activation="identity",
+                               causal=True, tied_to="layer_0"))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+    return MultiLayerNetwork(conf).init()
+
+
 def gpt_mini_draft(vocab_size: int = 80, width: int = 128,
                    n_layers: int = 2, n_heads: int = 2, max_len: int = 256,
                    max_cache_len: Optional[int] = None, seed: int = 43,

@@ -317,3 +317,50 @@ def test_latent_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     text = jax.jit(jax.grad(loss, (0, 2, 4, 5, 6))).lower(*args).compile(
         ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6
+
+
+def test_short_conv_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
+    """What the cell ``lfm2_24b_a2b-train-b1-l8192`` asks of the chip's
+    compilers at its own shape: the gated short convolution's forward
+    and backward kernels on ``[1, 8192, 3 * 2048]`` bf16 (32 time tiles
+    of 256 positions and all 6,144 columns, the halo a second block of
+    16 rows, the filter's gradient one resident block), and the tiled
+    attention kernels under the causal tables with 32 query heads on 8
+    key/value heads of 64: the head is the blocks' whole last dimension,
+    a group of 4 takes a query tile of 256 against 512 keys. The
+    registry would hand this CPU process the xla executor, so the
+    kernels' entries are compiled themselves."""
+    from deeplearning4j_tpu.ops import attention as att
+    from deeplearning4j_tpu.ops import shortconv
+
+    seq, d, cd = 8192, 2048, jnp.bfloat16
+    bcx = jax.ShapeDtypeStruct((1, seq, 3 * d), cd, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((d, 3), jnp.float32, sharding=one_chip)
+    assert shortconv.short_conv_supported(bcx, w) is False      # no TPU here
+    assert shortconv._time_tile(seq) == 256
+
+    def conv(bcx, w):
+        return jnp.sum(jnp.square(shortconv._tiled(bcx, w).astype(
+            jnp.float32)))
+
+    compiled = jax.jit(jax.value_and_grad(conv, (0, 1))).lower(
+        bcx, w).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+    # no copy of B, C or x~ beside the kernels' own operands: the
+    # temporaries are y, its float32 square's gradient and dw
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e8
+
+    q, k, v = (jax.ShapeDtypeStruct((1, seq, h, 64), cd, sharding=one_chip)
+               for h in (32, 8, 8))
+
+    def attend(q, k, v):
+        out = att._bd_join(att._causal_tiled(*att._bd_split(q, k, v)), 1)
+        return jnp.sum(out.astype(jnp.float32))
+
+    assert att._bd_query_tile(4, seq) == 256 and att._bd_key_tile(seq) == 512
+    # live tiles only: 272 of the 512 tile pairs of 256 x 512
+    assert len(att._causal_live_tiles(seq, 256, 512)) == 272
+    text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
