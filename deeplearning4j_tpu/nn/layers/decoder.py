@@ -24,20 +24,22 @@ head and loss; and all the prediction module does, its own ``attn``,
 ``ssm_norm`` round the three ops of ops/ssm.py), ``conv_op`` (a
 short-convolution operator's norm and both projections, and inside it
 ``short_conv`` round the op of ops/shortconv.py alone), ``shared_expert`` (its
-two or three products), ``route`` (norm, router, top-k, the sort of the
-pairs, and what
+two or three products), ``route`` (norm, router, top-k, the one-hot
+read of the chosen scores, the sort of the pairs that carries their
+weights and the sort that takes the weights' gradient back, and what
 ``ops/grouped.py`` does to move rows in XLA: a gather a block of pairs
 before its kernels, or a gather and a scatter-add a chunk inside its
-loop) and ``experts`` (the grouped products and the gating between
-them: on a TPU one Pallas call a block forward and two backward, which
-add their rows to the result themselves; the chunk loop's dots
-elsewhere).
+loop; whole rows, never single scalars) and ``experts`` (the grouped
+products and the gating between them: on a TPU one Pallas call a block
+forward and two backward, which add their rows to the result
+themselves; the chunk loop's dots elsewhere).
 ``observability/opindex.py`` places a device op by the innermost scope
 it is asked about.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -107,6 +109,45 @@ def expert_chunk_rows(rows: int, experts_per_token: int,
     (``grouped.TILE``), and nothing else about them is sized here."""
     expected = rows * experts_per_token / n_experts
     return 128 * min(max(math.ceil(1.5 * expected / 128), 1), 8)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _sort_pairs(key, coef, n_keys):
+    """The stable order of ``key`` (int32 [P], values in ``0..n_keys-1``)
+    and ``coef`` (float32 [P]) in that order, from ONE sort that carries
+    both: ``argsort`` is this sort without the weights, and
+    ``coef[order]`` beside it is a gather of ``P`` single scalars whose
+    transpose is a scatter-add of ``P`` single scalars (7 and 9 ns a
+    scalar on a v5e, PERF.md Findings PR 40). The gradient arrives in
+    sorted order and goes back by sorting it on ``order``, a permutation:
+    the same bits both ways."""
+    pairs = key.shape[0]
+    iota = jax.lax.iota(jnp.int32, pairs)
+    if n_keys * pairs <= 2 ** 31:
+        # one int32 holds a pair's key and its place, no two alike: a
+        # sort of two operands with no ties to keep in order, which a
+        # v5e's compiler builds in a third of the time of the stable
+        # sort of three below, in 0.8 MB less code (PERF.md, PR 40)
+        packed, coef = jax.lax.sort((key * pairs + iota, coef), num_keys=1,
+                                    is_stable=False)
+        return jax.lax.rem(packed, jnp.int32(pairs)), coef
+    _, order, coef = jax.lax.sort((key, iota, coef), num_keys=1,
+                                  is_stable=True)
+    return order, coef
+
+
+def _sort_pairs_fwd(key, coef, n_keys):
+    order, coef = _sort_pairs(key, coef, n_keys)
+    return (order, coef), order
+
+
+def _sort_pairs_bwd(n_keys, order, cotangents):
+    # ``order`` has no ties either
+    return None, jax.lax.sort((order, cotangents[1]), num_keys=1,
+                              is_stable=False)[1]
+
+
+_sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 
 
 _LIMB = 30      # bits of the low limb of ``expert_rows_total``
@@ -215,21 +256,33 @@ class RoutedExpertsLayer(_DecoderLayer):
 
     def _choose(self, logits, state):
         """The ``experts_per_token`` experts of every row and their
-        weights, float32 [R, k]."""
+        weights, float32 [R, k]. ``top_k`` gives the indices alone; a
+        row's chosen scores are read by a one-hot compare, a sum with one
+        term that is not zero, whose gradient is such a sum too (a row's
+        choices are distinct): top-k's own values, or ``take_along_axis``,
+        come back as a scatter-add of ``R k`` single scalars."""
         k = int(self.conf.experts_per_token)
-        if self.conf.router == "softmax":
-            top, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-            return chosen, top / jnp.sum(top, axis=-1, keepdims=True)
-        score = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(
-            score + jax.lax.stop_gradient(state["router_bias"]), k)
-        top = jnp.take_along_axis(score, chosen, axis=-1)
-        return chosen, top / (jnp.sum(top, axis=-1, keepdims=True)
-                              + float(self.conf.router_eps))
+        sigmoid = self.conf.router == "sigmoid"
+        if sigmoid:
+            score = jax.nn.sigmoid(logits)
+            ranked = score + jax.lax.stop_gradient(state["router_bias"])
+        else:
+            score = ranked = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(ranked, k)
+        experts = jnp.arange(score.shape[-1], dtype=chosen.dtype)
+        top = jnp.sum(jnp.where(chosen[..., None] == experts,
+                                score[..., None, :], 0.0), axis=-1)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        if sigmoid:
+            total = total + float(self.conf.router_eps)
+        return chosen, top / total
 
     def _route(self, params, state, a):
         """The normed rows [R, d] (float32) and the pairs held here,
-        sorted by expert: rows, weights, and the count of each expert."""
+        sorted by expert: rows, weights, and the count of each expert.
+        All ``R k`` pairs are sorted, those of experts held elsewhere
+        last, and the weights ride the sort (``_sort_pairs``): nothing
+        here moves a single scalar by index, forward or backward."""
         k = int(self.conf.experts_per_token)
         w = _rms_norm(a, params["ln_g"], self.conf.eps).reshape(
             -1, a.shape[-1])
@@ -241,12 +294,11 @@ class RoutedExpertsLayer(_DecoderLayer):
         local = chosen.astype(jnp.int32) - int(self.conf.first_expert)
         key = jnp.where((local >= 0) & (local < self.held), local,
                         self.held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
+        order, coef = _sort_pairs(key, coef.reshape(-1), self.held + 1)
         counts = jnp.sum(
             key[:, None] == jnp.arange(self.held, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32)
-        return (w, (order // k).astype(jnp.int32),
-                coef.reshape(-1)[order], counts)
+        return w, (order // k).astype(jnp.int32), coef, counts
 
     def _experts(self, params, state, a):
         cd = a.dtype

@@ -18,7 +18,13 @@ or three plain products in the layer.
 The pairs arrive sorted by held expert: pair ``p`` is row ``rows[p]`` of
 ``x`` weighted by ``coef[p]``, expert ``e`` owns the ``counts[e]`` pairs
 after those of the experts before it, and whatever follows
-``sum(counts)`` belongs to experts held elsewhere. The arrays are as
+``sum(counts)`` belongs to experts held elsewhere. The layer makes them
+with one stable sort of all its (row, choice) pairs by held expert that
+carries the pair's index and its weight along (``decoder._sort_pairs``:
+``rows`` is the sorted index over ``k``), and ``coef``'s gradient, which
+both executors return in this sorted order, goes back to the pairs' own
+order by a sort on that index: no gather and no scatter-add of single
+scalars on either side of this module. The arrays are as
 long as the worst case (every row choosing only experts held here); the
 work is not, on either of the two executors below. Nothing is dropped
 whatever the counts and nothing is padded into the result. Which one
