@@ -35,7 +35,8 @@ Three backends behind the ``causal_mha`` registry seam:
   keys), the forward runs at 46% and the tiled backward at 40% of the
   MXU's roofline for the visible pairs, which is 2.4 us a grid step for
   1.4 of products and, backward, 85% of the MXU's peak for the seven
-  products the two kernels execute (PERF.md, Findings PR 36). The
+  products the two kernels then executed (PERF.md, Findings PR 36;
+  since PR 41 the backward is one kernel of five products). The
   causal path was not moved onto that kernel body: the body carries a
   window of key blocks per row and could carry "keys up to my own", but
   the causal path's contract is the decode bit-identity above, pinned
@@ -394,15 +395,23 @@ def extend_cache(k_cache, v_cache, k_new, v_new, pos):
 #   and tiles of 128 x 512 the live tiles hold 1.25 times that.
 #
 #   Which way a kernel's score tile lies is chosen by what it reduces:
-#   the forward and the dK/dV kernel hold it as [keys, G * bq rows], the
-#   dQ kernel as [G * bq rows, keys]. Whatever a kernel knows per ROW
-#   (the forward's running max and sum, the backward's saved log-sum-exp
-#   and ``di``) then lies along the lanes in the first two, dense, and
-#   the forward's two reductions over a tile's keys go down the sublanes
-#   on the VPU; dK and dV are sums over rows, which that orientation
-#   hands to the MXU's contraction. dQ is a sum over keys, the
-#   contraction of [rows, keys] by the key tile, and needs no reduction
-#   of the scores at all, so it keeps its statistics as columns.
+#   the forward and the backward hold it as [keys, G * bq rows].
+#   Whatever a kernel knows per ROW (the forward's running max and sum,
+#   the backward's saved log-sum-exp and ``di``) then lies along the
+#   lanes, dense, and the forward's two reductions over a tile's keys go
+#   down the sublanes on the VPU; dK and dV are sums over rows, which
+#   that orientation hands to the MXU's contraction, and dQ^T = k^T dS^T
+#   contracts the keys as the forward's v^T p^T does.
+#
+#   The backward is ONE kernel (``_bd_bwd_kernel``, since PR 41): it
+#   walks the pairs query-major as the forward does and makes the
+#   scores, p, dP and dS once a pair, five products a step; dQ gathers
+#   over a query tile's run, dK and dV of the whole key/value head stay
+#   resident in VMEM. Where those resident blocks pass
+#   ``_BD_RESIDENT_BUDGET`` (over 16,384 rows at heads of 128) it is two
+#   kernels over the same pairs, a dQ kernel with the scores as
+#   [rows, keys] and its statistics as columns, and a key-major dK/dV
+#   kernel: seven products and two vector passes a pair.
 
 
 def block_diffusion_visible(i, j, seq_len: int, block_len: int):
@@ -625,8 +634,9 @@ def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
     v5e that is half of such a kernel's time (PERF.md, Findings PR 36).
     The accumulator follows as [dh, g * bq] (``v^T . p^T``) and is turned
     once a query tile, head by head; the log-sum-exp leaves by splitting
-    the lanes. The dQ kernel keeps [rows, keys]: it reduces nothing over
-    the keys and its products want the rows streamed."""
+    the lanes. The split backward's dQ kernel keeps [rows, keys]: it
+    reduces nothing over the keys and its products want the rows
+    streamed."""
     import jax.experimental.pallas as pl
 
     s_id = pl.program_id(1)
@@ -670,8 +680,9 @@ def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
             lse_ref[0, h:h + 1, :] = lse[:, h * bq:(h + 1) * bq]
 
 
-# dQ: scores as [rows, keys] (``_bd_scores``), the row statistics read
-# once a query tile into [g * bq, 1] columns; nothing is reduced over keys.
+# The split backward's dQ: scores as [rows, keys] (``_bd_scores``), the
+# row statistics read once a query tile into [g * bq, 1] columns; nothing
+# is reduced over keys.
 def _bd_dq_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
                   q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
                   lse_scr, di_scr, acc_scr, *, scale, seq_len, shift):
@@ -750,7 +761,85 @@ def _bd_dkv_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _bd_bwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
+                   q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                   dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                   *, scale, seq_len, shift):
+    """dQ, dK and dV from one walk over the live pairs in the forward's
+    query-major order: the scores, p, dP and dS of a pair are made once,
+    as [keys, rows] (``_bd_dkv_kernel``'s orientation), five products a
+    step. dQ gathers as [dh, rows] (``k^T . dS^T``, the forward's
+    ``v^T . p^T``) over a query tile's run and is turned head by head at
+    its end; dK and dV gather in float32 scratch that holds every key
+    tile of the key/value head, indexed by the step's key tile, zeroed
+    at the head's first step and written at its last."""
+    import jax.experimental.pallas as pl
+
+    s_id = pl.program_id(1)
+    g, bq, dh = q_ref.shape[1:]
+    bk = k_ref.shape[1]
+    ki = ki_ref[s_id]
+
+    @pl.when(s_id == 0)
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(first_ref[s_id] == 1)
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
+    kb, lower, upper = _bd_bounds(
+        kind_ref[s_id], qi_ref[s_id] * bq, ki * bk, seq_len, shift,
+        lanes & (bq - 1),
+        jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
+    q = q_ref[0].reshape(g * bq, dh)
+    do = do_ref[0].reshape(g * bq, dh)
+    st = jax.lax.dot_general(
+        k_ref[0], q, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale          # [bk, g*bq]
+    st = jnp.where((kb <= upper) & (kb >= lower), st, _MASK_VALUE)
+    pt = jnp.exp(st - _rows_to_lanes(lse_ref[0], g))
+    dpt = jax.lax.dot_general(
+        v_ref[0], do, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dst = (pt * (dpt - _rows_to_lanes(di_ref[0], g)) * scale).astype(q.dtype)
+    dv_scr[ki] += jax.lax.dot_general(
+        pt.astype(do.dtype), do, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dk_scr[ki] += jax.lax.dot_general(
+        dst, q, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dq_scr[:] += jax.lax.dot_general(
+        k_ref[0], dst, dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [dh, g*bq]
+
+    @pl.when(last_ref[s_id] == 1)
+    def _():
+        dq = dq_scr[:]
+        for h in range(g):
+            dq_ref[0, h] = dq[:, h * bq:(h + 1) * bq].T.astype(dq_ref.dtype)
+
+    @pl.when(s_id == pl.num_programs(1) - 1)
+    def _():
+        dk_ref[0] = dk_scr[:].reshape(-1, dh).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].reshape(-1, dh).astype(dv_ref.dtype)
+
+
 _BD_VMEM_LIMIT = 64 * 1024 * 1024
+# what the fused backward's resident dK and dV may take of that limit
+_BD_RESIDENT_BUDGET = _BD_VMEM_LIMIT // 2
+
+
+def _bd_fused_fits(t: int, dh: int, dtype) -> bool:
+    """Whether dK and dV of one key/value head of ``t`` rows can stay in
+    VMEM for the fused backward: their float32 scratch and the two
+    outputs' double buffers, a row at least a lane tile wide, within
+    ``_BD_RESIDENT_BUDGET``. Up to 16,384 rows at heads of 128 in bf16."""
+    lanes = max(dh, 128)
+    return (2 * t * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+            <= _BD_RESIDENT_BUDGET)
 
 
 def _bd_call(kernel, tables, n_steps, bh, in_specs, out_specs, out_shape,
@@ -819,11 +908,24 @@ def _count_tiled_attention(direction: str, group: int, bq: int) -> None:
 
     get_registry().counter(
         "dl4j_tiled_attention_calls_total",
-        "Tiled attention kernel calls traced (a forward kernel, or a dQ "
-        "and a dK/dV kernel), by direction, by the query heads a key/value "
-        "head and by the query positions a tile that group was given",
+        "Tiled attention kernel calls traced (a forward kernel, or a "
+        "backward in either form), by direction, by the query heads a "
+        "key/value head and by the query positions a tile that group was "
+        "given",
         ("direction", "group", "query_tile")).labels(
             direction=direction, group=str(group), query_tile=str(bq)).inc()
+
+
+def _count_tiled_backward(form: str, group: int) -> None:
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_tiled_attention_backward_total",
+        "Tiled attention backward calls traced, by form (fused: one kernel "
+        "makes dQ, dK and dV; split: a dQ and a dK/dV kernel, where the "
+        "resident dK and dV would not fit) and by the query heads a "
+        "key/value head", ("form", "group")).labels(
+            form=form, group=str(group)).inc()
 
 
 def _bd_forward(qg, kg, vg, seq_len, block_len):
@@ -847,11 +949,14 @@ def _bd_forward(qg, kg, vg, seq_len, block_len):
 
 
 def _bd_backward(qg, kg, vg, og, lse, dog, seq_len, block_len):
+    import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, g, t, dh = qg.shape
     bq, bk = _bd_query_tile(g, seq_len), _bd_key_tile(seq_len)
     _count_tiled_attention("backward", g, bq)
+    fused = _bd_fused_fits(t, dh, kg.dtype)
+    _count_tiled_backward("fused" if fused else "split", g)
     static = dict(scale=1.0 / math.sqrt(dh), seq_len=seq_len,
                   shift=_bd_shift(block_len))
     di = jnp.sum(og.astype(jnp.float32) * dog.astype(jnp.float32), axis=-1)
@@ -859,6 +964,17 @@ def _bd_backward(qg, kg, vg, og, lse, dog, seq_len, block_len):
     operands = (qg, kg, vg, dog, lse, di)
     in_specs = [rows, keys, keys, rows, stats, stats]
     tables = _bd_tables(seq_len, block_len, bq, bk, key_major=False)
+    if fused:
+        head = pl.BlockSpec((1, t, dh), lambda b, *_: (b, 0, 0))
+        return tuple(_bd_call(
+            _bd_bwd_kernel, tables, len(tables[0]), bh,
+            in_specs=in_specs, out_specs=[rows, head, head],
+            out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
+                       for a in (qg, kg, vg)],
+            scratch=[pltpu.VMEM((dh, g * bq), jnp.float32),
+                     pltpu.VMEM((t // bk, bk, dh), jnp.float32),
+                     pltpu.VMEM((t // bk, bk, dh), jnp.float32)],
+            **static)(*tables, *operands))
     dq = _bd_call(
         _bd_dq_kernel, tables, len(tables[0]), bh,
         in_specs=in_specs, out_specs=rows,

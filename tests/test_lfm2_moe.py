@@ -361,8 +361,12 @@ def test_the_operator_is_the_references(net):
     (512, 64, 8, 2, True), (256, 64, 4, 4, True),
     # the other decoders' heads still take the kernels
     (256, 128, 4, 2, True), (512, 256, 2, 2, True),
-    (256, 32, 4, 2, False), (256, 96, 4, 2, False)],
-    ids=["64-4on1", "64-1on1", "128", "256", "32-refused", "96-refused"])
+    (256, 32, 4, 2, False), (256, 96, 4, 2, False),
+    # two key tiles of 512: a query tile of 256 meets both, so the fused
+    # backward gathers dQ across steps at heads of 64
+    (1024, 64, 8, 2, True)],
+    ids=["64-4on1", "64-1on1", "128", "256", "32-refused", "96-refused",
+         "64-4on1-1024"])
 def test_causal_attention_at_heads_of_64_is_a_dense_masked_softmax(
         monkeypatch, rows, dh, heads, kv_heads, kernels):
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")

@@ -508,6 +508,87 @@ def test_query_tile_follows_the_group(why, heads, kv_heads, dh, seq, block,
         for d in ("forward", "backward") for tile in (128, 256, 512)}, why
 
 
+@pytest.mark.parametrize("why,heads,kv_heads,dh,seq,block,form", [
+    ("sdar_30b_a3b-train-b1-l4096", 32, 4, 128, 4096, 4, "fused"),
+    ("nemotron3_nano_30b_a3b-train-b1-l4096", 32, 2, 128, 4096, None,
+     "fused"),
+    ("glm4_7_flash-train-b1-l4096", 20, 20, 256, 4096, None, "fused"),
+    ("lfm2_24b_a2b-train-b1-l8192", 32, 8, 64, 8192, None, "fused"),
+    ("32k rows: dK and dV of a head pass the budget", 32, 4, 128, 32768,
+     None, "split")])
+def test_backward_form_follows_the_resident_bytes(why, heads, kv_heads, dh,
+                                                  seq, block, form):
+    """One backward kernel where a key/value head's dK and dV fit in VMEM
+    beside their output buffers (every decoder cell's shape), the dQ and
+    dK/dV kernels where they do not: a function of the call's shapes, no
+    option selects it. Traced at the shapes, nothing runs; every
+    backward is still counted in ``dl4j_tiled_attention_calls_total``."""
+    group = heads // kv_heads
+    rows = seq if block is None else 2 * seq
+    assert att._bd_fused_fits(rows, dh, jnp.bfloat16) == (form == "fused")
+    q, k, v = (jax.ShapeDtypeStruct((1, rows, h, dh), jnp.bfloat16)
+               for h in (heads, kv_heads, kv_heads))
+
+    def attend(q, k, v):
+        qg, kg, vg = att._bd_split(q, k, v)
+        og = (att._causal_tiled(qg, kg, vg) if block is None
+              else att._bd_attention(qg, kg, vg, seq, block))
+        return jnp.sum(att._bd_join(og, 1).astype(jnp.float32))
+
+    def counts():
+        return {f: _count("dl4j_tiled_attention_backward_total", form=f,
+                          group=str(group)) for f in ("fused", "split")}
+
+    tile = str(att._bd_query_tile(group, seq))
+    calls = _count("dl4j_tiled_attention_calls_total", direction="backward",
+                   group=str(group), query_tile=tile)
+    before = counts()
+    jax.eval_shape(jax.grad(attend, (0, 1, 2)), q, k, v)
+    after = counts()
+    assert {f: after[f] - before[f] for f in after} == {
+        f: float(f == form) for f in after}, why
+    assert _count("dl4j_tiled_attention_calls_total", direction="backward",
+                  group=str(group), query_tile=tile) == calls + 1
+
+
+@pytest.mark.parametrize("rows,block,heads,kv_heads", [
+    (1024, None, 16, 2), (512, 4, 8, 2)], ids=["causal", "block_diffusion"])
+def test_split_backward_is_the_fused_ones(monkeypatch, rows, block, heads,
+                                          kv_heads):
+    """Where the resident dK and dV would pass the budget the backward
+    is the dQ and the dK/dV kernels: the same gradients on one input,
+    within the order in which dQ's products are summed."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    seq = rows if block is None else rows // 2
+    k = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(k[0], (1, rows, heads, 128), jnp.float32)
+    kk, v = (jax.random.normal(k[i], (1, rows, kv_heads, 128), jnp.float32)
+             for i in (1, 2))
+    g = jax.random.normal(k[3], q.shape, jnp.float32)
+
+    def grads():
+        def loss(*a):
+            qg, kg, vg = att._bd_split(*a)
+            og = (att._causal_tiled(qg, kg, vg) if block is None
+                  else att._bd_attention(qg, kg, vg, seq, block))
+            return jnp.sum(att._bd_join(og, 1) * g)
+        return jax.jit(jax.grad(loss, (0, 1, 2)))(q, kk, v)
+
+    group = str(heads // kv_heads)
+    fused = grads()
+    # a budget this sequence's resident blocks pass
+    monkeypatch.setattr(att, "_BD_RESIDENT_BUDGET",
+                        2 * rows * 128 * 12 - 1)
+    assert not att._bd_fused_fits(rows, 128, jnp.float32)
+    before = _count("dl4j_tiled_attention_backward_total", form="split",
+                    group=group)
+    split = grads()
+    assert _count("dl4j_tiled_attention_backward_total", form="split",
+                  group=group) == before + 1
+    for a, b in zip(fused, split):
+        _close(a, b, rtol=1e-5)
+
+
 def _dense_causal(q, k, v):
     group = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
@@ -526,9 +607,15 @@ def _dense_causal(q, k, v):
     # the latent decoder cell's group of 1 and heads of 256: a query tile
     # of 512 that meets two key tiles and a diagonal tile; and a group of
     # 4, a tile of 256 (two query tiles a key tile)
-    ("pallas", 1024, 256, 2, 2), ("pallas", 512, 128, 4, 1)],
+    ("pallas", 1024, 256, 2, 2), ("pallas", 512, 128, 4, 1),
+    # the fused backward over two key/value heads at groups of 16 and 8:
+    # a key tile meets several query tiles of 128 (dK, dV resident across
+    # their runs), a query tile two or three key tiles (dQ across steps);
+    # 768 rows take key tiles of 256
+    ("pallas", 768, 128, 32, 2), ("pallas", 1024, 128, 16, 2)],
     ids=["pallas-512-128", "xla-24-16", "pallas-1024-128-16on1",
-         "pallas-1024-256-1on1", "pallas-512-128-4on1"])
+         "pallas-1024-256-1on1", "pallas-512-128-4on1",
+         "pallas-768-128-16on1x2", "pallas-1024-128-8on1x2"])
 def test_causal_attention_matches_dense_masked_softmax(monkeypatch, backend,
                                                        rows, dh, heads,
                                                        kv_heads):

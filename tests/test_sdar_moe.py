@@ -188,9 +188,16 @@ F32_4ON2 = (4, 2, jnp.float32, 2e-5)
     ("pallas", 128, 4, 8, 1, jnp.bfloat16, 2e-2),
     # a group of 1: the query tile is 512 positions, a whole half of the
     # rows, against key tiles of 512 of all three kinds
-    ("pallas", 512, 4, 2, 2, jnp.float32, 2e-5)],
+    ("pallas", 512, 4, 2, 2, jnp.float32, 2e-5),
+    # the fused backward over two key/value heads (dK and dV zeroed at
+    # each head's first step): a group of 16 (tiles of 128 rows against
+    # 256 keys, so a key tile meets two query tiles and a clean query tile
+    # two key tiles) and a group of 4 (tiles of 256 against 256)
+    ("pallas", 256, 4, 32, 2, jnp.float32, 2e-5),
+    ("pallas", 256, 4, 8, 2, jnp.float32, 2e-5)],
     ids=["xla-20-4", "xla-12-3", "pallas-256-4", "pallas-128-8",
-         "pallas-256-4-8on1", "pallas-128-4-8on1-bf16", "pallas-512-4-1on1"])
+         "pallas-256-4-8on1", "pallas-128-4-8on1-bf16", "pallas-512-4-1on1",
+         "pallas-256-4-16on1x2", "pallas-256-4-4on1x2"])
 def test_attention_matches_dense_masked_softmax(monkeypatch, backend, seq,
                                                 block, heads, kv_heads,
                                                 dtype, rtol):

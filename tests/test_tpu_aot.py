@@ -160,10 +160,12 @@ def test_char_rnn_step_has_no_pass_over_dxz_or_xz(one_chip, x32, monkeypatch):
 
 
 def test_block_attention_kernels_compile_at_the_bench_shape(one_chip, x32):
-    """The forward, dQ and dK/dV kernels of ops/attention.py at the
-    SDAR cell's shape: 2 x 4,096 rows, 32 query heads on 4 key/value
+    """The forward and the fused backward kernel of ops/attention.py at
+    the SDAR cell's shape: 2 x 4,096 rows, 32 query heads on 4 key/value
     heads of 128, blocks of 4 (Mosaic accepts the tiles, the scalar
-    prefetch tables and the 64 MB VMEM limit)."""
+    prefetch tables, the backward's resident dK and dV of 8,192 rows and
+    the 64 MB VMEM limit): two kernels, where the split backward's dQ and
+    dK/dV kernels made three."""
     from deeplearning4j_tpu.ops import attention as att
 
     seq, block = 4096, 4
@@ -175,9 +177,10 @@ def test_block_attention_kernels_compile_at_the_bench_shape(one_chip, x32):
                                              block), 1)
         return jnp.sum(out.astype(jnp.float32))
 
+    assert att._bd_fused_fits(2 * seq, 128, jnp.bfloat16)
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     # live tiles only: 320 of the 1,024 tile pairs of 128 x 512
     assert len(att._bd_live_tiles(seq, block, 128, 512)) == 320
 
@@ -217,7 +220,8 @@ def test_hybrid_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     the chip's compilers at its own shape: the tiled attention kernels
     under the causal tables (4,096 rows, 32 query heads on 2 key/value
     heads of 128: 16 heads a group, 2,048 rows a tile against 512 keys,
-    within the 64 MB VMEM limit), and the two kernels of the chunked
+    within the 64 MB VMEM limit; the forward and the fused backward, two
+    kernels), and the two kernels of the chunked
     recurrence (64 heads of 64 in pairs, state 128, 8 groups, 32 chunks
     of 128: a step is a group's chunk, its decay tiles never leave
     VMEM). The registry would hand this CPU process the xla executor, so
@@ -235,7 +239,7 @@ def test_hybrid_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
 
     text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     # live tiles only: 144 of the 256 tile pairs of 128 x 512
     assert len(att._causal_live_tiles(seq, 128, 512)) == 144
 
@@ -274,7 +278,8 @@ def test_latent_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     causal tables with 20 query heads on 20 key/value heads of 256 (a
     group of 1, which the rule gives a query tile of 512: 512 rows a
     grid step against 512 keys, 36 live tile pairs a head, within the
-    64 MB VMEM limit), and the grouped experts' kernels at a hidden
+    64 MB VMEM limit; the forward and the fused backward, two kernels),
+    and the grouped experts' kernels at a hidden
     width of 1,536, whose three matrices pass the VMEM cap whole and run
     as two slices of 768 inside one block (the forward and both backward
     kernels twice each)."""
@@ -296,7 +301,7 @@ def test_latent_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     assert len(att._causal_live_tiles(seq, 512, 512)) == 36
     text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
     rows, d, f, held, experts, top_k = seq, 2048, 1536, 8, 64, 4
     pairs, chunk = rows * top_k, expert_chunk_rows(rows, top_k, experts)
@@ -327,7 +332,8 @@ def test_short_conv_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     16 rows, the filter's gradient one resident block), and the tiled
     attention kernels under the causal tables with 32 query heads on 8
     key/value heads of 64: the head is the blocks' whole last dimension,
-    a group of 4 takes a query tile of 256 against 512 keys. The
+    a group of 4 takes a query tile of 256 against 512 keys, and the
+    fused backward keeps dK and dV of 8,192 rows resident. The
     registry would hand this CPU process the xla executor, so the
     kernels' entries are compiled themselves."""
     from deeplearning4j_tpu.ops import attention as att
@@ -363,4 +369,4 @@ def test_short_conv_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     assert len(att._causal_live_tiles(seq, 256, 512)) == 272
     text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
