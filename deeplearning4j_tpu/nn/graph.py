@@ -83,15 +83,6 @@ class ComputationGraph(Trainer):
                 else:
                     input_types[name] = None
 
-        # block-fusion pass: pattern-match bottleneck tails on the RESOLVED
-        # configs (nn/fusion.py); applied in _walk for training walks only
-        from deeplearning4j_tpu.nn import fusion as _fusion
-        self._fusion_plans = _fusion.find_fusable_chains(
-            self._resolved_confs, self.conf.vertex_inputs,
-            self.conf.network_outputs,
-            default_activation=gc.activation or "sigmoid")
-        self._fusion_interior = _fusion.interior_vertices(self._fusion_plans)
-
         self._init_trees(seed, structure_only)
         return self
 
@@ -196,22 +187,13 @@ class ComputationGraph(Trainer):
         new_state = dict(state)
         from deeplearning4j_tpu.nn.conf.vertices import (
             DuplicateToTimeSeriesVertex, LastTimeStepVertex)
-        # training walks route matched bottleneck tails through the fused
-        # op (nn/fusion.py); eval walks use the per-vertex path (running
-        # statistics, no batch stats)
-        plans = getattr(self, "_fusion_plans", None) or {}
-        if not train:
-            plans = {}
-        interior = self._fusion_interior if plans else frozenset()
         # selective block remat: maximal contiguous topo runs of vertices
         # matching DL4J_TPU_REMAT prefixes execute under one
         # jax.checkpoint (span inputs saved, interiors recomputed in the
-        # backward). Plain path only: fusion plans and masked inputs
-        # fall back to inline execution.
+        # backward). Masked inputs fall back to inline execution.
         remat = ((self.remat_prefixes if self.remat_prefixes is not None
                   else _remat_prefixes()) if train else ())
-        spans = (self._remat_spans(remat, set(need_inputs_of))
-                 if remat and not plans else {})
+        spans = self._remat_spans(remat, set(need_inputs_of)) if remat else {}
         topo_i = 0
         topo = self.topo
         while topo_i < len(topo):
@@ -225,18 +207,6 @@ class ComputationGraph(Trainer):
                 topo_i += step
                 continue
             topo_i += 1
-            if name in interior:
-                continue
-            if name in plans:
-                from deeplearning4j_tpu.nn import fusion as _fusion
-                fb = plans[name]
-                with _opindex.scope(name):
-                    y, bn_state_new = _fusion.execute_fused_tail(
-                        fb, self, params, state, acts)
-                acts[name] = y
-                masks[name] = None
-                new_state[fb.bn] = bn_state_new
-                continue
             conf = self._resolved_confs[name]
             in_names = self.conf.vertex_inputs[name]
             xs = [acts[i] for i in in_names]

@@ -9,37 +9,13 @@ ZeroPaddingLayer.java. Backprop is JAX autodiff.
 
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.ops import initializers as init_mod
 from deeplearning4j_tpu.ops import registry as ops
-from deeplearning4j_tpu.ops.convolution import (conv2d_space_to_depth,
-                                                conv2d_strided_1x1_as_slice)
 from deeplearning4j_tpu.ops.convolution import pair as _pair
 from deeplearning4j_tpu.ops.convolution import spatial_padding
-
-
-def _s2d_stem_enabled() -> bool:
-    """Space-to-depth lowering for few-channel odd-kernel s2 convs (the
-    ResNet stem). Exact rewrite; MEASURED NEUTRAL end-to-end on ResNet-50
-    (min-of-runs 99.5 vs 99.8 ms — inside the chip's ~3.5% run-to-run
-    weather; PERF.md round 5), so default off: no graph change without a
-    measured win. The standard TPU transform is kept as tested machinery
-    for stem-dominated models (the win MLPerf sees on the 7x7 stem is
-    already captured by XLA's own lane packing on this stack)."""
-    return os.environ.get("DL4J_TPU_S2D_STEM", "0") == "1"
-
-
-def _slice_1x1_enabled() -> bool:
-    """Strided-1x1-as-slice lowering for unpadded projection convs.
-    Exact rewrite; MEASURED NEGATIVE end-to-end on ResNet-50 (+4-7
-    ms/step, PERF.md round 5 — the materialized quarter tensor loses to
-    XLA's strided window walk), so default off; kept as tested machinery
-    for architectures where the projection share is larger."""
-    return os.environ.get("DL4J_TPU_SLICE_1X1", "0") == "1"
 
 
 class ConvolutionLayer(Layer):
@@ -65,17 +41,9 @@ class ConvolutionLayer(Layer):
             (x.shape[1], x.shape[2]), (kh, kw), (sh, sw),
             _pair(self.conf.padding), self.conf.mode, (dh, dw))
         cd = self.compute_dtype
-        xc, wc = x.astype(cd), params["W"].astype(cd)
-        if (kh == kw == 1 and (sh > 1 or sw > 1) and (dh, dw) == (1, 1)
-                and all(p == (0, 0) for p in pads) and _slice_1x1_enabled()):
-            z = conv2d_strided_1x1_as_slice(xc, wc, strides=(sh, sw))
-        elif ((sh, sw) == (2, 2) and (dh, dw) == (1, 1) and kh % 2 == 1
-                and kw % 2 == 1 and kh >= 5 and x.shape[-1] <= 8
-                and _s2d_stem_enabled()):
-            z = conv2d_space_to_depth(xc, wc, padding=pads)
-        else:
-            z = ops.get("conv2d")(
-                xc, wc, strides=(sh, sw), padding=pads, dilation=(dh, dw))
+        z = ops.get("conv2d")(
+            x.astype(cd), params["W"].astype(cd), strides=(sh, sw),
+            padding=pads, dilation=(dh, dw))
         if "b" in params:
             z = z + params["b"].astype(cd)
         # stay in compute dtype (bf16 activations end-to-end under the

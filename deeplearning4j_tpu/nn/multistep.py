@@ -4,18 +4,13 @@ and ComputationGraph fit_batch_repeated."""
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
-
-def _unroll() -> int:
-    """Scan unroll factor (default 2: lets XLA overlap the tail of one
-    step with the head of the next, measured ~2% on the ResNet-50 bench;
-    4 was measured NEUTRAL there — more unrolling only grows the program.
-    Override with DL4J_TPU_SCAN_UNROLL for experiments)."""
-    return max(1, int(os.environ.get("DL4J_TPU_SCAN_UNROLL", "2")))
+# Scan unroll factor: 2 lets XLA overlap the tail of one step with the
+# head of the next, measured ~2% on the ResNet-50 bench; 4 was measured
+# NEUTRAL there — more unrolling only grows the program.
+_UNROLL = 2
 
 
 def build_multi_step(step_fn, n_steps: int):
@@ -37,7 +32,7 @@ def build_multi_step(step_fn, n_steps: int):
 
         (p, s, o, _), scores = jax.lax.scan(
             body, (params, state, opt_state, rng), jnp.arange(n_steps),
-            unroll=min(_unroll(), n_steps))
+            unroll=min(_UNROLL, n_steps))
         return p, s, o, scores[-1]
 
     return jax.jit(multi, donate_argnums=(0, 1, 2))

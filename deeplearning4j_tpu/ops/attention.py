@@ -22,8 +22,7 @@ Three backends behind the ``causal_mha`` registry seam:
   the running (m, l, acc) carried in f32 VMEM scratch, causal tile-skip
   above the diagonal, the [t, t] score matrix never materialized to HBM.
   Guarded by ``attention_supported``; it silently delegates to the xla
-  backend everywhere else, the same graceful fallback as
-  ops/fused_block.py. It compiles under Mosaic on the v5e and matches
+  backend everywhere else. It compiles under Mosaic on the v5e and matches
   ``xla_dot`` there (``chip_smoke.py``, kernels phase); no benchmark
   cell runs it, so its speed is still unmeasured. Its backward
   recomputes through the xla_dot formulation (a custom_vjp), which

@@ -104,65 +104,6 @@ def conv1d_xla(x, w, *, stride, padding, dilation=1):
 
 
 # ---------------------------------------------------------------------------
-# Exact stride-2 conv rewrites (TPU lowering details, invisible to configs)
-# ---------------------------------------------------------------------------
-
-def conv2d_space_to_depth(x, w, *, padding):
-    """Exact space-to-depth lowering of an odd-kernel stride-2 conv.
-
-    The standard TPU ResNet stem transform: a kxk/s2 conv on a
-    few-channel input (e.g. 7x7/s2 on [N,224,224,3]) keeps the MXU's
-    contracting dimension at C_in*kw = 21 lanes and makes XLA pad/relayout
-    the big activation. Folding 2x2 spatial blocks into channels
-    ([N,115,115,12] here) and re-blocking the kernel (7x7 zero-padded to
-    8x8, reshaped to 4x4 over 4*C_in channels) yields a bit-identical
-    stride-1 VALID conv with 4x the contracting depth and no strided
-    window walk. Params keep their reference shape [kh,kw,C_in,C_out];
-    the re-blocking is a per-step reshape of a tiny weight tensor, and
-    autodiff derives the matching backward through it.
-
-    Exactness: y[i,j] = sum_{di,dj,c} w[di,dj,c] * xp[2i+di, 2j+dj, c]
-    with di = 2p+a, dj = 2q+b becomes a (kh+1)/2 x (kw+1)/2 window over
-    the block grid; the zero row/col added to w absorbs the odd kernel.
-    """
-    n, h, wd, c = x.shape
-    kh, kw, _, c_out = w.shape
-    (lo_h, hi_h), (lo_w, hi_w) = padding
-    big_kh, big_kw = kh + (kh % 2), kw + (kw % 2)
-    out_h = (h + lo_h + hi_h - kh) // 2 + 1
-    out_w = (wd + lo_w + hi_w - kw) // 2 + 1
-    pad_h = 2 * (out_h - 1) + big_kh
-    pad_w = 2 * (out_w - 1) + big_kw
-    xp = jnp.pad(x, [(0, 0), (lo_h, pad_h - h - lo_h),
-                     (lo_w, pad_w - wd - lo_w), (0, 0)])
-    xsd = xp.reshape(n, pad_h // 2, 2, pad_w // 2, 2, c)
-    xsd = xsd.transpose(0, 1, 3, 2, 4, 5).reshape(
-        n, pad_h // 2, pad_w // 2, 4 * c)
-    w8 = jnp.pad(w, [(0, big_kh - kh), (0, big_kw - kw), (0, 0), (0, 0)])
-    wsd = w8.reshape(big_kh // 2, 2, big_kw // 2, 2, c, c_out)
-    wsd = wsd.transpose(0, 2, 1, 3, 4, 5).reshape(
-        big_kh // 2, big_kw // 2, 4 * c, c_out)
-    return lax.conv_general_dilated(
-        xsd, wsd, window_strides=(1, 1), padding=[(0, 0), (0, 0)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-
-def conv2d_strided_1x1_as_slice(x, w, *, strides):
-    """Exact rewrite of an unpadded 1x1 strided conv as slice + 1x1/s1.
-
-    A 1x1/s2 projection conv reads every other row/column; XLA's strided
-    conv lowering inserts layout copies around it (PERF.md lever #1).
-    Slicing first hands XLA a dense quarter-size 1x1 conv (a plain GEMM)
-    and lets the slice fuse with the producer.
-    """
-    sh, sw = strides
-    return lax.conv_general_dilated(
-        x[:, ::sh, ::sw, :], w, window_strides=(1, 1),
-        padding=[(0, 0), (0, 0)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-
-# ---------------------------------------------------------------------------
 # Pooling (SubsamplingLayer.java semantics)
 # ---------------------------------------------------------------------------
 

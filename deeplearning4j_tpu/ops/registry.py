@@ -28,9 +28,7 @@ _LOCK = threading.Lock()
 _IMPLS: dict[str, dict[str, callable]] = {}
 
 # Preference order; "pallas" first means use the hand kernel when one exists.
-_DEFAULT_ORDER = ("pallas", "xla") if os.environ.get(
-    "DL4J_TPU_PREFER_PALLAS", "1"
-) == "1" else ("xla",)
+_DEFAULT_ORDER = ("pallas", "xla")
 _order = list(_DEFAULT_ORDER)
 
 

@@ -3,8 +3,11 @@ layer type (CNNGradientCheckTest.java / BNGradientCheckTest.java /
 LRNGradientCheckTests.java / GlobalPoolingGradientCheckTests.java analogue),
 and a LeNet end-to-end smoke run (MultiLayerTest-style convergence)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from deeplearning4j_tpu.datasets import ArrayDataSetIterator, DataSet
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
@@ -213,48 +216,74 @@ def test_lenet_learns_synthetic_mnist():
     assert acc > 0.9, f"LeNet failed to learn: acc={acc}"
 
 
-class TestStride2Rewrites:
-    """The exact conv lowerings behind DL4J_TPU_S2D_STEM /
-    DL4J_TPU_SLICE_1X1 (PERF.md round 5) must match the direct
-    lax.conv lowering bit-for-bit in f32 — values AND gradients."""
+# ------------------------------------------- the layer against lax directly
+# (kernel, stride, mode, dilation, h, c_in, c_out): the ResNet-50 stem on 3
+# channels, its unpadded 1x1/s2 projection, and the block convolutions
+CONV_GEOMETRIES = {
+    "stem7x7s2_h28_same": (7, 2, "same", 1, 28, 3, 8),
+    "stem7x7s2_h29_same": (7, 2, "same", 1, 29, 3, 8),
+    "stem7x7s2_h28_truncate": (7, 2, "truncate", 1, 28, 3, 8),
+    "proj1x1s2_h56": (1, 2, "same", 1, 56, 16, 8),
+    "proj1x1s2_h57": (1, 2, "same", 1, 57, 16, 8),
+    "3x3s1_same": (3, 1, "same", 1, 12, 8, 8),
+    "3x3s2_same": (3, 2, "same", 1, 13, 8, 8),
+    "1x1s1": (1, 1, "same", 1, 12, 16, 32),
+    "3x3_dilation2": (3, 1, "same", 2, 12, 8, 8),
+}
+# bf16 compute rounds x, W, the output and the cotangents to 8 significant
+# bits (at most 0.6% of the largest reference entry here): 2%. The bias's
+# gradient is a bf16 sum of thousands of cotangents and is left out
+CONV_TOL = {"float32": 1e-5, "bfloat16": 2e-2}
 
-    def test_space_to_depth_matches_direct(self):
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-        from deeplearning4j_tpu.ops.convolution import (
-            conv2d_space_to_depth, spatial_padding)
-        rng = np.random.default_rng(0)
-        for h, mode in ((28, "same"), (29, "same"), (28, "truncate")):
-            x = jnp.asarray(rng.normal(size=(2, h, h, 3)), jnp.float32)
-            w = jnp.asarray(rng.normal(size=(7, 7, 3, 8)), jnp.float32)
-            pads = spatial_padding((h, h), (7, 7), (2, 2), (0, 0), mode)
-            ref = lax.conv_general_dilated(
-                x, w, (2, 2), pads,
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))
-            got = conv2d_space_to_depth(x, w, padding=pads)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                       rtol=2e-5, atol=2e-5)
-            g_ref = jax.grad(lambda w: jnp.sum(lax.conv_general_dilated(
-                x, w, (2, 2), pads,
-                dimension_numbers=("NHWC", "HWIO", "NHWC")) ** 2))(w)
-            g_got = jax.grad(lambda w: jnp.sum(
-                conv2d_space_to_depth(x, w, padding=pads) ** 2))(w)
-            np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
-                                       rtol=2e-4, atol=2e-4)
 
-    def test_strided_1x1_slice_matches_direct(self):
-        import jax.numpy as jnp
-        from jax import lax
-        from deeplearning4j_tpu.ops.convolution import (
-            conv2d_strided_1x1_as_slice)
-        rng = np.random.default_rng(1)
-        for h in (56, 57):
-            x = jnp.asarray(rng.normal(size=(2, h, h, 16)), jnp.float32)
-            w = jnp.asarray(rng.normal(size=(1, 1, 16, 8)), jnp.float32)
-            ref = lax.conv_general_dilated(
-                x, w, (2, 2), [(0, 0), (0, 0)],
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))
-            got = conv2d_strided_1x1_as_slice(x, w, strides=(2, 2))
-            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                       rtol=1e-5, atol=1e-5)
+@pytest.mark.parametrize("compute", sorted(CONV_TOL))
+@pytest.mark.parametrize("geometry", sorted(CONV_GEOMETRIES))
+def test_conv_layer_matches_lax_reference(geometry, compute):
+    """``ConvolutionLayer`` forward, dW and dx against a direct float32
+    ``lax.conv_general_dilated`` whose padding lax derives itself."""
+    k, s, mode, d, h, c_in, c_out = CONV_GEOMETRIES[geometry]
+    policy = DtypePolicy(param_dtype="float32", compute_dtype=compute)
+    conf = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(0.1))
+            .dtype(policy).list()
+            .layer(Convolution2D(n_out=c_out, kernel=(k, k), stride=(s, s),
+                                 dilation=(d, d), mode=mode,
+                                 activation="identity"))
+            .layer(Output(n_out=3, activation="softmax", loss="mcxent"))
+            .set_input_type(InputType.convolutional(h, h, c_in))
+            .build())
+    net = MultiLayerNetwork(conf).init()
+    layer = net.layers[0]
+    rng = np.random.default_rng(k * 100 + h)
+    params = {"W": jnp.asarray(rng.normal(size=(k, k, c_in, c_out)),
+                               jnp.float32),
+              "b": jnp.asarray(rng.normal(size=(c_out,)), jnp.float32)}
+    x = jnp.asarray(rng.normal(size=(2, h, h, c_in)), jnp.float32)
+
+    def ref(params, x):
+        return lax.conv_general_dilated(
+            x, params["W"], (s, s), "SAME" if mode == "same" else "VALID",
+            rhs_dilation=(d, d),
+            dimension_numbers=("NHWC", "HWIO", "NHWC")) + params["b"]
+
+    def got(params, x):
+        y, _ = layer.apply(params, {}, x, train=True)
+        return y
+
+    want = ref(params, x)
+    y = got(params, x)
+    assert y.dtype == jnp.dtype(compute) and y.shape == want.shape
+    cot = jnp.asarray(rng.normal(size=want.shape), jnp.float32)
+    tol = CONV_TOL[compute]
+
+    def close(a, b, what):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        err = np.abs(a - b).max()
+        assert err <= tol * np.abs(b).max(), (what, err, np.abs(b).max())
+
+    close(y, want, "y")
+    g_ref = jax.grad(lambda p, x: jnp.sum(ref(p, x) * cot),
+                     argnums=(0, 1))(params, x)
+    g_got = jax.grad(lambda p, x: jnp.sum(got(p, x).astype(jnp.float32)
+                                          * cot), argnums=(0, 1))(params, x)
+    close(g_got[0]["W"], g_ref[0]["W"], "dW")
+    close(g_got[1], g_ref[1], "dx")

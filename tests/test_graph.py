@@ -6,7 +6,11 @@ training, ResNet-style residual blocks, JSON + checkpoint round-trip."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax import lax
+
+from deeplearning4j_tpu import zoo
 
 from deeplearning4j_tpu.datasets import DataSet
 from deeplearning4j_tpu.datasets.dataset import MultiDataSet
@@ -33,6 +37,7 @@ from deeplearning4j_tpu.nn.conf.vertices import (
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.updater import Adam, Sgd
 from deeplearning4j_tpu.utils.gradient_check import gradient_check_fn
+from deeplearning4j_tpu.zoo.models import _bottleneck
 
 F64 = DtypePolicy(param_dtype="float64", compute_dtype="float64")
 
@@ -513,3 +518,121 @@ def test_selective_remat_exact_in_f32(monkeypatch):
             np.testing.assert_array_equal(
                 np.asarray(base.params[ln][pn]),
                 np.asarray(rem.params[ln][pn]), err_msg=f"{ln}.{pn}")
+
+
+# ------------------------------------ one ResNet-50 unit against plain jnp
+def _bottleneck_reference(params, state, x, *, stride, project, train):
+    """``zoo`` bottleneck unit (1x1 -> 3x3 -> 1x1 x4, batch norm after each
+    convolution, identity or projected shortcut) in float32 jnp; batch
+    statistics in training (biased variance), running ones otherwise.
+    Returns the unit's output and the running statistics a step leaves."""
+    new_state = {}
+
+    def conv_bn(name, h, s):
+        z = lax.conv_general_dilated(
+            h, params[f"{name}_conv"]["W"], (s, s), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        p, run = params[f"{name}_bn"], state[f"{name}_bn"]
+        if train:
+            mean = z.mean((0, 1, 2))
+            var = ((z - mean) ** 2).mean((0, 1, 2))
+            new_state[f"{name}_bn"] = {
+                "mean": 0.9 * run["mean"] + 0.1 * mean,
+                "var": 0.9 * run["var"] + 0.1 * var}
+        else:
+            mean, var = run["mean"], run["var"]
+        return (z - mean) / jnp.sqrt(var + 1e-5) * p["gamma"] + p["beta"]
+
+    a = jax.nn.relu(conv_bn("u_a", x, stride))
+    b = jax.nn.relu(conv_bn("u_b", a, 1))
+    c = conv_bn("u_c", b, 1)
+    shortcut = conv_bn("u_proj", x, stride) if project else x
+    return jax.nn.relu(c + shortcut), new_state
+
+
+# (values, gradients). Values: largest error over largest reference entry;
+# bf16 rounds activations to 8 significant bits through three convolutions
+# and batch norms (at most 1.5% here). Gradients: norm of the error over
+# the norm of the reference gradient, every leaf of the unit and dx in one
+# vector; bf16's backward rounds each cotangent through three batch norms
+# (1 to 16% over seeds and sizes on the CPU)
+UNIT_TOL = {"float32": (1e-4, 1e-4), "bfloat16": (4e-2, 0.25)}
+
+
+@pytest.mark.parametrize("compute", sorted(UNIT_TOL))
+@pytest.mark.parametrize("project", [False, True],
+                         ids=["identity", "projection_s2"])
+def test_bottleneck_unit_matches_composed_reference(project, compute):
+    """One ``zoo`` bottleneck unit on the graph's walk: the train-mode
+    output and gradients, batch norm's running statistics after one
+    ``fit`` step, and the eval-mode output, against plain jnp."""
+    stride, c_in, h = (2, 8, 8) if project else (1, 16, 6)
+    policy = {"float32": zoo.F32, "bfloat16": zoo.BF16}[compute]
+    g = (NeuralNetConfiguration.builder().seed(7).updater(Sgd(0.1))
+         .dtype(policy).graph_builder().add_inputs("img"))
+    out = _bottleneck(g, "u", "img", 4, stride, project)
+    g.add_layer("pool", GlobalPooling(pooling="avg"), out)
+    g.add_layer("fc", Output(n_out=3, activation="softmax", loss="mcxent"),
+                "pool")
+    net = ComputationGraph(
+        g.set_outputs("fc")
+        .set_input_types(InputType.convolutional(h, h, c_in)).build()).init()
+
+    rng = np.random.default_rng(11 + project)
+
+    def draw(shape, lo=None):
+        v = rng.normal(size=shape) if lo is None else rng.uniform(
+            lo, 2.0, shape)
+        return jnp.asarray(v, jnp.float32)
+
+    # batch norm away from its identity start: gamma, beta and the running
+    # statistics (the centre of the variance's single pass) all drawn
+    for name in net.state:
+        f = net.state[name]["mean"].shape
+        net.params[name] = {"gamma": draw(f, 0.5), "beta": draw(f)}
+        net.state[name] = {"mean": draw(f), "var": draw(f, 0.5)}
+    x = draw((4, h, h, c_in))
+    tol_value, tol_grad = UNIT_TOL[compute]
+
+    def close(a, b, what):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        err = np.abs(a - b).max()
+        assert err <= tol_value * np.abs(b).max(), (what, err,
+                                                    np.abs(b).max())
+
+    def system(params, x):
+        acts, _, _, new_state = net._walk(params, net.state, {"img": x},
+                                          train=True, rng=None)
+        return acts[out], new_state
+
+    def reference(params, x):
+        return _bottleneck_reference(params, net.state, x, stride=stride,
+                                     project=project, train=True)
+
+    y, _ = system(net.params, x)
+    want, want_state = reference(net.params, x)
+    close(y, want, "train output")
+    cot = draw(want.shape)
+
+    def grads(fn):
+        gp, gx = jax.grad(lambda p, x: jnp.sum(
+            fn(p, x)[0].astype(jnp.float32) * cot), argnums=(0, 1))(
+                net.params, x)
+        unit = {n: gp[n] for n in net.params if n.startswith("u_")}
+        return np.concatenate([np.ravel(np.asarray(v, np.float64)) for v in
+                               jax.tree_util.tree_leaves((unit, gx))])
+
+    got_g, want_g = grads(system), grads(reference)
+    err = np.linalg.norm(got_g - want_g) / np.linalg.norm(want_g)
+    assert err <= tol_grad, err
+
+    net.fit_batch(DataSet(np.asarray(x), np.eye(3, dtype=np.float32)[
+        rng.integers(0, 3, 4)]))
+    for name in want_state:
+        for k in ("mean", "var"):
+            close(net.state[name][k], want_state[name][k], f"{name}.{k}")
+
+    got = net.feed_forward(np.asarray(x))[out]
+    want, _ = _bottleneck_reference(net.params, net.state, x, stride=stride,
+                                    project=project, train=False)
+    close(got, want, "eval output")
