@@ -4,7 +4,7 @@ query/key norm, routed experts of which this chip holds a share, a
 state-space mixer, a gated short convolution, and a head with integer
 labels and per-token weights.
 
-Four decoders are built of them (PERF.md section 4 has the models):
+Five decoders are built of them (PERF.md section 4 has the models):
 
 - a block-diffusion decoder (``zoo.sdar_moe``): ``TokenEmbedding``,
   ``MoeDecoderBlock`` (attention under the block-diffusion mask, then a
@@ -35,23 +35,27 @@ Four decoders are built of them (PERF.md section 4 has the models):
   gated silu experts without a shared one in the rest (``*MoeBlock``);
   ``RmsNorm`` and ``TokenOutput(causal=True, tied_to=<the embedding>)``,
   a head that reads the embedding's own matrix.
+- a causal sparse-attention decoder (``zoo.keye_vl2_moe``):
+  ``TokenEmbedding``, ``SparseMoeBlock`` (grouped-query attention over
+  the keys a learned indexer selects for each row, then a softmax router
+  over gated silu experts), ``RmsNorm``, ``TokenOutput(causal=True)``.
 
 Which decoder uses what:
 
-    ======================  ==========  ==========  ==============  ==========
-                            sdar_moe    nemotron_h  glm4_moe_lite   lfm2_moe
-    ======================  ==========  ==========  ==============  ==========
-    TokenEmbedding          x           x           x (two users)   x (tied)
-    RmsNorm                 x           x           without module  x
-    TokenOutput             causal=F    causal=T    without module  tied_to
+    ======================  ==========  ==========  ==============  ==========  ==========
+                            sdar_moe    nemotron_h  glm4_moe_lite   lfm2_moe    keye_vl2
+    ======================  ==========  ==========  ==============  ==========  ==========
+    TokenEmbedding          x           x           x (two users)   x (tied)    x
+    RmsNorm                 x           x           without module  x           x
+    TokenOutput             causal=F    causal=T    without module  tied_to     causal=T
     MoeDecoderBlock         x
     CausalAttention                     x
     Mamba2Mixer                         x
-    RoutedExperts           (in block)  x           (in blocks)     (in blocks)
-      router                softmax     sigmoid     sigmoid         sigmoid
-      router_eps            1e-20       1e-20       1e-20           1e-6
-      expert_form           gated_silu  relu2       gated_silu      gated_silu
-      shared expert         none        relu2       gated_silu      none
+    RoutedExperts           (in block)  x           (in blocks)     (in blocks) (in block)
+      router                softmax     sigmoid     sigmoid         sigmoid     softmax
+      router_eps            1e-20       1e-20       1e-20           1e-6        1e-20
+      expert_form           gated_silu  relu2       gated_silu      gated_silu  gated_silu
+      shared expert         none        relu2       gated_silu      none        none
     LatentDenseBlock                                x
     LatentMoeBlock                                  x
     MtpTokenOutput                                  with module
@@ -59,11 +63,13 @@ Which decoder uses what:
     ShortConvMoeBlock                                               x
     CausalDenseBlock                                                (pattern)
     CausalMoeBlock                                                  x
-    ops/attention.py        block_diff  causal      causal          causal, 64
-    ops/grouped.py          kernels     chunk loop  kernels, 2 sl.  kernels, 2 sl.
+    SparseMoeBlock                                                              x
+    ops/attention.py        block_diff  causal      causal          causal, 64  (bodies)
+    ops/sparse_attention.py                                                     x
+    ops/grouped.py          kernels     chunk loop  kernels, 2 sl.  kernels, 2 sl. kernels
     ops/ssm.py                          x
     ops/shortconv.py                                                x
-    ======================  ==========  ==========  ==============  ==========
+    ======================  ==========  ==========  ==============  ==========  ==========
 
 Layout as the recurrent family: ``[batch, time, features]``, but the
 first layer takes ``[batch, time]`` integer ids (``InputType.recurrent(
@@ -291,6 +297,41 @@ class CausalMoeBlock(_RotatedHeads, RoutedExperts):
     def make_layer(self, input_type, global_conf, policy):
         from deeplearning4j_tpu.nn.layers.decoder import CausalMoeBlockLayer
         return CausalMoeBlockLayer(self, input_type, global_conf, policy)
+
+
+@register_layer
+@dataclass(frozen=True)
+class SparseMoeBlock(_RotatedHeads, RoutedExperts):
+    """One decoder layer: ``CausalMoeBlock``'s, whose attention takes for
+    each row only the ``index_topk`` earlier keys a learned indexer
+    scores highest (DeepSeek Sparse Attention). For ``u = RMSNorm(x)``,
+    row ``t`` at position ``t``, ``R_t`` the rotation over the whole
+    head:
+
+        qI_t,j = R_t (W_IQ u_t)_j                 j = 1..index_heads, index_head_dim each
+        kI_s   = R_s LayerNorm(W_IK u_s)          index_head_dim, one key head
+        w_t,j  = (W_w u_t)_j / sqrt(index_heads * index_head_dim)
+        I_t,s  = sum_j w_t,j relu(qI_t,j . kI_s)     s <= t, float32
+        S_t    = the index_topk largest I_t,s (all t + 1 keys where t < index_topk),
+                 ties to the lower s, shared by every head
+        x + W_o concat_h(softmax_{s in S_t}(q_h,t . k_g(h),s / sqrt(head_dim)) v_g(h),s)
+
+    then the routed experts. The indexer reads ``u`` without its
+    gradient, and trains on a loss of its own that the layer hands the
+    net (``MultiLayerNetwork._loss`` adds it): ``L_I = mean_t sum_{s in
+    S_t} p_t,s (log p_t,s - log softmax_{S_t}(I_t)_s)``, ``p`` the
+    attention's probabilities averaged over the heads, without a
+    gradient. So the indexer's leaves learn from ``L_I`` alone and every
+    other leaf from the data loss alone."""
+
+    layer_type = "sparse_moe_block"
+    index_heads: int = 4
+    index_head_dim: int = 16
+    index_topk: int = 16
+
+    def make_layer(self, input_type, global_conf, policy):
+        from deeplearning4j_tpu.nn.layers.decoder import SparseMoeBlockLayer
+        return SparseMoeBlockLayer(self, input_type, global_conf, policy)
 
 
 @register_layer

@@ -13,7 +13,11 @@ in the compute dtype, as in every other net of this package.
 Named scopes inside a layer, under the layer's own: ``attn`` (norm,
 projections, head norms, rotation, output projection, and inside it
 ``block_attention`` or ``causal_attention`` round the attention itself,
-whatever backend runs; a latent attention has two more inside it,
+whatever backend runs; a sparse attention has ``dsa_indexer`` round the
+indexer's three projections, its norm and rotation, ``dsa_select``
+round its scores and top-k, ``sparse_attention`` round the attention
+over the selection and ``dsa_kl`` round the indexer's loss; a latent
+attention has two more inside it,
 ``mla_down`` round both compressions and their norms and ``mla_up``
 round both expansions, the rotation and the broadcast of the rotated
 key slice), ``dense_mlp`` (a dense layer's norm and three products),
@@ -45,11 +49,13 @@ import math
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.nn.layers.attention import _layer_norm
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import grouped
 from deeplearning4j_tpu.ops import initializers as init_mod
 from deeplearning4j_tpu.ops import shortconv, ssm
+from deeplearning4j_tpu.ops import sparse_attention as sa
 
 
 def _rms_norm(x, g, eps):
@@ -356,14 +362,20 @@ class _GroupedQueryHeads:
             "Wo": self._init(ko, (hq * dh, d), hq * dh, d),
         }
 
+    def _normed(self, params, x):
+        return _rms_norm(x, params["attn_ln_g"], self.conf.eps).astype(x.dtype)
+
     def _heads(self, params, x):
         """q [b, t, hq, dh], k and v [b, t, hkv, dh] of the normed rows,
         in ``x``'s dtype."""
-        conf, cd = self.conf, x.dtype
-        b, t, _ = x.shape
+        return self._projected_heads(params, self._normed(params, x))
+
+    def _projected_heads(self, params, u):
+        """``_heads`` of the normed rows ``u``."""
+        conf, cd = self.conf, u.dtype
+        b, t, _ = u.shape
         hq, hkv, dh = int(conf.n_heads), int(conf.n_kv_heads), int(
             conf.head_dim)
-        u = _rms_norm(x, params["attn_ln_g"], conf.eps).astype(cd)
         return (_project(u, params["Wq"], cd).reshape(b, t, hq, dh),
                 _project(u, params["Wk"], cd).reshape(b, t, hkv, dh),
                 _project(u, params["Wv"], cd).reshape(b, t, hkv, dh))
@@ -388,8 +400,10 @@ class _RotatedHeads(_GroupedQueryHeads):
     def _rotated_heads(self, params, x, pos):
         """``_heads`` with q and k normed and rotated, row ``i`` at
         ``pos[i]``."""
-        conf, cd = self.conf, x.dtype
-        q, k, v = self._heads(params, x)
+        return self._normed_rotated(params, *self._heads(params, x), pos)
+
+    def _normed_rotated(self, params, q, k, v, pos):
+        conf, cd = self.conf, q.dtype
         q = _rotate(_rms_norm(q, params["q_norm_g"], conf.eps),
                     conf.rope_theta, pos).astype(cd)
         k = _rotate(_rms_norm(k, params["k_norm_g"], conf.eps),
@@ -442,6 +456,97 @@ class MoeDecoderBlockLayer(_RotatedHeadsMoeBlock):
         with jax.named_scope("attn"):
             a = self._attention(params, x.astype(self.compute_dtype))
         return self._experts(params, state, a)
+
+
+class SparseMoeBlockLayer(_RotatedHeadsMoeBlock):
+    """``CausalMoeBlockLayer`` whose attention takes, for every row, the
+    ``index_topk`` keys a learned indexer scores highest (nn/conf/
+    layers_decoder.py ``SparseMoeBlock`` has the equations). Parameters
+    beside the block's: ``W_IQ`` [d, nI dI], ``W_IK`` [d, dI],
+    ``kI_ln_g`` and ``kI_ln_b`` [dI], ``W_w`` [d, nI].
+
+    The indexer's loss (``ops/sparse_attention.py`` ``dsa_indexer_loss``)
+    is a term of the net's loss that this layer makes: it rides in the
+    new state under ``own_loss`` and ``MultiLayerNetwork._loss`` adds it.
+    Its gradient reaches the indexer's five leaves alone, and the data
+    loss's none of them: the indexer reads the normed rows without their
+    gradient, and the selection has none. Beside it the state holds the
+    last step's selected pairs (``dsa_selected_pairs``) and the tile
+    pairs the attention kernels walked and skipped (``dsa_tiles``).
+
+    With ``hands_back_selection`` set while a forward is traced, that
+    forward's new state also carries the selection's words under
+    ``selection``: a check reads what the layer chose on its own path.
+    A training step is never traced with it set (8 MB a layer at 8,192
+    rows)."""
+
+    own_loss = "dsa_indexer_kl"
+    hands_back_selection = False
+
+    def init_params(self, key):
+        k_block, kq, kk, kw = jax.random.split(key, 4)
+        params = super().init_params(k_block)
+        d = int(self.conf.n_out)
+        n, di = int(self.conf.index_heads), int(self.conf.index_head_dim)
+        params.update({
+            "W_IQ": self._init(kq, (d, n * di), d, n * di),
+            "W_IK": self._init(kk, (d, di), d, di),
+            "kI_ln_g": jnp.ones((di,), self.param_dtype),
+            "kI_ln_b": jnp.zeros((di,), self.param_dtype),
+            "W_w": self._init(kw, (d, n), d, n),
+        })
+        return params
+
+    def init_state(self):
+        return {**super().init_state(),
+                "dsa_indexer_kl": jnp.zeros((), jnp.float32),
+                "dsa_selected_pairs": jnp.zeros((), jnp.int32),
+                "dsa_tiles": jnp.zeros((2,), jnp.int32)}
+
+    def _indexer(self, params, u, pos):
+        """qI [b, t, nI, dI] and kI [b, t, dI] rotated, in ``u``'s dtype,
+        and ``w`` [b, t, nI] float32, of the normed rows ``u`` without
+        their gradient."""
+        conf, cd = self.conf, u.dtype
+        b, t, _ = u.shape
+        n, di = int(conf.index_heads), int(conf.index_head_dim)
+        u = jax.lax.stop_gradient(u)
+        q_index = _rotate(_project(u, params["W_IQ"], cd).reshape(b, t, n, di)
+                          .astype(jnp.float32), conf.rope_theta, pos)
+        k_index = _layer_norm(_project(u, params["W_IK"], cd),
+                              params["kI_ln_g"], params["kI_ln_b"], conf.eps)
+        k_index = _rotate(k_index[:, :, None, :], conf.rope_theta,
+                          pos)[:, :, 0]
+        w = jnp.einsum("btf,fg->btg", u, params["W_w"].astype(cd),
+                       preferred_element_type=jnp.float32) / math.sqrt(n * di)
+        return q_index.astype(cd), k_index.astype(cd), w
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        conf = self.conf
+        x = x.astype(self.compute_dtype)
+        pos = jnp.arange(x.shape[1], dtype=jnp.int32)
+        with jax.named_scope("attn"):
+            u = self._normed(params, x)
+            q, k, v = self._normed_rotated(
+                params, *self._projected_heads(params, u), pos)
+            with jax.named_scope("dsa_indexer"):
+                q_index, k_index, w = self._indexer(params, u, pos)
+            with jax.named_scope("dsa_select"):
+                sel, lse_index = sa.dsa_select(q_index, k_index, w,
+                                               topk=int(conf.index_topk))
+                pairs = sa.selected_pairs(sel)
+            with jax.named_scope("sparse_attention"):
+                o, lse, tiles = sa.sparse_attention(q, k, v, sel)
+            with jax.named_scope("dsa_kl"):
+                kl = sa.dsa_indexer_loss(q, k, lse, q_index, k_index, w, sel,
+                                         lse_index)
+            a = self._merge_heads(params, x, o)
+        y, new_state = self._experts(params, state, a)
+        new_state = {**new_state, "dsa_selected_pairs": pairs,
+                     "dsa_tiles": tiles, "dsa_indexer_kl": kl}
+        if self.hands_back_selection:
+            new_state["selection"] = sel
+        return y, new_state
 
 
 class _DenseMlp:

@@ -242,6 +242,11 @@ class MultiLayerNetwork(Trainer):
             for layer in self.layers:
                 if layer.name in params:
                     reg = reg + layer.regularization(params[layer.name])
+                # a loss term a hidden layer makes of its own, handed
+                # back in its new state under the key ``own_loss`` names
+                own = getattr(layer, "own_loss", None)
+                if own is not None and layer.name in new_state:
+                    reg = reg + new_state[layer.name][own]
             return data_loss + reg, new_state
 
     # ------------------------------------------------ the trainer's adapter

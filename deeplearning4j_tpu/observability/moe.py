@@ -16,7 +16,15 @@ the score gauge too:
   multi-token-prediction module (``MtpTokenOutput``, whose own expert
   layer is counted above like any other), the last step's two losses
   apart, ``part`` ``main`` and ``mtp``, unweighted (``mtp_loss`` in that
-  layer's state; ``mtp_losses(net)`` reads it).
+  layer's state; ``mtp_losses(net)`` reads it);
+- where a layer chooses its attention's keys with a learned indexer
+  (``SparseMoeBlock``), from that layer's state: ``dl4j_dsa_indexer_kl
+  {layer}``, the last step's indexer loss; ``dl4j_dsa_selected_pairs
+  {layer}``, the (row, key) pairs its selection kept; and
+  ``dl4j_sparse_attention_tiles{layer, kind}``, the causal tile pairs
+  the attention kernels ``walked`` and ``skipped`` (none held a kept
+  key; where the XLA form ran, one tile a sequence).
+  ``sparse_attention(net)`` reads them.
 """
 
 from __future__ import annotations
@@ -50,6 +58,21 @@ def mtp_losses(net):
         if "mtp_loss" in s:
             return tuple(float(v) for v in np.asarray(s["mtp_loss"]))
     return None
+
+
+def sparse_attention(net) -> dict:
+    """``{layer: {"kl", "pairs", "walked", "skipped"}}`` of the last step
+    for every layer of ``net`` that selects its keys, as Python numbers.
+    One host read."""
+    state = {name: {k: s[k] for k in ("dsa_indexer_kl", "dsa_selected_pairs",
+                                      "dsa_tiles")}
+             for name, s in (net.state or {}).items()
+             if "dsa_indexer_kl" in s}
+    return {name: {"kl": float(s["dsa_indexer_kl"]),
+                   "pairs": int(s["dsa_selected_pairs"]),
+                   "walked": int(s["dsa_tiles"][0]),
+                   "skipped": int(s["dsa_tiles"][1])}
+            for name, s in jax.device_get(state).items()}
 
 
 def install(net) -> None:
@@ -88,6 +111,25 @@ def install(net) -> None:
                                        np.asarray(s["mtp_loss"])):
                     parts.add(float(value), {"layer": name, "part": part})
                 families.append(parts)
+        sparse = sparse_attention(live)
+        if sparse:
+            kl = MetricFamily(
+                "dl4j_dsa_indexer_kl", "gauge",
+                "The last step's indexer loss (KL divergence of the "
+                "indexer's softmax from the attention's), by layer")
+            pairs = MetricFamily(
+                "dl4j_dsa_selected_pairs", "gauge",
+                "(row, key) pairs the last step's selection kept, by layer")
+            tiles = MetricFamily(
+                "dl4j_sparse_attention_tiles", "gauge",
+                "Causal tile pairs the last step's sparse-attention kernels "
+                "walked and skipped, by layer")
+            for name, v in sparse.items():
+                kl.add(v["kl"], {"layer": name})
+                pairs.add(float(v["pairs"]), {"layer": name})
+                for kind in ("walked", "skipped"):
+                    tiles.add(float(v[kind]), {"layer": name, "kind": kind})
+            families += [kl, pairs, tiles]
         return families
 
     net._moe_collector = get_registry().register_collector(collect)

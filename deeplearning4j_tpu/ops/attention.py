@@ -639,25 +639,47 @@ def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
     import jax.experimental.pallas as pl
 
     s_id = pl.program_id(1)
-    g, bq, dh = q_ref.shape[1:]
+    g, bq, _ = q_ref.shape[1:]
     bk = k_ref.shape[1]
 
-    @pl.when(first_ref[s_id] == 1)
+    def visible():
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
+        kb, lower, upper = _bd_bounds(
+            kind_ref[s_id], qi_ref[s_id] * bq, ki_ref[s_id] * bk, seq_len,
+            shift, lanes & (bq - 1),
+            jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
+        return lambda: (kb <= upper) & (kb >= lower)
+
+    fwd_body(lambda: first_ref[s_id] == 1, lambda: last_ref[s_id] == 1,
+             visible, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+             acc_scr, scale=scale)
+
+
+def fwd_body(first, last, visible, q_ref, k_ref, v_ref, o_ref, lse_ref,
+             m_scr, l_scr, acc_scr, *, scale):
+    """One step of the forward kernels: the pair's scores as [keys,
+    rows] where the mask is true, the running statistics reset where
+    ``first()`` and the output written where ``last()``. ``visible()``
+    reads what the mask is made of and returns the function that makes
+    it ([bk, g * bq] or broadcasting to it), called where the scores are
+    masked. ``ops/sparse_attention.py`` runs it under a mask made from
+    the data."""
+    import jax.experimental.pallas as pl
+
+    g, bq, dh = q_ref.shape[1:]
+
+    @pl.when(first())
     def _():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
-    kb, lower, upper = _bd_bounds(
-        kind_ref[s_id], qi_ref[s_id] * bq, ki_ref[s_id] * bk, seq_len, shift,
-        lanes & (bq - 1),
-        jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
+    mask = visible()
     st = jax.lax.dot_general(
         k_ref[0], q_ref[0].reshape(g * bq, dh),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale          # [bk, g*bq]
-    st = jnp.where((kb <= upper) & (kb >= lower), st, _MASK_VALUE)
+    st = jnp.where(mask(), st, _MASK_VALUE)
     m_prev = m_scr[:]
     m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -669,7 +691,7 @@ def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                  # [dh, g*bq]
 
-    @pl.when(last_ref[s_id] == 1)
+    @pl.when(last())
     def _():
         l = l_scr[:]
         out = acc_scr[:] / l
@@ -775,46 +797,77 @@ def _bd_bwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
     import jax.experimental.pallas as pl
 
     s_id = pl.program_id(1)
-    g, bq, dh = q_ref.shape[1:]
+    g, bq, _ = q_ref.shape[1:]
     bk = k_ref.shape[1]
     ki = ki_ref[s_id]
+
+    def visible():
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
+        kb, lower, upper = _bd_bounds(
+            kind_ref[s_id], qi_ref[s_id] * bq, ki * bk, seq_len, shift,
+            lanes & (bq - 1),
+            jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
+        return lambda: (kb <= upper) & (kb >= lower)
+
+    bwd_body(s_id, lambda: first_ref[s_id] == 1,
+             lambda: last_ref[s_id] == 1, ki, visible,
+             q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+             dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, scale=scale)
+
+
+def bwd_body(s_id, first, last, ki, visible, q_ref, k_ref, v_ref, do_ref,
+             lse_ref, di_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+             *, scale, live=True):
+    """One step of the one-kernel backward (``_bd_bwd_kernel``'s
+    docstring) at grid step ``s_id``, the pair's entries where the mask
+    is true, ``first``, ``last`` and ``visible`` as ``fwd_body``'s.
+    ``live`` False (traced) makes a step that computes nothing: the
+    kernels of ``ops/sparse_attention.py`` end their walk with such
+    steps."""
+    import jax.experimental.pallas as pl
+
+    g, bq, dh = q_ref.shape[1:]
 
     @pl.when(s_id == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(first_ref[s_id] == 1)
+    @pl.when(first())
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
-    kb, lower, upper = _bd_bounds(
-        kind_ref[s_id], qi_ref[s_id] * bq, ki * bk, seq_len, shift,
-        lanes & (bq - 1),
-        jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
-    q = q_ref[0].reshape(g * bq, dh)
-    do = do_ref[0].reshape(g * bq, dh)
-    st = jax.lax.dot_general(
-        k_ref[0], q, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale          # [bk, g*bq]
-    st = jnp.where((kb <= upper) & (kb >= lower), st, _MASK_VALUE)
-    pt = jnp.exp(st - _rows_to_lanes(lse_ref[0], g))
-    dpt = jax.lax.dot_general(
-        v_ref[0], do, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dst = (pt * (dpt - _rows_to_lanes(di_ref[0], g)) * scale).astype(q.dtype)
-    dv_scr[ki] += jax.lax.dot_general(
-        pt.astype(do.dtype), do, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dk_scr[ki] += jax.lax.dot_general(
-        dst, q, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dq_scr[:] += jax.lax.dot_general(
-        k_ref[0], dst, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # [dh, g*bq]
+    def step():
+        mask = visible()
+        q = q_ref[0].reshape(g * bq, dh)
+        do = do_ref[0].reshape(g * bq, dh)
+        st = jax.lax.dot_general(
+            k_ref[0], q, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [bk, g*bq]
+        st = jnp.where(mask(), st, _MASK_VALUE)
+        pt = jnp.exp(st - _rows_to_lanes(lse_ref[0], g))
+        dpt = jax.lax.dot_general(
+            v_ref[0], do, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - _rows_to_lanes(di_ref[0], g))
+               * scale).astype(q.dtype)
+        dv_scr[ki] += jax.lax.dot_general(
+            pt.astype(do.dtype), do,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[ki] += jax.lax.dot_general(
+            dst, q, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_scr[:] += jax.lax.dot_general(
+            k_ref[0], dst, dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [dh, g*bq]
 
-    @pl.when(last_ref[s_id] == 1)
+    if live is True:
+        step()
+    else:
+        pl.when(live)(step)
+
+    @pl.when(last())
     def _():
         dq = dq_scr[:]
         for h in range(g):

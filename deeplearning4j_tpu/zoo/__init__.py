@@ -11,6 +11,7 @@ from deeplearning4j_tpu.zoo.models import (
     gpt_mini,
     gpt_mini_draft,
     gpt_mini_tp_rules,
+    keye_vl2_moe,
     lenet,
     lfm2_moe,
     mnist_mlp,
@@ -23,6 +24,6 @@ from deeplearning4j_tpu.zoo.models import (
 )
 
 __all__ = ["BF16", "F32", "VGG16_MEAN_RGB", "char_rnn", "glm4_moe_lite",
-           "gpt_mini",
-           "gpt_mini_draft", "gpt_mini_tp_rules", "lenet", "lfm2_moe", "mnist_mlp",
+           "gpt_mini", "gpt_mini_draft", "gpt_mini_tp_rules", "keye_vl2_moe",
+           "lenet", "lfm2_moe", "mnist_mlp",
            "nemotron_h", "resnet18", "resnet50", "sdar_moe", "vgg16", "vgg16_preprocess"]

@@ -546,6 +546,62 @@ def lfm2_moe(seed: int = 42, pattern: str = LFM2_MOE_PATTERN,
     return MultiLayerNetwork(conf).init()
 
 
+def keye_vl2_moe(seed: int = 42, n_layers: int = 48, n_experts: int = 128,
+                 experts_held: Optional[int] = None, first_expert: int = 0,
+                 vocab_size: int = 151_936, hidden: int = 2048,
+                 n_heads: int = 32, n_kv_heads: int = 4, head_dim: int = 128,
+                 expert_width: int = 768, experts_per_token: int = 8,
+                 index_heads: int = 16, index_head_dim: int = 64,
+                 index_topk: int = 2048, rope_theta: float = 1e7,
+                 eps: float = 1e-6, learning_rate: float = 1e-5,
+                 dtype: Optional[DtypePolicy] = None) -> MultiLayerNetwork:
+    """The language model of Keye-VL-2.0-30B-A3B (``model_type``
+    KeyeVL2; the defaults are its published config.json, ``sa_config``
+    among them): a causal decoder of RMS-normed grouped-query attention
+    with per-head query/key norm and rotary positions, whose keys a
+    learned indexer chooses for each row (the ``index_topk`` of all
+    earlier keys it scores highest), and softmax-routed SwiGLU experts
+    renormalised over the ``experts_per_token`` chosen, no shared one.
+    Integer ids in, the next token as integer labels out; the indexer's
+    own loss joins the data loss (``SparseMoeBlock``).
+
+    ``experts_held`` and ``first_expert`` give this chip's share of the
+    experts under expert parallelism (the router still scores all
+    ``n_experts``); ``vocab_size`` is the slice of the vocabulary held
+    here; the head is untied.
+
+    Init, for every seed alike: matrices normal(0, 0.02), the family's
+    ``initializer_range``; the embedding rows normal(0, 1), as the other
+    three softmax- or sigmoid-routed decoders here seed them (at 0.02
+    the first attention's output outweighs its input and every row's
+    router sees much the same average, PERF.md Findings PR 31); the
+    router column by column, with no balanced start (uniform ids and no
+    ``[MASK]`` rows, PR 33's finding); norm weights 1, the indexer's key
+    norm's bias 0."""
+    from deeplearning4j_tpu.nn.conf.layers_decoder import (
+        RmsNorm, SparseMoeBlock, TokenEmbedding, TokenOutput)
+    b = (NeuralNetConfiguration.builder()
+         .seed(seed).updater(Adam(learning_rate)).dtype(dtype or BF16)
+         .weight_init({"type": "normal", "mean": 0.0, "std": 0.02})
+         .list()
+         .layer(TokenEmbedding(n_out=hidden, weight_init={
+             "type": "normal", "mean": 0.0, "std": 1.0})))
+    for _ in range(n_layers):
+        b = b.layer(SparseMoeBlock(
+            n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
+            rope_theta=rope_theta, eps=eps, index_heads=index_heads,
+            index_head_dim=index_head_dim, index_topk=index_topk,
+            n_experts=n_experts, experts_per_token=experts_per_token,
+            expert_width=expert_width, experts_held=experts_held,
+            first_expert=first_expert))
+    conf = (b.layer(RmsNorm(eps=eps))
+            .layer(TokenOutput(n_out=vocab_size, activation="identity",
+                               causal=True))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+    return MultiLayerNetwork(conf).init()
+
+
 def gpt_mini_draft(vocab_size: int = 80, width: int = 128,
                    n_layers: int = 2, n_heads: int = 2, max_len: int = 256,
                    max_cache_len: Optional[int] = None, seed: int = 43,

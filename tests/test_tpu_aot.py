@@ -9,6 +9,7 @@ needs it lives in this one file (one process may load the TPU library).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -370,3 +371,85 @@ def test_short_conv_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
     text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v).compile(
         ).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_sparse_decoder_kernels_compile_at_the_bench_shape(one_chip, x32):
+    """What the cell ``keye_vl2_30b_a3b-train-b1-l8192`` asks of the
+    chip's compilers at its own shape, 8,192 rows: the selection kernel
+    (16 indexer heads of 64 and one key head, a top 2,048: a query tile
+    of 128 rows holds its 8,192 keys' int32 images in 4 MB of VMEM), the
+    tiled attention forward and one-kernel backward under the selection's
+    bits (32 query heads on 4 key/value heads of 128, 128 rows a tile
+    against 512 keys, the causal pairs' table made from the bits), and
+    the indexer's loss with its gradient in one kernel. The registry
+    would hand this CPU process the xla executors, so the kernels'
+    entries are compiled themselves."""
+    from deeplearning4j_tpu.ops import attention as att
+    from deeplearning4j_tpu.ops import sparse_attention as sa
+
+    seq, cd = 8192, jnp.bfloat16
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    q_index, k_index = shape((1, seq, 16, 64), cd), shape((1, seq, 64), cd)
+    w = shape((1, seq, 16), jnp.float32)
+    sel = shape((1, seq // 32, seq), jnp.int32)
+    q, k, v = (shape((1, seq, h, 128), cd) for h in (32, 4, 4))
+    assert sa.dsa_select_supported(q_index, k_index, w, 2048) is False
+    assert sa._tiled_length(seq)
+
+    select = jax.jit(lambda a, b, c: sa._select_tiled(a, b, c, 2048)).lower(
+        q_index, k_index, w).compile()
+    assert select.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+    def attend(q, k, v, sel):
+        tables, steps = sa._tables(sel, 128, 512)
+        og, lse = sa._sel_attention(*att._bd_split(q, k, v), sel, tables,
+                                    steps)
+        return jnp.sum(att._bd_join(og, 1).astype(jnp.float32))
+
+    assert att._bd_query_tile(8, seq) == 128 and att._bd_key_tile(seq) == 512
+    # 544 causal tile pairs of 128 x 512, the grid's length; the walk
+    # skips those without a kept key
+    assert len(att._causal_live_tiles(seq, 128, 512)) == 544
+    text = jax.jit(jax.grad(attend, (0, 1, 2))).lower(q, k, v, sel).compile(
+        ).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+    lse, lse_index = shape((1, 32, seq), jnp.float32), shape((1, seq),
+                                                            jnp.float32)
+    loss = jax.jit(jax.grad(sa._kl_tiled, (3, 4, 5))).lower(
+        q, k, lse, q_index, k_index, w, sel, lse_index).compile()
+    assert loss.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_sparse_decoder_step_fits_the_chip(one_chip, x32, monkeypatch):
+    """The whole training step of the cell, compiled for a described
+    v5e: 28 Pallas calls (a selection, an attention forward and backward,
+    the indexer's loss and three expert kernels in each of the 4 layers),
+    and its arguments and temporaries within the chip's 16 GB."""
+    from unittest import mock
+
+    from deeplearning4j_tpu.datasets import DataSet
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    init = MultiLayerNetwork.init
+    with mock.patch.object(MultiLayerNetwork, "init",
+                           lambda self, seed=None, structure_only=False:
+                           init(self, seed, structure_only=True)):
+        net = zoo.keye_vl2_moe(n_layers=4, experts_held=16,
+                               vocab_size=18992, learning_rate=1e-7)
+    ids = jnp.zeros((1, 8192), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = net._step_args(net._batch_args(DataSet(ids, ids)),
+                          jax.random.PRNGKey(0))
+    compiled = jax.jit(net._step_fn(), donate_argnums=(0, 1, 2)).lower(
+        *_on(one_chip, args)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 28
+    # no float array of a (row, key) pair: the selection is int32 bits
+    assert not [shape for shape in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]",
+                                              text)
+                if shape.split(",").count("8192") >= 2]
+    memory = compiled.memory_analysis()
+    # 465 M float32 parameters and Adam's two moments are 5.58 GB
+    assert abs(memory.argument_size_in_bytes - 5.585e9) < 0.01e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
