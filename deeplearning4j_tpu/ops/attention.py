@@ -402,6 +402,16 @@ def extend_cache(k_cache, v_cache, k_new, v_new, pos):
 #   that orientation hands to the MXU's contraction, and dQ^T = k^T dS^T
 #   contracts the keys as the forward's v^T p^T does.
 #
+#   A row's mask is the same for every head of the group, so a step
+#   makes it once for the query tile's [bk, bq] entries and lays it on
+#   each head's lanes (``_on_heads``; a forward of one chunk compares
+#   across its lanes instead, ``_bd_visible``). The forward makes its
+#   scores in chunks of lanes (whole heads or equal slices of one head,
+#   so whole rows and the arithmetic of one chunk), the next chunk's
+#   product issued before this one's max, exp and sum (``_fwd_chunks``):
+#   in the order written, Mosaic's schedule runs the product and the
+#   vector passes one after the other.
+#
 #   The backward is ONE kernel (``_bd_bwd_kernel``, since PR 41): it
 #   walks the pairs query-major as the forward does and makes the
 #   scores, p, dP and dS once a pair, five products a step; dQ gathers
@@ -465,6 +475,7 @@ def _dense_masked_mha(q, k, v, visible):
 
 
 _BD_STEP_ROWS = 1024    # rows (G heads x query positions) a grid step aims at
+_BD_CHUNK_LANES = 512   # score lanes a forward chunk holds at most
 
 
 def _bd_key_tile(seq_len: int) -> int:
@@ -483,6 +494,24 @@ def _bd_query_tile(group: int, seq_len: int) -> int:
     512 at a group of 1, 256 at 4, 128 from 8 on."""
     want = min(max(_BD_STEP_ROWS // group, 128), _bd_key_tile(seq_len))
     return next(bq for bq in (512, 256, 128) if bq <= want)
+
+
+def _fwd_chunks(group: int, bq: int) -> int:
+    """How many chunks of query lanes a forward grid step's [bk, group *
+    bq] scores are cut into (``fwd_body``): two at least, so that one
+    chunk's product runs beside the other's vector passes, and no more
+    than ``_BD_CHUNK_LANES`` lanes a chunk; one below 256 lanes, where a
+    chunk would hold fewer than 128. A chunk is whole heads or an equal
+    slice of one head's positions, so where the lanes do not split so
+    at that count the step takes the most chunks below it that do (one
+    at a group of 3, 5 or 7). Timed alone on a v5e at the five decoder
+    cells' tiles, two chunks beat four and eight at 512 and 1,024 lanes,
+    and four beat eight and two at 2,048 in ten rounds of ten (PERF.md,
+    Findings)."""
+    lanes = group * bq
+    want = max(lanes // _BD_CHUNK_LANES, 2 if lanes >= 256 else 1)
+    return next(n for n in range(want, 0, -1) if group % n == 0
+                or (n % group == 0 and bq % (n // group) == 0))
 
 
 def block_attention_supported(q, k, v, seq_len: int, block_len: int) -> bool:
@@ -641,18 +670,58 @@ def _bd_fwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
     s_id = pl.program_id(1)
     g, bq, _ = q_ref.shape[1:]
     bk = k_ref.shape[1]
+    fwd_body(lambda: first_ref[s_id] == 1, lambda: last_ref[s_id] == 1,
+             _bd_visible(kind_ref, qi_ref, ki_ref, s_id, g, bq, bk, seq_len,
+                         shift, shared=_fwd_chunks(g, bq) > 1),
+             q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+             scale=scale)
 
+
+def _bd_visible(kind_ref, qi_ref, ki_ref, s_id, g, bq, bk, seq_len, shift,
+                *, shared=True):
+    """``visible`` of the kernel bodies under a rule's table at grid step
+    ``s_id``, whose ``mask(lo, hi)`` is the mask of score lanes
+    ``lo:hi``: the rule's compare made once a step for the query tile's
+    [bk, bq] entries and laid on the lanes' heads (``_on_heads``), or
+    with ``shared`` False the compare made across the step's lanes, lane
+    ``h * bq + r`` as row ``r``. On a v5e the first reads faster in a
+    chunked forward and in the backward, the second in a forward of one
+    chunk (PERF.md, Findings)."""
     def visible():
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, bq if shared
+                                                     else g * bq), 1)
         kb, lower, upper = _bd_bounds(
             kind_ref[s_id], qi_ref[s_id] * bq, ki_ref[s_id] * bk, seq_len,
-            shift, lanes & (bq - 1),
+            shift, lanes if shared else lanes & (bq - 1),
             jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
-        return lambda: (kb <= upper) & (kb >= lower)
+        if shared:
+            return functools.partial(_on_heads,
+                                     (kb <= upper) & (kb >= lower))
+        return lambda lo, hi: ((kb <= upper[:, lo:hi])
+                               & (kb >= lower[:, lo:hi]))
+    return visible
 
-    fwd_body(lambda: first_ref[s_id] == 1, lambda: last_ref[s_id] == 1,
-             visible, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-             acc_scr, scale=scale)
+
+def _on_heads(keep, lo, hi):
+    """Lanes ``lo:hi`` of a step's scores under the query tile's mask
+    ``keep`` [bk, bq], which every head of the group shares (lane
+    ``h * bq + r`` is row ``r`` of head ``h``): a slice of one head's
+    rows, or one copy a head."""
+    bq = keep.shape[1]
+    if hi - lo == bq:
+        return keep
+    if hi - lo < bq:
+        return keep[:, lo % bq:lo % bq + hi - lo]
+    return jnp.concatenate([keep] * ((hi - lo) // bq), axis=1)
+
+
+def _chunk_rows(ref, lo, hi):
+    """Rows ``lo:hi`` of ``ref[0]`` [g, bq, dh] seen as [g * bq, dh]: whole
+    heads, or a slice of one head's positions."""
+    bq, dh = ref.shape[2:]
+    if hi - lo >= bq:
+        return ref[0, lo // bq:hi // bq].reshape(hi - lo, dh)
+    return ref[0, lo // bq, lo % bq:lo % bq + hi - lo]
 
 
 def fwd_body(first, last, visible, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -660,10 +729,17 @@ def fwd_body(first, last, visible, q_ref, k_ref, v_ref, o_ref, lse_ref,
     """One step of the forward kernels: the pair's scores as [keys,
     rows] where the mask is true, the running statistics reset where
     ``first()`` and the output written where ``last()``. ``visible()``
-    reads what the mask is made of and returns the function that makes
-    it ([bk, g * bq] or broadcasting to it), called where the scores are
-    masked. ``ops/sparse_attention.py`` runs it under a mask made from
-    the data."""
+    reads what the mask is made of and returns ``mask(lo, hi)``, the mask
+    of score lanes ``lo:hi`` ([bk, hi - lo] or broadcasting to it).
+    ``ops/sparse_attention.py`` runs it under a mask made from the data.
+
+    The scores are made in ``_fwd_chunks`` independent chunks of lanes,
+    unrolled: a chunk is whole rows (every row's statistics lie in its
+    own lane), so each makes its own product, max, exp, sum and second
+    product and reads and writes its own lanes of the scratch once, with
+    the arithmetic of one chunk. Each chunk's scores are issued before
+    the passes of the chunk before it, so that the MXU has a product to
+    run beside the vector unit's passes (PERF.md, Findings)."""
     import jax.experimental.pallas as pl
 
     g, bq, dh = q_ref.shape[1:]
@@ -675,21 +751,33 @@ def fwd_body(first, last, visible, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     mask = visible()
-    st = jax.lax.dot_general(
-        k_ref[0], q_ref[0].reshape(g * bq, dh),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale          # [bk, g*bq]
-    st = jnp.where(mask(), st, _MASK_VALUE)
-    m_prev = m_scr[:]
-    m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    pt = jnp.exp(st - m_new)
-    l_scr[:] = alpha * l_scr[:] + jnp.sum(pt, axis=0, keepdims=True)
-    m_scr[:] = m_new
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        v_ref[0], pt.astype(v_ref.dtype),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # [dh, g*bq]
+    width = g * bq // _fwd_chunks(g, bq)
+
+    def scores(lo):                                          # [bk, width]
+        st = jax.lax.dot_general(
+            k_ref[0], _chunk_rows(q_ref, lo, lo + width),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        return jnp.where(mask(lo, lo + width), st, _MASK_VALUE)
+
+    # the next chunk's product is written before this chunk's passes:
+    # written after them, it ran after them
+    st = scores(0)
+    for lo in range(0, g * bq, width):
+        following = scores(lo + width) if lo + width < g * bq else None
+        lanes = slice(lo, lo + width)
+        m_prev = m_scr[:, lanes]
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pt = jnp.exp(st - m_new)
+        l_scr[:, lanes] = (alpha * l_scr[:, lanes]
+                           + jnp.sum(pt, axis=0, keepdims=True))
+        m_scr[:, lanes] = m_new
+        acc_scr[:, lanes] = acc_scr[:, lanes] * alpha + jax.lax.dot_general(
+            v_ref[0], pt.astype(v_ref.dtype),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [dh, width]
+        st = following
 
     @pl.when(last())
     def _():
@@ -799,18 +887,10 @@ def _bd_bwd_kernel(qi_ref, ki_ref, kind_ref, first_ref, last_ref,
     s_id = pl.program_id(1)
     g, bq, _ = q_ref.shape[1:]
     bk = k_ref.shape[1]
-    ki = ki_ref[s_id]
-
-    def visible():
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, g * bq), 1)
-        kb, lower, upper = _bd_bounds(
-            kind_ref[s_id], qi_ref[s_id] * bq, ki * bk, seq_len, shift,
-            lanes & (bq - 1),
-            jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0))
-        return lambda: (kb <= upper) & (kb >= lower)
-
     bwd_body(s_id, lambda: first_ref[s_id] == 1,
-             lambda: last_ref[s_id] == 1, ki, visible,
+             lambda: last_ref[s_id] == 1, ki_ref[s_id],
+             _bd_visible(kind_ref, qi_ref, ki_ref, s_id, g, bq, bk, seq_len,
+                         shift),
              q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
              dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, scale=scale)
 
@@ -844,7 +924,7 @@ def bwd_body(s_id, first, last, ki, visible, q_ref, k_ref, v_ref, do_ref,
         st = jax.lax.dot_general(
             k_ref[0], q, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [bk, g*bq]
-        st = jnp.where(mask(), st, _MASK_VALUE)
+        st = jnp.where(mask(0, g * bq), st, _MASK_VALUE)
         pt = jnp.exp(st - _rows_to_lanes(lse_ref[0], g))
         dpt = jax.lax.dot_general(
             v_ref[0], do, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -968,6 +1048,18 @@ def _count_tiled_attention(direction: str, group: int, bq: int) -> None:
             direction=direction, group=str(group), query_tile=str(bq)).inc()
 
 
+def _count_forward_chunks(g: int, bq: int) -> None:
+    from deeplearning4j_tpu.observability.metrics import get_registry
+
+    get_registry().counter(
+        "dl4j_tiled_attention_forward_chunks_total",
+        "Tiled attention forward calls traced (under a rule's table or a "
+        "selection), by the chunks of query lanes a grid step's scores "
+        "were cut into and by the query heads a key/value head",
+        ("chunks", "group")).labels(
+            chunks=str(_fwd_chunks(g, bq)), group=str(g)).inc()
+
+
 def _count_tiled_backward(form: str, group: int) -> None:
     from deeplearning4j_tpu.observability.metrics import get_registry
 
@@ -986,6 +1078,7 @@ def _bd_forward(qg, kg, vg, seq_len, block_len):
     bh, g, t, dh = qg.shape
     bq, bk = _bd_query_tile(g, seq_len), _bd_key_tile(seq_len)
     _count_tiled_attention("forward", g, bq)
+    _count_forward_chunks(g, bq)
     tables = _bd_tables(seq_len, block_len, bq, bk, key_major=False)
     rows, stats, keys = _bd_specs(g, bq, bk, dh)
     return _bd_call(

@@ -239,18 +239,25 @@ def _tiled_length(length: int) -> bool:
             and att._bd_key_tile(length) % (length // 32) == 0)
 
 
-def _selected(words, ki, bk, reps):
-    """The keep mask of key tile ``ki`` [bk, reps * bq] from the query
-    tile's words [L/32, bq]: bit ``ki * m + p`` of every word, p < m,
-    stacked down the sublanes, the rows repeated ``reps`` times along
-    the lanes (one copy a head of the group)."""
+def _key_bits(words, ki, bk):
+    """The bits of key tile ``ki`` from the query tile's words [L/32, bq]:
+    bit ``ki * m + p`` of every word, p < m, stacked down the sublanes
+    (int32 [bk, bq], 1 where the row keeps the key)."""
     n_words = words.shape[0]
     m = bk // n_words
     parts = [jnp.right_shift(words, ki * m + p) & 1 for p in range(m)]
-    mask = parts[0] if m == 1 else jnp.concatenate(parts, axis=0)
-    if reps > 1:
-        mask = jnp.concatenate([mask] * reps, axis=1)
-    return mask != 0
+    return parts[0] if m == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _visible(sel_ref, ki, bk):
+    """``visible`` of ``ops/attention.py``'s kernel bodies under the
+    selection: key tile ``ki``'s bits of the query tile's words made
+    into a [bk, bq] mask once a step, and ``mask(lo, hi)`` laying it on
+    score lanes ``lo:hi``, one copy a head (``att._on_heads``)."""
+    def visible():
+        return functools.partial(att._on_heads,
+                                 _key_bits(sel_ref[0], ki, bk) != 0)
+    return visible
 
 
 # ---------------------------------------------------- sparse attention
@@ -298,13 +305,12 @@ def _sel_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, n_ref,
 
     bh, s_id = pl.program_id(0), pl.program_id(1)
     t = (bh // hkv) * steps + s_id
-    g = q_ref.shape[1]
     bk = k_ref.shape[1]
 
     @pl.when(s_id < n_ref[bh // hkv])
     def _():
         att.fwd_body(lambda: first_ref[t] == 1, lambda: last_ref[t] == 1,
-                     lambda: lambda: _selected(sel_ref[0], ki_ref[t], bk, g),
+                     _visible(sel_ref, ki_ref[t], bk),
                      q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                      acc_scr, scale=scale)
 
@@ -317,12 +323,11 @@ def _sel_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, n_ref,
 
     bh, s_id = pl.program_id(0), pl.program_id(1)
     t = (bh // hkv) * steps + s_id
-    g = q_ref.shape[1]
     bk = k_ref.shape[1]
     ki = ki_ref[t]
     att.bwd_body(s_id, lambda: first_ref[t] == 1, lambda: last_ref[t] == 1,
                  ki,
-                 lambda: lambda: _selected(sel_ref[0], ki, bk, g),
+                 _visible(sel_ref, ki, bk),
                  q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                  dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
                  scale=scale, live=s_id < n_ref[bh // hkv])
@@ -339,6 +344,7 @@ def _sel_forward(qg, kg, vg, sel, tables, steps):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, g, length, dh, b, hkv, bq, bk = _sel_geometry(qg, sel)
+    att._count_forward_chunks(g, bq)
     rows, stats, keys, words = _specs(g, bq, bk, dh, sel.shape[1], steps,
                                       hkv)
     return att._bd_call(
@@ -642,8 +648,8 @@ def _kl_kernel(qi_ref, ki_ref, first_ref, last_ref, n_ref,
 
     @pl.when(s_id < n_ref[b])
     def _():
-        keep = _selected(sel_ref[0], ki, bk, 1)              # [bk, bq]
-        keep_heads = _selected(sel_ref[0], ki, bk, g)        # [bk, g*bq]
+        keep = _key_bits(sel_ref[0], ki, bk) != 0            # [bk, bq]
+        keep_heads = att._on_heads(keep, 0, g * bq)          # [bk, g*bq]
         p_sum = jnp.zeros((bk, bq), jnp.float32)
         for h in range(hkv):
             st = jax.lax.dot_general(
