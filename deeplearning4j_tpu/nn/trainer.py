@@ -350,30 +350,28 @@ class Trainer:
         return xla_step_cost(self._train_step, *self._step_args(
             self._batch_args(ds), jax.random.PRNGKey(0)))
 
-    def _maybe_derive_flops(self, batch):
-        """Auto-derive per-step FLOPs from the XLA cost model on the
-        *lowered* train step — tracing only, no second backend compile —
-        the first time each (train-step, batch-shapes) pair is seen.
-        Feeds live dl4j_mfu / dl4j_flops_per_second with zero user
-        wiring; DL4J_TPU_AUTO_FLOPS=0 opts out."""
+    def _maybe_derive_flops(self, program, args, batch):
+        """Per-step FLOPs from XLA's cost model of ``program``, the jitted
+        step its caller has just dispatched with ``args``, the first time
+        each (program, per-step batch shapes) pair is seen. The donated
+        trees of ``args`` are swapped for the dispatch's outputs, of the
+        same avals and shardings, so jax's caches hand back the lowering
+        and the executable the dispatch made: nothing is traced anew,
+        lowered or compiled. The chunked path's scan counts as one step:
+        XLA costs a ``while`` body once, and ``build_multi_batch_step``
+        pins ``unroll=1``. Feeds live dl4j_mfu / dl4j_flops_per_second
+        with zero user wiring; DL4J_TPU_AUTO_FLOPS=0 opts out."""
         if not _goodput.auto_flops_enabled():
             return
-        if self._train_step is None:
-            # the key below names the step, so build it first: the
-            # chunked path has not
-            self._train_step = self._build_train_step()
-        key = (id(self._train_step), jax.tree_util.tree_map(np.shape, batch))
+        key = (id(program), jax.tree_util.tree_map(np.shape, batch))
         if getattr(self, "_flops_key", None) == key:
             return
         self._flops_key = key
         with _get_tracer().program_span("flops_derive"):
             try:
-                from deeplearning4j_tpu.utils.perf import (
-                    xla_step_cost_lowered,
-                )
-                cost = xla_step_cost_lowered(
-                    self._train_step,
-                    *self._step_args(batch, jax.random.PRNGKey(0)))
+                from deeplearning4j_tpu.utils.perf import xla_step_cost
+                cost = xla_step_cost(program, self.params, self.state,
+                                     self.opt_state, *args[3:])
                 self.flops_per_step = cost["flops"] or None
             except NotImplementedError:
                 # meshed/wrapped steps have no .lower
@@ -404,10 +402,7 @@ class Trainer:
         self.score_value = score
         self.last_batch_examples = ds.num_examples
         _goodput.observe_steps(1)
-        # after the dispatch: self.params holds fresh (undonated) outputs
-        # and the batch was not donated, so lowering for cost analysis is
-        # safe
-        self._maybe_derive_flops(batch)
+        self._maybe_derive_flops(self._train_step, args, batch)
         if self.listeners:
             t0 = time.perf_counter()
             for l in self.listeners:
@@ -671,7 +666,7 @@ class Trainer:
         # pre-stack arrays already have the per-step shape; slicing the
         # stacked device arrays here would dispatch (and first-call
         # compile) an XLA gather outside the flops_derive span
-        self._maybe_derive_flops(trees[0])
+        self._maybe_derive_flops(jitted, args, trees[0])
         with tracer.span("score_sync", steps=len(batches)):
             self._replay_listeners(start, scores,
                                    [b.num_examples for b in batches])

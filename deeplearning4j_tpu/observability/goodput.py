@@ -23,9 +23,11 @@ keeps the books:
   framing: time making forward progress vs time spent on data stalls,
   host dispatch, checkpoints, rollbacks, recompiles.
 - **Live MFU with zero wiring** — both nets derive per-step FLOPs from
-  the XLA cost model on the *lowered* train step at step-build time
-  (``utils.perf.xla_step_cost_lowered`` — tracing only, no second
-  backend compile) and report them here, so ``dl4j_mfu`` /
+  the XLA cost model of the executable their first dispatch of each
+  step program compiled (``utils.perf.xla_step_cost`` right after the
+  dispatch: jax's caches hand back its lowering and executable, so
+  nothing is lowered or compiled again; the chunked path's scan costs
+  one step) and report them here, so ``dl4j_mfu`` /
   ``dl4j_flops_per_second`` / ``dl4j_goodput_fraction`` are live
   Prometheus gauges during any ``fit`` without user code. Peak FLOP/s
   comes from the device table (``utils.perf.PEAK_FLOPS``) or the
@@ -109,8 +111,8 @@ def set_enabled(flag: bool) -> None:
 
 def auto_flops_enabled() -> bool:
     """Whether the fit loops should auto-derive per-step FLOPs from the
-    lowered cost model (``DL4J_TPU_AUTO_FLOPS=0`` disables just the
-    derivation while keeping the ledger)."""
+    cost model of the step they dispatched (``DL4J_TPU_AUTO_FLOPS=0``
+    disables just the derivation while keeping the ledger)."""
     return enabled() and os.environ.get("DL4J_TPU_AUTO_FLOPS", "1") != "0"
 
 

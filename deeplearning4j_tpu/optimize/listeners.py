@@ -105,7 +105,7 @@ class PerformanceListener(TrainingListener):
         self.report_examples = report_examples
         self.flops_per_step = flops_per_step  # net.step_cost_analysis(ds)["flops"]
         # report_mfu without explicit flops: use the FLOPs the fit loop
-        # auto-derived from the lowered cost model (net.flops_per_step)
+        # read from the dispatched step's cost model (net.flops_per_step)
         self.report_mfu = bool(report_mfu) or flops_per_step is not None
         self.records: list[dict] = []
         self._last_time = None

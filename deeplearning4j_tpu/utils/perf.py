@@ -49,26 +49,10 @@ def _cost_numbers(cost) -> dict:
 
 def xla_step_cost(jitted_step, *args) -> dict:
     """Cost-model numbers for one compiled call of ``jitted_step(*args)``:
-    {"flops", "bytes_accessed"}. Raises NotImplementedError for wrapped
-    (non-jit) steps such as the meshed trainers."""
+    {"flops", "bytes_accessed"}. Right after a dispatch of the step with
+    arguments of the same avals and shardings this costs a cache lookup:
+    jax hands back the lowering and the executable the dispatch made.
+    Raises NotImplementedError for wrapped (non-jit) steps such as the
+    meshed trainers."""
     return _cost_numbers(
         _lower(jitted_step, args).compile().cost_analysis())
-
-
-def xla_step_cost_lowered(jitted_step, *args) -> dict:
-    """Like :func:`xla_step_cost` but from the *lowered* (pre-backend-
-    compile) module where the backend can cost one — pure tracing, no
-    second XLA compilation, so the fit loops can auto-derive per-step
-    FLOPs at step-build time without doubling compile cost. The TPU's
-    PJRT plug-in cannot (``Lowered.cost_analysis()`` is None there), so
-    on it the numbers come from the compiled executable: jax hands back
-    the one the step's own dispatch compiled, which makes this free once
-    the step has run and one real compile where it has not (the chunked
-    fit path derives from the single step; a later ``fit_batch`` then
-    reuses that executable). Same return shape. Raises
-    NotImplementedError for wrapped steps."""
-    lowered = _lower(jitted_step, args)
-    cost = lowered.cost_analysis()
-    if cost is None:
-        cost = lowered.compile().cost_analysis()
-    return _cost_numbers(cost)

@@ -1,9 +1,9 @@
 """Goodput & efficiency attribution engine tests: the per-run wall-time
 ledger (EfficiencyLedger / RunReport), zero-wiring live MFU gauges from
-the lowered cost model, padding-waste accounting (serving bucket ladder
-+ datapipe bucket_batch), tracer drop counters, the memory watermark,
-and the scripts/check_budgets.py CI gate (including a demonstrable
-failure on a violated budget)."""
+the dispatched step's cost model, padding-waste accounting (serving
+bucket ladder + datapipe bucket_batch), tracer drop counters, the memory
+watermark, and the scripts/check_budgets.py CI gate (including a
+demonstrable failure on a violated budget)."""
 
 import json
 import os
@@ -75,6 +75,22 @@ def _mlp(n_in=16, hidden=32, n_out=3):
             .layer(Output(n_out=n_out, activation="softmax", loss="mcxent"))
             .build())
     return MultiLayerNetwork(conf).init()
+
+
+def _graph(n_in=16, hidden=32, n_out=3):
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import Dense, Output
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    conf = (NeuralNetConfiguration.builder().seed(1).graph_builder()
+            .add_inputs("in")
+            .add_layer("h", Dense(n_in=n_in, n_out=hidden, activation="tanh"),
+                       "in")
+            .add_layer("out", Output(n_in=hidden, n_out=n_out,
+                                     activation="softmax", loss="mcxent"),
+                       "h")
+            .set_outputs("out").build())
+    return ComputationGraph(conf).init()
 
 
 def _xy(n=64, n_in=16, n_out=3, seed=0):
@@ -154,22 +170,25 @@ def test_disabled_engine_returns_null_ledger(fresh_obs):
 # -------------------------------------------------- fit integration
 
 
-def test_fit_publishes_live_goodput_and_mfu_gauges(fresh_obs, monkeypatch):
+@pytest.mark.parametrize("multi_step", ["auto", 8])
+def test_fit_publishes_live_goodput_and_mfu_gauges(fresh_obs, monkeypatch,
+                                                   multi_step):
     """A plain net.fit on a zoo model publishes dl4j_mfu /
     dl4j_goodput_fraction / dl4j_flops_per_second with no manual FLOPs
-    wiring — the acceptance criterion of the goodput engine."""
+    wiring — the acceptance criterion of the goodput engine. The CPU
+    resolves "auto" to per-batch dispatch; 8 takes the chunked path."""
     from deeplearning4j_tpu import zoo
 
     monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
     reg, tr = fresh_obs
     net = zoo.mnist_mlp()
     x, y = _xy(n=64, n_in=784, n_out=10)
-    net.fit(x, y, epochs=2, batch_size=8)
+    net.fit(x, y, epochs=2, batch_size=8, multi_step=multi_step)
 
     rep = net.last_run_report
     assert rep is not None and rep.status == "completed"
     assert rep.kind == "fit" and rep.steps == 16
-    # FLOPs were auto-derived from the lowered cost model
+    # FLOPs were auto-derived from the dispatched step's cost model
     assert net.flops_per_step and net.flops_per_step > 0
     assert rep.flops_per_step == pytest.approx(net.flops_per_step)
     assert rep.flops_per_second and rep.flops_per_second > 0
@@ -262,6 +281,62 @@ def test_fit_steps_count_k_per_chunked_dispatch(fresh_obs):
     assert net.iteration == 8
 
 
+@pytest.mark.parametrize("make", [_mlp, _graph], ids=["mlp", "graph"])
+def test_chunked_fit_reads_flops_from_the_dispatched_scan(fresh_obs, make):
+    """The chunked path derives per-step FLOPs from the scan it has just
+    dispatched: it never builds the single step, the derivation lowers
+    and compiles nothing (jax's caches hand back the dispatch's own
+    lowering and executable; the one interval booked to it, if any, is
+    jax's trace-cache lookup of the scan), and the count is one step's,
+    because XLA costs a ``while`` body once. Should a later XLA count
+    every trip, the last check says so."""
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.observability import metrics as obs
+
+    reg, tr = fresh_obs
+    # wide enough that the scan body's rng split is far under 1e-3 of
+    # the step's FLOPs
+    net = make(n_in=64, hidden=256)
+    x, y = _xy(n=512, n_in=64)
+    stages0 = obs.stage_snapshot()
+    net.fit(ArrayDataSetIterator(x, y, batch_size=32, drop_last=True),
+            epochs=1, multi_step=8)
+    made = obs.stage_delta(stages0)
+    assert net.iteration == 16
+    assert net._train_step is None
+    for kind in ("seconds", "programs"):
+        booked = {stage: owners["flops_derive"]
+                  for stage, owners in made[kind].items()
+                  if stage != "trace" and owners.get("flops_derive")}
+        assert not booked, f"{kind} made under flops_derive: {booked}"
+    assert made["programs"].get("trace", {}).get("flops_derive", 0) <= 1
+    under =[(s.name, (s.attrs or {}).get("program")) for s in tr.spans()
+             if s.parent == "flops_derive"]
+    assert set(under) <= {("xla_trace", "multi")}, under
+    assert made["spans"]["flops_derive"]["count"] == 1
+    single = net.step_cost_analysis(DataSet(x[:32], y[:32]))["flops"]
+    assert net.flops_per_step == pytest.approx(single, rel=1e-3)
+
+
+def test_chunked_fit_auto_flops_off_derives_nothing(fresh_obs, monkeypatch):
+    """DL4J_TPU_AUTO_FLOPS=0 on the chunked path: no count, no span."""
+    from deeplearning4j_tpu.observability import metrics as obs
+
+    monkeypatch.setenv("DL4J_TPU_AUTO_FLOPS", "0")
+    reg, tr = fresh_obs
+    net = _mlp()
+    x, y = _xy(n=128)
+    stages0 = obs.stage_snapshot()
+    net.fit(ArrayDataSetIterator(x, y, batch_size=8, drop_last=True),
+            epochs=1, multi_step=8)
+    assert net.iteration == 16
+    assert getattr(net, "flops_per_step", None) is None
+    assert net.last_run_report.flops_per_step is None
+    assert not [s for s in tr.spans() if s.name == "flops_derive"]
+    spans = obs.stage_delta(stages0)["spans"]
+    assert spans.get("flops_derive", {}).get("count", 0) == 0
+
+
 def test_fit_batch_repeated_counts_n_steps(fresh_obs):
     from deeplearning4j_tpu.datasets.dataset import DataSet
 
@@ -276,18 +351,7 @@ def test_fit_batch_repeated_counts_n_steps(fresh_obs):
 
 def test_graph_fit_produces_report(fresh_obs, monkeypatch):
     monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
-    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
-    from deeplearning4j_tpu.nn.conf.layers import Dense, Output
-    from deeplearning4j_tpu.nn.graph import ComputationGraph
-
-    conf = (NeuralNetConfiguration.builder().seed(1).graph_builder()
-            .add_inputs("in")
-            .add_layer("h", Dense(n_in=16, n_out=32, activation="tanh"),
-                       "in")
-            .add_layer("out", Output(n_in=32, n_out=3, activation="softmax",
-                                     loss="mcxent"), "h")
-            .set_outputs("out").build())
-    net = ComputationGraph(conf).init()
+    net = _graph()
     from deeplearning4j_tpu.datasets.dataset import DataSet
     from deeplearning4j_tpu.datasets.iterator import ListDataSetIterator
 

@@ -161,17 +161,20 @@ def test_stages_go_to_the_span_that_caused_them(tracer):
     for owner in ("net_init", "forward", "device_step", "none"):
         assert made["seconds"]["compile"].get(owner, 0) > 0, owner
         assert made["programs"]["lower"].get(owner, 0) >= 1, owner
-    # the chunked path never lowered the single step: flops_derive does,
-    # and compiles nothing
-    assert made["programs"]["lower"].get("flops_derive") == 1
-    assert made["seconds"]["trace"].get("flops_derive", 0) > 0
-    assert "flops_derive" not in made["programs"]["compile"]
+    # flops_derive reads the count from the scan the chunked path has
+    # dispatched: it lowers and compiles nothing, and never builds the
+    # single step
+    assert not made["programs"]["lower"].get("flops_derive")
+    assert not made["programs"]["compile"].get("flops_derive")
+    assert net._train_step is None
     assert made["programs"]["lower"]["forward"] == 1
     for name in ("net_init", "forward", "flops_derive"):
         assert made["spans"][name]["count"] == 1
         under = sum(by_owner.get(name, 0)
                     for by_owner in made["seconds"].values())
-        assert 0 < under <= made["spans"][name]["seconds"]
+        assert under <= made["spans"][name]["seconds"]
+        # flops_derive holds at most jax's trace-cache lookup of the scan
+        assert under > 0 or name == "flops_derive"
     by_name = {}
     for s in tracer.spans():
         by_name.setdefault(s.name, []).append(s)
@@ -180,8 +183,9 @@ def test_stages_go_to_the_span_that_caused_them(tracer):
         "net_init", "forward", "host_dispatch", "device_step", None}
     step = [s for s in by_name["xla_compile"] if s.parent == "device_step"]
     assert "multi" in [s.attrs["program"] for s in step]
-    derived = [s for s in by_name["xla_lower"] if s.parent == "flops_derive"]
-    assert [s.attrs["program"] for s in derived] == ["step_fn"]
+    derived = {(s.name, s.attrs["program"]) for s in tracer.spans()
+               if s.name in XLA_SPANS and s.parent == "flops_derive"}
+    assert derived <= {("xla_trace", "multi")}, derived
     assert by_name["net_init"][0].parent is None
     # a stage span lies inside the span that caused it, on the same clock
     init = by_name["net_init"][0]
